@@ -17,10 +17,6 @@ import os
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import json
 
 from fei_tpu.engine.engine import GenerationConfig, InferenceEngine

@@ -18,14 +18,9 @@ Run hermetically on CPU:
       python examples/int4_quantized_serving.py
 """
 
-import os
 import threading
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp
 
 from fei_tpu.engine import GenerationConfig, InferenceEngine

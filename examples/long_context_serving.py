@@ -20,10 +20,6 @@ if "xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 from fei_tpu.engine.engine import GenerationConfig, InferenceEngine
 from fei_tpu.parallel.mesh import make_mesh
 from fei_tpu.utils.metrics import METRICS

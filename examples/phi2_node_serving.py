@@ -20,11 +20,6 @@ With real weights (HF safetensors layout):
 import concurrent.futures as cf
 import os
 
-import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 from fei_tpu.engine import GenerationConfig, InferenceEngine
 
 

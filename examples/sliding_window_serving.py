@@ -11,13 +11,6 @@ Run hermetically on CPU:
   JAX_PLATFORMS=cpu python examples/sliding_window_serving.py
 """
 
-import os
-
-import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 from fei_tpu.engine import GenerationConfig, InferenceEngine
 from fei_tpu.utils.metrics import METRICS
 

@@ -16,12 +16,7 @@ Run hermetically on the 8-device virtual CPU mesh:
       python examples/swa_sp_long_prefill.py
 """
 
-import os
-
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
 from fei_tpu.engine import GenerationConfig, InferenceEngine
 from fei_tpu.parallel.mesh import make_mesh

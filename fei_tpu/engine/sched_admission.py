@@ -627,53 +627,27 @@ class AdmissionMixin:
         no decode scan to merge with)."""
         eng = self.engine
         C = toks.shape[1]
-        try:
-            t0 = time.perf_counter()
-            with METRICS.span("prefill_chunk", jax_trace=True):
-                fn = self._paged_chunk_fn(C, final)
-                out = fn(
-                    eng.params, self._pool, jnp.asarray(toks),
-                    jnp.asarray(st["row"][None]),
-                    jnp.asarray([lo], dtype=jnp.int32),
-                    jnp.int32(n - 1 - lo),
-                )
-                t_issue = time.perf_counter()
-                if final:
-                    last_logits, self._pool = out
-                    last_logits.block_until_ready()
-                else:
-                    self._pool = out
-            FLIGHT.dispatch(
-                "dispatch.prefill_chunk", t0, t_issue,
-                time.perf_counter(), rid=seq.rid,
-                mesh=mesh_tag(eng.mesh), slot=st["slot"],
-                tokens=hi - lo, paged=True,
+        t0 = time.perf_counter()
+        with METRICS.span("prefill_chunk", jax_trace=True):
+            out = self._device_call(
+                "paged-native prefill chunk", self._paged_chunk_fn(C, final),
+                eng.params, self._pool, jnp.asarray(toks),
+                jnp.asarray(st["row"][None]),
+                jnp.asarray([lo], dtype=jnp.int32),
+                jnp.int32(n - 1 - lo),
             )
-        except Exception as exc:  # noqa: BLE001
-            first = lo == st["prefix"] * eng.page_size
-            if first and self._pool_intact():
-                # first chunk, pool untouched (e.g. Mosaic rejected the
-                # chunk tile on-chip): release the slot and requeue the
-                # request at the FRONT — it re-admits through the
-                # normal path with the native route disabled, shared
-                # prefix pages surviving on their registry refs
-                log.warning(
-                    "paged-native prefill failed (%r); falling back to "
-                    "the dense-staging path", exc,
-                )
-                self.paged_native_prefill = False
-                METRICS.incr("scheduler.paged_prefill_disabled")
-                self._admitting = None
-                eng._allocator.free(st["slot"])
-                self._slots[st["slot"]] = None
-                seq.slot = -1
-                seq.prefilling = False
-                seq.prefix_match = None  # pins dropped: re-probe
-                seq.lazy = False  # re-decided at the next admission
-                with self._lock:
-                    self._waiting.appendleft(seq)
-                return
-            raise
+            t_issue = time.perf_counter()
+            if final:
+                last_logits, self._pool = out
+                last_logits.block_until_ready()
+            else:
+                self._pool = out
+        FLIGHT.dispatch(
+            "dispatch.prefill_chunk", t0, t_issue,
+            time.perf_counter(), rid=seq.rid,
+            mesh=mesh_tag(eng.mesh), slot=st["slot"],
+            tokens=hi - lo, paged=True,
+        )
         st["pos"] = hi
         if not final or hi < n:
             # more prompt chunks — or, on a resume, the generated

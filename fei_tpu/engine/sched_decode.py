@@ -519,9 +519,9 @@ class DecodeMixin:
         METRICS.incr("scheduler.decode_slot_steps", len(active) * n)
         METRICS.gauge("scheduler.batch_slots_active", len(active))
         chunk_logits = None
-        merged = False
+        merged = pc is not None
         t0 = time.perf_counter()
-        if pc is not None:
+        if merged:
             step = self._ragged_fn(
                 n, pc["toks"].shape[1], pc["final"], grammared
             )
@@ -531,35 +531,17 @@ class DecodeMixin:
                 jnp.asarray([pc["lo"]], dtype=jnp.int32),
                 jnp.int32(pc["ntok"] - 1 - pc["lo"]),
             ] + args[2:]
-            try:
-                with METRICS.span("decode_step"):
-                    res = step(*rargs, **kw)
-                    if pc["final"]:
-                        (chunk_logits, nxt, self._step_keys, self._pool,
-                         self._keys) = res
-                    else:
-                        nxt, self._step_keys, self._pool, self._keys = res
-                    t_issue = time.perf_counter()
-                    out = np.asarray(nxt)  # host sync inside the span
-                merged = True
-            except Exception as exc:  # noqa: BLE001
-                if not self._pool_intact():
-                    raise
-                # trace/compile-stage failure (e.g. Mosaic rejected the
-                # ragged tile on-chip): the donated pool is untouched, so
-                # disarm the merged path for the engine's lifetime,
-                # re-stash the chunk for a solo dispatch (the
-                # _step_active flush), and run the legacy scan
-                log.warning(
-                    "ragged merged dispatch failed (%r); falling back to "
-                    "the legacy FEI_TPU_ATTENTION=paged programs", exc,
-                )
-                self.ragged_attention = False
-                METRICS.incr("scheduler.ragged_disabled")
-                self._pending_chunk = pc
-                pc = None
-                t0 = time.perf_counter()
-        if not merged:
+            with METRICS.span("decode_step"):
+                res = self._device_call("ragged merged dispatch", step,
+                                        *rargs, **kw)
+                if pc["final"]:
+                    (chunk_logits, nxt, self._step_keys, self._pool,
+                     self._keys) = res
+                else:
+                    nxt, self._step_keys, self._pool, self._keys = res
+                t_issue = time.perf_counter()
+                out = np.asarray(nxt)  # host sync inside the span
+        else:
             step = self._multi_fn(n, grammared, masked=mask is not None)
             with METRICS.span("decode_step"):
                 nxt, self._step_keys, self._pool, self._keys = step(*args, **kw)

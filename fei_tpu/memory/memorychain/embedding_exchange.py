@@ -27,8 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from fei_tpu.utils.platform import shard_map
-
 _TOKEN_RX = re.compile(r"[a-z0-9]+")
 
 
@@ -75,7 +73,7 @@ def exchange_banks(
         )  # [n_nodes, N, D]
         return gathered[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=P(axis_name),

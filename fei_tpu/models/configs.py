@@ -112,8 +112,9 @@ class ModelConfig:
 # repo — the reference has no model code at all (SURVEY.md §2: LLM calls go out
 # over HTTP via LiteLLM, fei/core/assistant.py:524-530).
 MODEL_CONFIGS: dict[str, ModelConfig] = {
-    # hermetic-test presets
-    "tiny": ModelConfig(),
+    # hermetic-test presets (2048 positions: an interpret-mode paged kernel
+    # walks every page slot of a row's table, live or not)
+    "tiny": ModelConfig(max_seq_len=2048),
     "debug": ModelConfig(
         name="debug", vocab_size=512, hidden_size=128, intermediate_size=256,
         num_layers=4, num_heads=8, num_kv_heads=4, max_seq_len=2048,

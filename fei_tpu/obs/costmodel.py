@@ -3,32 +3,33 @@ estimates from the model config + batch/page geometry.
 
 Single-stream decode is weight-streaming-bound, so achieved tok/s ×
 bytes-streamed-per-token against the chip's HBM bandwidth — not MFU — is
-the lens that says whether there is headroom (BASELINE.md measures the
-same ceiling empirically). This module owns the byte model bench.py
-reports against, plus live per-dispatch accounting the scheduler feeds
-into the ``roofline.frac`` / ``roofline.tok_s_per_chip`` gauges so
-``/metrics`` and every bench line carry the fraction-of-roofline a run
-actually achieved.
+the lens that says whether there is headroom. This module owns the byte
+model bench.py reports against, plus live per-dispatch accounting the
+scheduler feeds into the ``roofline.frac`` / ``roofline.tok_s_per_chip``
+gauges so ``/metrics`` and every bench line carry the fraction-of-roofline
+a run actually achieved.
 
-``FEI_TPU_HBM_GBPS`` overrides the per-chip bandwidth ceiling (default
-the v5e spec number) — e.g. when serving on a different TPU generation.
+The peak comes from ``DEVICE_PEAKS``, keyed by the ``device_kind`` JAX
+reports. A device that is not in the table has no roofline: the gauges
+are not published and a bench line carries no roofline field.
 """
 
 from __future__ import annotations
 
-import os
+# device_kind -> per-chip peaks. v5e: Google Cloud documentation, "TPU
+# v5e" system architecture — 819 GB/s HBM2e, 197 TFLOP/s bf16. JAX names
+# the chip "TPU v5 lite" (my chip run, PR 21).
+DEVICE_PEAKS: dict[str, dict[str, float]] = {
+    "TPU v5 lite": {"hbm_gbps": 819.0, "bf16_tflops": 197.0},
+}
 
-# v5e HBM bandwidth (chip spec ~819 GB/s) — the default roofline ceiling
-V5E_HBM_GBPS = 819.0
 
+def device_peaks() -> dict[str, float] | None:
+    """The peaks of the device JAX came up on, or None when its
+    ``device_kind`` is not in ``DEVICE_PEAKS`` (the CPU, for one)."""
+    import jax
 
-def hbm_gbps() -> float:
-    """The per-chip HBM bandwidth ceiling (GB/s) the roofline fraction is
-    computed against; ``FEI_TPU_HBM_GBPS`` overrides the v5e default."""
-    try:
-        return float(os.environ.get("FEI_TPU_HBM_GBPS", "") or V5E_HBM_GBPS)
-    except ValueError:
-        return V5E_HBM_GBPS
+    return DEVICE_PEAKS.get(jax.devices()[0].device_kind)
 
 
 def decode_stream_bytes(engine, mean_ctx: int) -> dict:
@@ -132,14 +133,14 @@ def ragged_dispatch_bytes(
     return dispatch_bytes(engine, n_steps, total_ctx, slots) + int(chunk)
 
 
-def roofline_fraction(bytes_streamed: int, dt_s: float,
+def roofline_fraction(bytes_streamed: int, dt_s: float, hbm_gbps: float,
                       n_chips: int = 1) -> float:
     """Fraction of the aggregate HBM roofline achieved: estimated bytes
-    over wall time vs ``n_chips`` × the per-chip ceiling."""
+    over wall time vs ``n_chips`` × the per-chip ceiling ``hbm_gbps``."""
     if dt_s <= 0:
         return 0.0
     gbps = bytes_streamed / dt_s / 1e9
-    return gbps / (hbm_gbps() * max(1, n_chips))
+    return gbps / (hbm_gbps * max(1, n_chips))
 
 
 def chips_for_tag(tag: str | None) -> int:
@@ -224,11 +225,14 @@ def _roofline_gauges(engine, est_bytes: int, tokens: int, dt_s: float,
                      n_chips: int) -> None:
     from fei_tpu.obs.metrics import METRICS
 
-    # 9 decimals: a tiny CPU model's frac is O(1e-7) and must not round
-    # to a flat zero; production fractions are O(0.1) and unaffected
+    peaks = device_peaks()
+    if peaks is None:
+        return
     METRICS.gauge(
         "roofline.frac",
-        round(roofline_fraction(est_bytes, dt_s, n_chips), 9),
+        round(roofline_fraction(
+            est_bytes, dt_s, peaks["hbm_gbps"], n_chips
+        ), 9),
     )
     METRICS.gauge(
         "roofline.tok_s_per_chip",

@@ -68,11 +68,6 @@ METRIC_REGISTRY: dict[str, tuple[str, str]] = {
     "scheduler.decode_slot_steps": ("counter",
                                     "Per-slot decode steps (steps x active "
                                     "slots)."),
-    "scheduler.paged_prefill_disabled": ("counter",
-                                         "Paged-native prefill fallbacks."),
-    "scheduler.ragged_disabled": (
-        "counter", "Merged ragged dispatches disarmed after an on-chip "
-                   "failure (legacy two-program path takes over)."),
     "scheduler.spec_steps": ("counter", "Speculative decode steps."),
     "scheduler.spec_accepted": ("counter",
                                 "Speculative tokens accepted."),
@@ -370,10 +365,13 @@ METRIC_REGISTRY: dict[str, tuple[str, str]] = {
     "roofline.frac": ("gauge",
                       "Fraction of the aggregate HBM roofline achieved by "
                       "the most recent decode dispatch (analytical bytes "
-                      "estimate / wall time vs FEI_TPU_HBM_GBPS × chips)."),
+                      "estimate / wall time vs the device_kind's peak × "
+                      "chips). Not published on a device without a row "
+                      "in obs/costmodel.DEVICE_PEAKS."),
     "roofline.tok_s_per_chip": ("gauge",
                                 "Delivered tokens/s per chip over the most "
-                                "recent decode dispatch."),
+                                "recent decode dispatch (published with "
+                                "roofline.frac)."),
     "tenant.*.queued": ("gauge",
                         "Sequences from one tenant waiting for admission "
                         "(emitted only when tenant budgets are "

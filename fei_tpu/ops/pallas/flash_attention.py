@@ -39,12 +39,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> CompilerParams (jax 0.5); alias so
-# the kernels run on both API generations
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 NEG_INF = -1e30
 _LANES = 128  # lane width for row-stat (lse/D) outputs — Mosaic-native
 
@@ -260,7 +254,7 @@ def _fwd_impl(
         ] + ([
             jax.ShapeDtypeStruct((B, H, T_pad, _LANES), jnp.float32),
         ] if save_lse else []),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -487,7 +481,7 @@ def _bwd_impl(
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, T_pad, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -549,7 +543,7 @@ def _bwd_impl(
             jax.ShapeDtypeStruct((B, H, S_pad, D), k.dtype),
             jax.ShapeDtypeStruct((B, H, S_pad, D), v.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -625,3 +619,41 @@ def flash_attention(
     return _flash(
         scale, block_q, block_k, interpret, window, q, k, v, q_start, kv_length
     )
+
+
+def flash_attention_sharded(
+    q: jnp.ndarray,  # [B, T, H, D]
+    k: jnp.ndarray,  # [B, S, K, D]
+    v: jnp.ndarray,
+    q_start: jnp.ndarray,
+    kv_length: jnp.ndarray,
+    mesh,
+    axis_name: str = "tp",
+    window: int = 0,
+) -> jnp.ndarray:
+    """Flash attention inside a multi-device program. XLA cannot
+    auto-partition a pallas_call, so the kernel lifts through shard_map:
+    heads shard over ``axis_name`` (each device attends its own kv heads
+    and their query groups), everything else is replicated, and the head outputs all-gather INSIDE the body so the
+    result leaves replicated — the same contract, for the same
+    downstream-``wo`` summation-order reason, as
+    paged_attention._sharded_paged."""
+    from jax.sharding import PartitionSpec as P
+
+    n = mesh.shape.get(axis_name, 1)
+    heads = P(None, None, axis_name if n > 1 else None, None)
+
+    def body(q, k, v, q_start, kv_length):
+        out = flash_attention(q, k, v, q_start, kv_length, window=window)
+        if n > 1:
+            out = jax.lax.all_gather(out, axis_name, axis=2, tiled=True)
+        return out
+
+    fn = jax.shard_map(
+        body, mesh=mesh, in_specs=(heads, heads, heads, P(), P()),
+        out_specs=P(),
+        # the vma checker can't see through a pallas_call's output
+        check_vma=False,
+    )
+    return fn(q, k, v, q_start, kv_length)
+

@@ -39,15 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> CompilerParams (jax 0.5); alias so
-# the kernels run on both API generations
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 from fei_tpu.ops.quant import QTensor4, unpack4
 from fei_tpu.utils.logging import get_logger
-from fei_tpu.utils.platform import shard_map
 
 log = get_logger("ops.int4")
 
@@ -134,7 +127,7 @@ def _int4_mm_kernel(
         out_specs=pl.BlockSpec((block_m, block_n), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -272,7 +265,7 @@ def int4_mm_sharded(
     def body(x_loc, p_loc, s_loc):  # names must not shadow the pallas `pl`
         return int4_mm(x_loc, QTensor4(p=p_loc, s=s_loc))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(x_spec, w_spec, w_spec),
         out_specs=out_spec,
         check_vma=False,  # the vma checker can't see through a pallas_call

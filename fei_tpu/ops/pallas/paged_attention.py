@@ -30,14 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from fei_tpu.utils.platform import shard_map
-
-# jax renamed pltpu.TPUCompilerParams -> CompilerParams (jax 0.5); alias so
-# the kernels run on both API generations
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 NEG_INF = -1e30
 
 
@@ -205,7 +197,7 @@ def _paged_call(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, rows, D), qg.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -347,7 +339,7 @@ def _sharded_paged(
             )
         return out
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_spec,
         # the vma checker can't see through a pallas_call's output
         check_vma=False,

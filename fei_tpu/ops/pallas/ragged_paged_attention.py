@@ -48,8 +48,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from fei_tpu.ops.pallas.paged_attention import NEG_INF, _CompilerParams
-from fei_tpu.utils.platform import shard_map
+from fei_tpu.ops.pallas.paged_attention import NEG_INF
 
 
 def _ragged_kernel(
@@ -262,7 +261,7 @@ def _ragged_call(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((Bv, K, rows, D), qg.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -382,7 +381,7 @@ def ragged_paged_attention_sharded(
             )
         return out
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_spec,
         # the vma checker can't see through a pallas_call's output
         check_vma=False,

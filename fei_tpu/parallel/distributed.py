@@ -103,21 +103,14 @@ def _enable_cpu_collectives() -> None:
     touching a multi-host sharding fails with "Multiprocess computations
     aren't implemented on the CPU backend". jaxlib ships a gloo transport
     behind ``jax_cpu_collectives_implementation`` — turn it on before the
-    backend is created when the platform is explicitly CPU. Guarded: the
-    flag does not exist on every jaxlib, and a created backend rejects
-    the update (both leave TPU/GPU paths untouched)."""
+    backend is created when the platform is explicitly CPU (TPU/GPU paths
+    are untouched)."""
     platform = (
         os.environ.get("JAX_PLATFORMS", "")
         or str(getattr(jax.config, "jax_platforms", "") or "")
     )
-    if not platform.startswith("cpu"):
-        return
-    try:
+    if platform.startswith("cpu"):
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception as exc:  # noqa: BLE001 — older jaxlib or a live
-        # backend: keep going, initialize() itself may still work for
-        # coordinator-only uses
-        log.debug("cpu collectives unavailable: %s", exc)
 
 
 def process_info() -> dict:

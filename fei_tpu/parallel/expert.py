@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from fei_tpu.ops.quant import QTensor, scale_expert_out, wcast
-from fei_tpu.utils.platform import shard_map
 
 
 def _wspec(w, spec: P):
@@ -104,7 +103,7 @@ def moe_mlp_ep(
             f"ep axis size {n} must divide num_experts {E} evenly"
         )
     espec = P(axis_name)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _moe_shard, k=num_experts_per_tok, axis_name=axis_name
         ),
@@ -236,7 +235,7 @@ def moe_mlp_ep_routed(
     )
     wspec_up = P(axis_name, None, tp_axis)
     wspec_down = P(axis_name, tp_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _routed_shard,
             k=num_experts_per_tok,

@@ -35,7 +35,6 @@ from fei_tpu.ops.moe import moe_mlp
 from fei_tpu.ops.quant import mm
 from fei_tpu.ops.rope import compute_rope_freqs
 from fei_tpu.parallel.ring import _ring_attention_shard, _ulysses_shard
-from fei_tpu.utils.platform import shard_map
 
 
 def _prefill_shard(
@@ -134,7 +133,7 @@ def prefill_ring_kv(
     cos, sin = compute_rope_freqs(cfg.rope_dim_, T, cfg.rope_theta)
     x = embed_tokens(params, cfg, tokens, dtype)  # [B, T, H] (seq-sharded in)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _prefill_shard, cfg=cfg, axis_name=axis_name, attend=attend
         ),

@@ -28,7 +28,6 @@ from fei_tpu.models.llama import (
     _layer, _logits, _norm, embed_tokens, model_dtype,
 )
 from fei_tpu.ops.rope import compute_rope_freqs
-from fei_tpu.utils.platform import pcast, shard_map
 
 
 def _stage_apply(cfg: ModelConfig, local_layers: dict, x, positions, cos, sin):
@@ -59,8 +58,8 @@ def _pipeline_shard(
     M = xs.shape[0]
     perm = [(i, i + 1) for i in range(n - 1)]  # stage i -> i+1
 
-    recv0 = pcast(jnp.zeros_like(xs[0]), axis_name, to="varying")
-    outs0 = pcast(jnp.zeros_like(xs), axis_name, to="varying")
+    recv0 = jax.lax.pcast(jnp.zeros_like(xs[0]), axis_name, to="varying")
+    outs0 = jax.lax.pcast(jnp.zeros_like(xs), axis_name, to="varying")
 
     def body(s, carry):
         recv, outs = carry
@@ -119,7 +118,7 @@ def pipeline_forward_train(
     xs = x.reshape(num_micro, mb, T, -1)
 
     layer_specs = jax.tree.map(lambda _: P(axis_name), params["layers"])
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_pipeline_shard, cfg=cfg, axis_name=axis_name),
         mesh=mesh,
         in_specs=(layer_specs, P(), P(), P(), P()),

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from fei_tpu.utils.platform import pcast, shard_map
-
 NEG_INF = -1e30
 
 
@@ -90,11 +88,12 @@ def _ring_attention_shard(
     q_pos = my_idx * C + jnp.arange(C)
 
     # init state is device-varying (the loop writes per-device values into it)
-    m0 = pcast(
-        jnp.full((B, C, H, 1), NEG_INF, dtype=jnp.float32), axis_name, to="varying"
-    )
-    l0 = pcast(jnp.zeros((B, C, H, 1), dtype=jnp.float32), axis_name, to="varying")
-    acc0 = pcast(jnp.zeros((B, C, H, D), dtype=jnp.float32), axis_name, to="varying")
+    def varying(x):
+        return jax.lax.pcast(x, axis_name, to="varying")
+
+    m0 = varying(jnp.full((B, C, H, 1), NEG_INF, dtype=jnp.float32))
+    l0 = varying(jnp.zeros((B, C, H, 1), dtype=jnp.float32))
+    acc0 = varying(jnp.zeros((B, C, H, D), dtype=jnp.float32))
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def body(step, carry):
@@ -154,7 +153,7 @@ def ring_attention(
     if scale is None:
         scale = D ** -0.5
     spec = P(None, axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ring_attention_shard, axis_name=axis_name, scale=scale,
             window=window,
@@ -218,7 +217,7 @@ def ulysses_attention(
     if scale is None:
         scale = D ** -0.5
     spec = P(None, axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ulysses_shard, axis_name=axis_name, scale=scale, window=window
         ),

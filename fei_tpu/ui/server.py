@@ -259,7 +259,8 @@ class ServeAPI:
             mesh = self._mesh_tag()
             load = self._load_fields()
             base = {"model": self.model_name, "mesh": mesh,
-                    "role": self.role, **self._kv_geometry(), **load}
+                    "role": self.role, **self._device(),
+                    **self._kv_geometry(), **load}
             if self._draining():
                 # a draining replica must leave the load-balancer rotation
                 # while its in-flight set finishes
@@ -425,6 +426,17 @@ class ServeAPI:
 
         eng = getattr(self.provider, "engine", None)
         return mesh_tag(getattr(eng, "mesh", None))
+
+    def _device(self) -> dict:
+        """``platform`` / ``device_kind`` / ``device_count`` of the device
+        the backing engine runs on, as JAX reports them — a replica that
+        came up on the CPU of a chip machine says so here. Empty for
+        non-engine providers, which hold no device."""
+        if getattr(self.provider, "engine", None) is None:
+            return {}
+        from fei_tpu.utils.platform import device_info
+
+        return device_info()
 
     def _kv_geometry(self) -> dict:
         """Both halves of the KV pool geometry on /health — the
@@ -991,8 +1003,11 @@ def main(argv: list[str] | None = None) -> int:
     args = p.parse_args(argv)
 
     from fei_tpu.agent.providers import JaxLocalProvider
+    from fei_tpu.utils.platform import device_info, device_memory_in_use
 
     provider = JaxLocalProvider(model=args.model)
+    log.info("engine built on %s; bytes in use per device: %s",
+             device_info(), device_memory_in_use())
     api = ServeAPI(
         provider,
         model_name=provider.engine.cfg.name,

@@ -1,5 +1,6 @@
 #!/usr/bin/env python
-"""Chaos crash smoke: kill -9 real ``fei serve`` processes mid-stream.
+"""Chaos crash smoke (a CPU smoke): kill -9 real ``fei serve`` processes
+mid-stream.
 
 The only test in the tree where a replica dies as a PROCESS, not a
 monkeypatch. Two tiny ``fei serve`` subprocesses (session journal armed,
@@ -23,9 +24,9 @@ sockets:
    byte-identical (the PRNG key chain survived the crash);
 4. B's journal dir, rebooted, recovers the torn seeded session too.
 
-``FEI_TPU_CRASH_SMOKE_MODE=reshard`` (the ``chaos_reshard`` pipeline
-stage) runs the MESH-SHRINK scene instead — the common TPU failure
-where a chip or ICI link dies and the replica re-forms smaller:
+``FEI_TPU_CRASH_SMOKE_MODE=reshard`` runs the MESH-SHRINK scene instead —
+the common TPU failure where a chip or ICI link dies and the replica
+re-forms smaller:
 
 1. a ``FEI_TPU_MESH=tp2`` serve (two forced host devices) and a
    single-chip survivor boot side by side; their /health pages must
@@ -40,8 +41,8 @@ where a chip or ICI link dies and the replica re-forms smaller:
    recovery (``engine.cross_mesh_recoveries``) — mesh is provenance,
    page_size is the only gate (docs/ENGINE.md "Mesh elasticity").
 
-Runs on CPU by design: several serve processes cannot share one
-accelerator, and everything under test (WAL, resurrection ledger,
+Runs on the CPU by design — both children are pinned to it: a chip belongs
+to one process, and everything under test (WAL, resurrection ledger,
 teacher-forced resume) is host-side. Exit 0 clean, non-zero with a
 reason on stderr — same contract as fleet_smoke.py.
 """
@@ -82,7 +83,7 @@ def _spawn(name: str, port: int, jdir: str, log_path: str,
            fault: str = "",
            extra_env: dict | None = None) -> subprocess.Popen:
     env = dict(os.environ)
-    # scrub knobs meant for OTHER smokes; the pipeline chaos sweep must
+    # scrub knobs meant for OTHER smokes; a chaos sweep must
     # not leak a fault (or a mesh/tier shape) into a replica that is
     # supposed to boot plain
     for k in list(env):

@@ -17,7 +17,7 @@ claims on CPU, no ports, no subprocesses:
    both replicas while streaming load keeps flowing; zero streams that
    had tokens flowing die mid-stream, and every request still completes.
 
-The rehearse/on-chip pipelines also re-run this file with FEI_TPU_FAULT
+A chaos sweep re-runs this file with FEI_TPU_FAULT
 sweeping ``router.forward:{conn,http503,hang}`` and ``replica.health:
 conn`` — the retry/breaker/force-reprobe paths must absorb each kind
 with no assertion weakened (the env-armed counts are below the breaker
@@ -52,7 +52,7 @@ def kv_main() -> int:
     the pool actually preempted; and — without injected chaos — every
     resume streamed pages back (``kv.pages_restored`` moved,
     ``kv.fetch_fallbacks`` and ``preempted_tokens_recomputed`` did not).
-    The pipelines re-run this mode with FEI_TPU_FAULT sweeping
+    A chaos sweep re-runs this mode with FEI_TPU_FAULT sweeping
     ``kv.spill``/``kv.fetch`` — under chaos the tier is ALLOWED to fall
     back to token replay, but a failed fetch must still complete every
     request (fallback, never wedge)."""
@@ -164,8 +164,8 @@ def kvcdn_main() -> int:
     (``kv.prefix_hits_remote``), and r1 admits over fetched bytes
     (``kv.prefix_hits_tier``) instead of re-prefilling. Phase 3 rolls
     the fleet and asserts speculative pre-warm pushed hot prefixes into
-    the restarted replicas (``router.prewarm_pushes``). The pipelines
-    re-run this mode with FEI_TPU_FAULT sweeping ``kv.fetch`` — under
+    the restarted replicas (``router.prewarm_pushes``). A chaos sweep
+    re-runs this mode with FEI_TPU_FAULT sweeping ``kv.fetch`` — under
     chaos every CDN rung is ALLOWED to fall back to plain prefill, but
     every request must still reach 200 (degrade, never wedge)."""
     import os

@@ -15,7 +15,7 @@ validates the Chrome-trace JSON:
 - the preempt instant made it onto the timeline;
 - ``GET /v1/traces/<id>`` returns the trace plus its flight slice.
 
-Runs on CPU (rehearse pipeline) or TPU (on-chip pipeline) unchanged.
+Runs on whatever platform JAX comes up on.
 Exit status: 0 clean, non-zero with a reason on stderr.
 """
 

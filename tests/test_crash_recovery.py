@@ -22,8 +22,8 @@ The claims under test (docs/ENGINE.md "Crash consistency", docs/FLEET.md
 - the ``crash`` fault kind is a delay fuse (fires SIGKILL on the Nth
   check), and the snapshot writer fsyncs file and directory.
 
-The real kill -9 over real processes is scripts/crash_smoke.py (the
-``chaos_crash`` pipeline stage); here the engine dies by losing
+The real kill -9 over real processes is scripts/crash_smoke.py; here
+the engine dies by losing
 everything except its journal directory, and replicas die by dropping
 their transport mid-stream — same recovery surface, hermetic and fast.
 """
@@ -35,7 +35,6 @@ import shutil
 
 import pytest
 
-from conftest import requires_shard_map
 from fei_tpu.agent.providers import JaxLocalProvider
 from fei_tpu.engine import faults as faults_mod
 from fei_tpu.engine.engine import GenerationConfig, InferenceEngine
@@ -227,7 +226,6 @@ class TestJournalReplay:
             eng.close()
 
 
-@requires_shard_map
 class TestJournalReplayTp2:
     """The same identity proof with decode dispatched through the
     shard_map'd kernel on a 2-way tensor-parallel mesh. Slow lane: the

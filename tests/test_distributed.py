@@ -36,7 +36,6 @@ _WORKER = textwrap.dedent("""
     jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, "@@REPO@@")
     from fei_tpu.parallel import distributed as dist
-    from fei_tpu.utils.platform import shard_map
 
     ok = dist.initialize()  # env-driven: FEI_TPU_COORDINATOR / _NUM_PROCESSES / _PROCESS_ID
     info = dist.process_info()
@@ -51,7 +50,7 @@ _WORKER = textwrap.dedent("""
         rank = jax.lax.axis_index("dp")
         return jax.lax.psum(v * (rank + 1), "dp")
 
-    out = jax.jit(shard_map(
+    out = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp")
     ))(x)
     total = float(out.addressable_shards[0].data[0])

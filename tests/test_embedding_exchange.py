@@ -5,8 +5,6 @@ import jax
 import numpy as np
 import pytest
 
-from conftest import requires_shard_map
-
 from fei_tpu.memory.memorychain.embedding_exchange import (
     EmbeddingFederation,
     exchange_banks,
@@ -41,7 +39,6 @@ def node_mesh():
 
 
 class TestExchange:
-    @requires_shard_map
     def test_all_gather_gives_every_node_every_bank(self, node_mesh):
         n = node_mesh.shape["dp"]
         rng = np.random.default_rng(0)
@@ -53,7 +50,6 @@ class TestExchange:
 
 
 class TestFederation:
-    @requires_shard_map
     def test_cross_node_recall(self, node_mesh):
         n = node_mesh.shape["dp"]
         feds = [
@@ -102,7 +98,6 @@ class TestFederation:
 
 
 class TestMultiNodePerDevice:
-    @requires_shard_map
     def test_more_nodes_than_devices(self, node_mesh):
         """num_nodes = 2x devices: no bank may be dropped."""
         n = node_mesh.shape["dp"]

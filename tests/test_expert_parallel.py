@@ -5,8 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_shard_map
-
 from fei_tpu.ops.moe import moe_mlp, moe_mlp_routed
 from fei_tpu.parallel.expert import (
     expert_flops_share,
@@ -15,7 +13,6 @@ from fei_tpu.parallel.expert import (
     routed_capacity,
 )
 from fei_tpu.parallel.mesh import make_mesh
-from fei_tpu.utils.platform import shard_map
 
 
 def _setup(key, B, T, H, I, E):
@@ -35,7 +32,6 @@ def ep_mesh():
 
 
 class TestExpertParallel:
-    @requires_shard_map
     def test_matches_dense(self, ep_mesh):
         n = ep_mesh.shape["ep"]
         x, router, wg, wu, wd = _setup(jax.random.PRNGKey(0), 2, 8, 32, 64, 2 * n)
@@ -43,7 +39,6 @@ class TestExpertParallel:
         got = moe_mlp_ep(x, router, wg, wu, wd, 2, ep_mesh)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
 
-    @requires_shard_map
     def test_top1_routing(self, ep_mesh):
         n = ep_mesh.shape["ep"]
         x, router, wg, wu, wd = _setup(jax.random.PRNGKey(1), 1, 4, 16, 32, n)
@@ -51,7 +46,6 @@ class TestExpertParallel:
         got = moe_mlp_ep(x, router, wg, wu, wd, 1, ep_mesh)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
 
-    @requires_shard_map
     def test_jit_compiles(self, ep_mesh):
         n = ep_mesh.shape["ep"]
         x, router, wg, wu, wd = _setup(jax.random.PRNGKey(2), 1, 4, 16, 32, n)
@@ -113,7 +107,6 @@ class TestRoutedExpertParallel:
     """GShard-style token-routed EP: dispatch/combine masks + two
     all_to_alls over the ep axis (SURVEY.md hard part #2)."""
 
-    @requires_shard_map
     def test_dropless_matches_dense(self, ep_mesh):
         n = ep_mesh.shape["ep"]
         x, router, wg, wu, wd = _setup(jax.random.PRNGKey(0), 2, 8, 32, 64, 2 * n)
@@ -121,7 +114,6 @@ class TestRoutedExpertParallel:
         got = moe_mlp_ep_routed(x, router, wg, wu, wd, 2, ep_mesh, dropless=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
 
-    @requires_shard_map
     def test_dropless_top1(self, ep_mesh):
         n = ep_mesh.shape["ep"]
         x, router, wg, wu, wd = _setup(jax.random.PRNGKey(1), 1, 8, 16, 32, n)
@@ -129,7 +121,6 @@ class TestRoutedExpertParallel:
         got = moe_mlp_ep_routed(x, router, wg, wu, wd, 1, ep_mesh, dropless=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
 
-    @requires_shard_map
     def test_uneven_tokens_padded(self, ep_mesh):
         """B*T not divisible by the ep axis: padding rows must route
         nowhere and consume no capacity."""
@@ -176,7 +167,6 @@ class TestRoutedExpertParallel:
                     out[r] += w[i, choice] * (act @ wd[e])
         return out.reshape(B, T, H)
 
-    @requires_shard_map
     def test_capacity_drops_match_reference(self, ep_mesh):
         """Tight capacity: kept/dropped assignments must match an
         independent numpy model of the drop rule, not just stay finite."""
@@ -189,7 +179,7 @@ class TestRoutedExpertParallel:
         n = ep_mesh.shape["ep"]
         x, router, wg, wu, wd = _setup(jax.random.PRNGKey(3), 2, 8, 32, 64, 2 * n)
         C = 2  # well below the dropless worst case of B*T/n tokens
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(_routed_shard, k=2, capacity=C, axis_name="ep"),
             mesh=ep_mesh,
             in_specs=(P(), P(), P("ep"), P("ep"), P("ep")),
@@ -203,7 +193,6 @@ class TestRoutedExpertParallel:
         )
         np.testing.assert_allclose(np.asarray(got), want, atol=2e-4)
 
-    @requires_shard_map
     def test_jit_compiles(self, ep_mesh):
         n = ep_mesh.shape["ep"]
         x, router, wg, wu, wd = _setup(jax.random.PRNGKey(4), 2, 8, 32, 64, 2 * n)
@@ -229,7 +218,6 @@ class TestRoutedExpertParallel:
     def test_routed_capacity_floor(self):
         assert routed_capacity(1, 64, 1, 1.0) == 1
 
-    @requires_shard_map
     def test_meshed_moe_engine_end_to_end(self, ep_mesh, monkeypatch):
         """Mixtral-architecture engine on an ep mesh: prefill + decode run
         with token-routed EP inside the jitted programs and emit the same
@@ -254,7 +242,6 @@ class TestRoutedExpertParallel:
         got = sharded.generate(prompt, gen).token_ids
         assert got == want
 
-    @requires_shard_map
     def test_meshed_moe_engine_default_capacity(self, ep_mesh):
         """Default serving capacity (factor 2.0): generation completes and
         per-device expert FLOPs are bounded by 2k/E of dense."""

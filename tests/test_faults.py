@@ -350,12 +350,12 @@ class TestDeadlines:
 
 @pytest.mark.skipif(
     not os.environ.get("FEI_TPU_FAULT"),
-    reason="chaos sweep only: set FEI_TPU_FAULT (scripts/*_pipeline.sh)",
+    reason="chaos sweep only: set FEI_TPU_FAULT",
 )
 def test_env_fault_sweep_recovers():
     """Under ANY env-armed engine fault the engine must (a) fail requests
     with typed errors only and (b) serve normally once the fault drains.
-    The pipeline chaos stages sweep FEI_TPU_FAULT across kinds/points."""
+    A chaos sweep arms FEI_TPU_FAULT across kinds/points."""
     FAULTS.load_env()  # the autouse disarm cleared the import-time arming
     eng = _make()
     gen = _gen(max_new_tokens=8)

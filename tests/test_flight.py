@@ -341,9 +341,11 @@ class TestSchedulerFlight:
                        if r["name"] == "resume")
         assert resumed["tags"]["generated"] >= 1
 
-    def test_roofline_gauges_live(self, flown):
-        assert _gauge("roofline.frac") > 0
-        assert _gauge("roofline.tok_s_per_chip") > 0
+    def test_no_roofline_gauges_off_the_peak_table(self, flown):
+        # the CPU has no row in DEVICE_PEAKS: nothing is published
+        gauges = METRICS.snapshot()["gauges"]
+        assert "roofline.frac" not in gauges
+        assert "roofline.tok_s_per_chip" not in gauges
 
     def test_timeline_endpoint_end_to_end(self, flown):
         from fei_tpu.ui.server import ServeAPI
@@ -411,18 +413,38 @@ class TestCostModel:
             2 * engine.cfg.num_active_params(), rel=0.10
         )
 
-    def test_roofline_fraction(self, monkeypatch):
-        monkeypatch.setenv("FEI_TPU_HBM_GBPS", "100")
-        assert costmodel.hbm_gbps() == 100.0
-        assert costmodel.roofline_fraction(int(50e9), 1.0) == (
+    def test_roofline_fraction(self):
+        assert costmodel.roofline_fraction(int(50e9), 1.0, 100.0) == (
             pytest.approx(0.5)
         )
-        assert costmodel.roofline_fraction(int(50e9), 1.0, n_chips=2) == (
-            pytest.approx(0.25)
+        assert costmodel.roofline_fraction(
+            int(50e9), 1.0, 100.0, n_chips=2
+        ) == pytest.approx(0.25)
+        assert costmodel.roofline_fraction(int(50e9), 0.0, 100.0) == 0.0
+
+    def test_peak_table_known_kind_publishes_unknown_does_not(
+        self, engine, monkeypatch
+    ):
+        from fei_tpu.obs import metrics
+
+        # a private registry: the process-wide one must stay free of
+        # roofline gauges for test_no_roofline_gauges_off_the_peak_table
+        own = metrics.Metrics()
+        monkeypatch.setattr(metrics, "METRICS", own)
+        assert costmodel.device_peaks() is None  # the CPU is not a row
+        costmodel.account_dispatch(engine, 1, 10, 1, 0.01)
+        assert own.snapshot()["gauges"] == {}
+
+        v5e = costmodel.DEVICE_PEAKS["TPU v5 lite"]
+        assert v5e == {"hbm_gbps": 819.0, "bf16_tflops": 197.0}
+        monkeypatch.setattr(costmodel, "device_peaks", lambda: v5e)
+        costmodel.account_dispatch(engine, 1, 10, 1, 0.01)
+        est = costmodel.dispatch_bytes(engine, 1, 10, 1)
+        gauges = own.snapshot()["gauges"]
+        assert gauges["roofline.frac"] == pytest.approx(
+            est / 0.01 / 819e9, rel=1e-3
         )
-        assert costmodel.roofline_fraction(int(50e9), 0.0) == 0.0
-        monkeypatch.setenv("FEI_TPU_HBM_GBPS", "bogus")
-        assert costmodel.hbm_gbps() == costmodel.V5E_HBM_GBPS
+        assert gauges["roofline.tok_s_per_chip"] == pytest.approx(100.0)
 
     def test_chips_for_tag(self):
         assert costmodel.chips_for_tag(None) == 1

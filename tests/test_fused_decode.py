@@ -133,7 +133,9 @@ def test_fused_fn_early_exit_stops_kv_writes(engine):
     fed, and the carry token repeats through the ys."""
     prompt = engine.tokenizer.encode("device stop", add_bos=True)
     full = _ref_tokens(engine, prompt, 12)
-    j = 3  # the fused chunk samples full[1:] — stop lands at scan step j
+    # the fused chunk samples full[1:] — the stop lands at scan step j, the
+    # first (from 3) whose token the scan has not sampled earlier
+    j = next(j for j in range(3, 10) if full[1 + j] not in full[1:1 + j])
     stop_at = full[1 + j]
     gen = GenerationConfig(max_new_tokens=12, stop_token_ids=(stop_at,))
     tok, cache, rng = engine._prefill_sample(prompt, gen)

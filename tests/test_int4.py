@@ -192,8 +192,8 @@ class TestEngineInt4:
     # dequantize-then-single-dot were never bitwise-equal; on CPU XLA
     # the tiny model's logit gap is ~1 bf16 ulp and commit a48a9e0
     # (per-layer lax.map init draws) landed weights where the rounding
-    # difference flips the argmax mid-stream. The identity holds under
-    # Mosaic on TPU, where the onchip pipeline's kernels stage runs it.
+    # difference flips the argmax mid-stream. It runs off the CPU only
+    # (`JAX_PLATFORMS=tpu pytest`); ROADMAP S4 has what the chip said last.
     @pytest.mark.skipif(
         jax.default_backend() == "cpu",
         reason="int4 kernel/oracle parity needs TPU Mosaic rounding; "
@@ -332,9 +332,8 @@ class TestEngineInt4:
 
 @pytest.mark.slow  # fast lane: -m 'not slow'
 class TestInt4Mesh:
-    """Mesh composition tests — need multiple devices (the on-chip pipeline
-    runs this file against the single real chip: these must skip, not
-    error, there)."""
+    """Mesh composition tests — need multiple devices (run against a
+    single chip these must skip, not error)."""
 
     @pytest.fixture(autouse=True)
     def _needs_devices(self):

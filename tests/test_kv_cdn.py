@@ -37,7 +37,6 @@ import time
 import numpy as np
 import pytest
 
-from conftest import requires_shard_map
 from fei_tpu.engine.engine import GenerationConfig, InferenceEngine
 from fei_tpu.engine.faults import FAULTS
 from fei_tpu.fleet import Router
@@ -420,13 +419,11 @@ class TestCasAdmitByteIdentity:
             eng.close()
 
 
-@requires_shard_map
 class TestCasAdmitTp2:
     """The same fetch-and-scatter identity with decode on a 2-way
     tensor-parallel mesh (replicated weights keep tp2 token-identical to
     single-chip, so the ms1 references bind here too). Slow lane: the
-    tp2 compile dominates tier-1's budget; runs FOR REAL in
-    rehearse_pipeline's kvcdn stage."""
+    tp2 compile dominates tier-1's budget."""
 
     @pytest.mark.slow
     def test_tp2_fetched_prefix_byte_identical(self, cdn_ref):

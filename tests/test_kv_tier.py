@@ -28,7 +28,6 @@ import threading
 import numpy as np
 import pytest
 
-from conftest import requires_shard_map
 from fei_tpu.engine.engine import GenerationConfig, InferenceEngine
 from fei_tpu.engine.faults import FAULTS
 from fei_tpu.fleet import Router
@@ -290,14 +289,12 @@ class TestSpillRestoreByteIdentity:
             eng.close()
 
 
-@requires_shard_map
 class TestSpillRestoreTp2:
     """The same identity proof with decode dispatched through the
     shard_map'd kernel on a 2-way tensor-parallel mesh: gathered pages
     must reassemble and scatter back correctly across shards. Slow lane:
     the tp2 compile dominates tier-1's budget (same policy as
-    test_sharded_serving); runs FOR REAL in rehearse_pipeline's kv_tier
-    stage."""
+    test_sharded_serving)."""
 
     @pytest.mark.slow
     @pytest.mark.parametrize("seeded", [False, True],

@@ -108,12 +108,11 @@ class TestPagedNativePrefill:
         got = list(native.scheduler.stream(prompt, GEN))
         assert got == want
 
-    def test_kernel_failure_falls_back_to_staging(self, monkeypatch):
+    def test_kernel_failure_is_a_typed_device_error(self, monkeypatch):
         """A compile-stage failure of the native chunk program (the
-        realistic Mosaic-rejection case) must not kill the streams: the
-        admission restarts on the dense-staging path, permanently."""
-        legacy = _engine(monkeypatch, native=False)
-        want = list(legacy.scheduler.stream(PROMPT, GEN))
+        realistic Mosaic-rejection case) fails the request with the typed
+        DeviceError: nothing switches the scheduler to another path."""
+        from fei_tpu.utils.errors import DeviceError
 
         native = _engine(monkeypatch, native=True)
 
@@ -124,12 +123,9 @@ class TestPagedNativePrefill:
             return fn
 
         monkeypatch.setattr(native.scheduler, "_paged_chunk_fn", boom)
-        got = list(native.scheduler.stream(PROMPT, GEN))
-        assert got == want
-        assert native.scheduler.paged_native_prefill is False
-        # and the NEXT admission goes straight to staging
-        got2 = list(native.scheduler.stream(PROMPT, GEN))
-        assert got2 == want
+        with pytest.raises(DeviceError, match="Mosaic said no"):
+            list(native.scheduler.stream(PROMPT, GEN))
+        assert native.scheduler.paged_native_prefill is True
 
     def test_near_capacity_prompt_with_prefix_pads_hit_null_page(
         self, monkeypatch
@@ -151,28 +147,6 @@ class TestPagedNativePrefill:
         n2 = list(native.scheduler.stream(prompt, gen))  # prefix-hit run
         assert n1 == l1
         assert n2 == l2
-
-    def test_kernel_failure_with_prefix_requeues(self, monkeypatch):
-        """First-chunk failure on a PREFIX-HIT admission must also flip
-        the flag and requeue — not fail this request forever."""
-        legacy = _engine(monkeypatch, native=False, prefix_cache=True)
-        w1 = list(legacy.scheduler.stream(PROMPT, GEN))
-        w2 = list(legacy.scheduler.stream(PROMPT, GEN))
-
-        native = _engine(monkeypatch, native=True, prefix_cache=True)
-        first = list(native.scheduler.stream(PROMPT, GEN))  # native admit
-        assert first == w1
-
-        def boom(C, final):
-            def fn(*a, **k):
-                raise RuntimeError("Mosaic said no")
-
-            return fn
-
-        monkeypatch.setattr(native.scheduler, "_paged_chunk_fn", boom)
-        second = list(native.scheduler.stream(PROMPT, GEN))  # prefix hit
-        assert second == w2
-        assert native.scheduler.paged_native_prefill is False
 
 
 class TestSchedulerLifecycle:

@@ -10,8 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_shard_map
-
 from fei_tpu.ops.attention import attention
 from fei_tpu.ops.pallas import flash_attention, paged_attention
 
@@ -309,7 +307,6 @@ class TestPagedBlockAttention:
         )
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=_atol())
 
-    @requires_shard_map
     def test_sharded_matches_local(self):
         from fei_tpu.ops.pallas.paged_attention import (
             paged_attention_block,

@@ -13,8 +13,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_shard_map
-
 from fei_tpu.engine import GenerationConfig, InferenceEngine
 
 GEN = GenerationConfig(max_new_tokens=10, temperature=0.0, ignore_eos=True)
@@ -78,7 +76,6 @@ class TestTinyPhiServing:
 
 
 class TestTinyPhiParallelism:
-    @requires_shard_map
     def test_pipeline_forward_matches_dense(self):
         """The parallel block through the pp pipeline (GPipe stages call
         the same _layer body)."""

@@ -5,8 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_shard_map
-
 from fei_tpu.models.configs import get_model_config
 from fei_tpu.models.llama import forward_train, init_params
 from fei_tpu.parallel.mesh import make_mesh
@@ -23,7 +21,6 @@ def setup():
 
 
 class TestPipeline:
-    @requires_shard_map
     def test_matches_dense_forward(self, setup):
         mesh, cfg, params = setup
         tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab_size)
@@ -31,7 +28,6 @@ class TestPipeline:
         got = pipeline_forward_train(params, cfg, tokens, mesh, num_micro=2)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-3)
 
-    @requires_shard_map
     def test_single_microbatch(self, setup):
         mesh, cfg, params = setup
         tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 8), 0, cfg.vocab_size)
@@ -39,7 +35,6 @@ class TestPipeline:
         got = pipeline_forward_train(params, cfg, tokens, mesh, num_micro=1)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-3)
 
-    @requires_shard_map
     def test_micro_equals_batch(self, setup):
         mesh, cfg, params = setup
         tokens = jax.random.randint(jax.random.PRNGKey(3), (4, 8), 0, cfg.vocab_size)

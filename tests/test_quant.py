@@ -11,8 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_shard_map
-
 from fei_tpu.models.configs import get_model_config
 from fei_tpu.models.llama import KVCache, forward, init_params
 from fei_tpu.ops.quant import (
@@ -152,7 +150,6 @@ class TestQuantizedMoEPaths:
         ref = moe_mlp(x, router, wg, wu, wd, 2)
         assert np.abs(np.asarray(want) - np.asarray(ref)).max() < 0.05
 
-    @requires_shard_map
     def test_ep_routed_quantized(self):
         from fei_tpu.ops.moe import moe_mlp
         from fei_tpu.parallel.expert import moe_mlp_ep, moe_mlp_ep_routed

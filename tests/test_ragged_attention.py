@@ -14,8 +14,8 @@ The claims under test (docs/ENGINE.md "Decode dispatch model"):
   ``dispatch.prefill_chunk`` — the chunk-record count dropping under
   overlap is the measured dispatch reduction, and the flight-recorder
   dispatch.step identity from test_flight survives the merge;
-- fallback: a failing merged program disarms the path for the engine's
-  lifetime and the streams finish on the legacy programs, token-equal.
+- failure: a merged program that fails to compile fails the streams with
+  the typed DeviceError; nothing disarms the path.
 """
 
 from __future__ import annotations
@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-from conftest import requires_shard_map
 
 from fei_tpu.engine.engine import GenerationConfig, InferenceEngine
 from fei_tpu.obs import FLIGHT
@@ -180,7 +178,6 @@ class TestRaggedKernel:
         )[:, 0]
         _assert_rows(got, want, "windowed decode rows diverged")
 
-    @requires_shard_map
     def test_sharded_matches_local(self):
         from fei_tpu.ops.pallas.ragged_paged_attention import (
             ragged_paged_attention_sharded,
@@ -373,13 +370,12 @@ class TestMergedDispatch:
         finally:
             eng.scheduler.close()
 
-    def test_merged_failure_disarms_and_falls_back(
-        self, legacy_refs, monkeypatch
-    ):
+    def test_merged_failure_is_a_typed_device_error(self, monkeypatch):
         """A trace/compile-stage failure of the merged program (the
-        realistic Mosaic-rejection case) must not kill the streams: the
-        chunk re-stashes, flushes solo, and the engine finishes on the
-        legacy programs — permanently."""
+        realistic Mosaic-rejection case) fails the streams with the typed
+        DeviceError: the merged path is never disarmed behind the user."""
+        from fei_tpu.utils.errors import DeviceError
+
         eng = _engine("ragged")
         try:
             def boom(n, C, final, grammared):
@@ -388,12 +384,22 @@ class TestMergedDispatch:
                 return fn
 
             monkeypatch.setattr(eng.scheduler, "_ragged_fn", boom)
-            r0 = _counter("scheduler.ragged_disabled")
-            live, long_, _ = _overlap(eng, GEN_LIVE, GEN_LONG)
-            assert live == legacy_refs["live"]
-            assert long_ == legacy_refs["long"]
-            assert eng.scheduler.ragged_attention is False
-            assert _counter("scheduler.ragged_disabled") == r0 + 1
+            seen = []
+
+            def live():
+                try:
+                    list(eng.scheduler.stream(LIVE, GEN_LIVE))
+                except DeviceError as exc:
+                    seen.append(exc)
+
+            t = threading.Thread(target=live)
+            t.start()
+            with pytest.raises(DeviceError, match="Mosaic said no"):
+                # admitted while LIVE decodes: its chunk rides a scan
+                list(eng.scheduler.stream(LONG, GEN_LONG))
+            t.join(timeout=120)
+            assert not t.is_alive() and seen, "the live stream did not fail"
+            assert eng.scheduler.ragged_attention is True
         finally:
             eng.scheduler.close()
 
@@ -408,13 +414,12 @@ class TestMergedDispatch:
             os.environ.pop("FEI_TPU_ATTENTION", None)
 
 
-@pytest.mark.slow  # pipeline `ragged` stage; tier-1 carries the fast pins
+@pytest.mark.slow  # tier-1 carries the fast pins
 class TestRaggedSlow:
     def test_tp2_overlap_identity(self):
         """The mesh composition claim: the merged program all-gathers kv
         heads inside shard_map exactly like the legacy kernel, so tp2
         tokens match the legacy tp2 engine under overlap."""
-        pytest.importorskip("jax.experimental.shard_map")
         if len(jax.devices()) < 2:
             pytest.skip("needs 2 devices")
         old = os.environ.get("FEI_TPU_MESH")

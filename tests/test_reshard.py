@@ -35,7 +35,6 @@ import shutil
 import numpy as np
 import pytest
 
-from conftest import requires_shard_map
 from fei_tpu.kv.pagesio import (
     canonicalize_arrays,
     check_fingerprint,
@@ -286,7 +285,6 @@ class TestImportErrorLadder:
 # -- end to end across real unequal meshes (slow lane) ---------------------
 
 
-@requires_shard_map
 class TestCrossMeshEndToEnd:
     """tp2 state recovers on a single chip. Slow lane: each tp2 engine
     pays its shard_map compile on the CPU mesh (test_sharded_serving

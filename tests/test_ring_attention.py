@@ -6,8 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_shard_map
-
 from fei_tpu.ops.attention import attention
 from fei_tpu.parallel.mesh import make_mesh
 from fei_tpu.parallel.ring import ring_attention, ulysses_attention
@@ -36,7 +34,6 @@ def sp_mesh():
 
 
 class TestRingAttention:
-    @requires_shard_map
     def test_matches_oracle(self, sp_mesh):
         n = sp_mesh.shape["sp"]
         B, T, H, K, D = 2, 16 * n, 4, 2, 32
@@ -45,7 +42,6 @@ class TestRingAttention:
         got = ring_attention(q, k, v, sp_mesh)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-3)
 
-    @requires_shard_map
     def test_mqa(self, sp_mesh):
         """Single shared KV head (multi-query attention)."""
         n = sp_mesh.shape["sp"]
@@ -55,7 +51,6 @@ class TestRingAttention:
         got = ring_attention(q, k, v, sp_mesh)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-3)
 
-    @requires_shard_map
     def test_sliding_window_matches_oracle(self, sp_mesh):
         """Window smaller than one shard's chunk: most ring steps visit
         chunks that are entirely dead for most rows — full-causal CANNOT
@@ -70,7 +65,6 @@ class TestRingAttention:
                 np.asarray(got), np.asarray(want), atol=2e-3
             )
 
-    @requires_shard_map
     def test_jit_compiles(self, sp_mesh):
         n = sp_mesh.shape["sp"]
         B, T, H, K, D = 1, 4 * n, 2, 2, 16
@@ -86,7 +80,6 @@ class TestRingAttention:
 
 
 class TestUlysses:
-    @requires_shard_map
     def test_matches_oracle(self, sp_mesh):
         n = sp_mesh.shape["sp"]
         B, T, D = 2, 4 * n, 32
@@ -96,7 +89,6 @@ class TestUlysses:
         got = ulysses_attention(q, k, v, sp_mesh)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-3)
 
-    @requires_shard_map
     def test_sliding_window_matches_oracle(self, sp_mesh):
         n = sp_mesh.shape["sp"]
         B, T, D = 2, 4 * n, 32

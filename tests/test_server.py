@@ -249,6 +249,23 @@ class TestProtocolShape:
 
 
 class TestLocalEngineServing:
+    def test_health_names_the_device(self, local_server, mock_server):
+        """/health says which device the engine came up on, as JAX reports
+        it; a provider without an engine holds no device and says none."""
+        import jax
+
+        with urllib.request.urlopen(
+            f"http://127.0.0.1:{local_server.port}/health", timeout=10
+        ) as r:
+            health = json.loads(r.read())
+        assert health["platform"] == "cpu"
+        assert health["device_kind"] == jax.devices()[0].device_kind
+        assert health["device_count"] == len(jax.devices())
+        with urllib.request.urlopen(
+            f"http://127.0.0.1:{mock_server[0].port}/health", timeout=10
+        ) as r:
+            assert "platform" not in json.loads(r.read())
+
     def test_completion_and_stream_agree(self, local_server):
         msgs = [{"role": "user", "content": "stream parity"}]
         req = {"messages": msgs, "max_tokens": 16, "temperature": 0.0}

@@ -43,10 +43,6 @@ from fei_tpu.parallel.mesh import (
 )
 from fei_tpu.utils.metrics import METRICS
 
-from conftest import requires_shard_map
-
-pytestmark = requires_shard_map
-
 PROMPT = list(range(11, 29))
 PROMPTS = [list(range(11 + i, 29 + i)) for i in range(3)]
 
@@ -162,8 +158,7 @@ class TestShardedParity:
     # each distinct (engine, sampling-config) pair pays its own ~20s
     # shard_map compile on the CPU mesh, so only the greedy tp2 parity
     # proof rides the fast tier-1 lane; the seeded / tp2dp2 / preemption
-    # variants run in the slow lane and FOR REAL in
-    # scripts/rehearse_pipeline.sh's sharded_serving stage.
+    # variants run in the slow lane.
     @pytest.mark.slow
     def test_tp2_seeded_token_identical(self, parity_engines):
         ms1, tp2 = parity_engines
