@@ -161,25 +161,16 @@ def _emit(metric: str, value: float, unit: str = "tok/s/chip",
         from fei_tpu.utils.platform import device_info
 
         line.update(device_info())
-    # every line from a device with a known peak carries the roofline
-    # fraction — suites that computed their own keep it; the rest fall
-    # back to the live gauge the engine's dispatch accounting maintains —
-    # and every line carries per-chip throughput
+    # a suite that computed its share of the HBM roofline carries it as
+    # a fraction too, and every line carries per-chip throughput
     try:
         from fei_tpu.obs.costmodel import chips_for_tag
-        from fei_tpu.utils.metrics import METRICS
 
         if (
             device and "roofline_frac" not in line
-            and _device_peaks() is not None
+            and "pct_v5e_hbm" in line and _device_peaks() is not None
         ):
-            if "pct_v5e_hbm" in line:
-                line["roofline_frac"] = round(line["pct_v5e_hbm"] / 100.0, 9)
-            else:
-                gauges = METRICS.snapshot().get("gauges", {})
-                line["roofline_frac"] = round(
-                    float(gauges.get("roofline.frac", 0.0)), 9
-                )
+            line["roofline_frac"] = round(line["pct_v5e_hbm"] / 100.0, 9)
         if "tok_s_per_chip" not in line:
             chips = chips_for_tag(line.get("mesh"))
             v = float(line.get("value", 0.0))
@@ -203,8 +194,7 @@ def _emit(metric: str, value: float, unit: str = "tok/s/chip",
     return 0
 
 
-# The byte model and the peak table live in fei_tpu.obs.costmodel (the
-# engine's live per-dispatch roofline accounting uses the same estimates).
+# The byte model and the peak table live in fei_tpu.obs.costmodel.
 from fei_tpu.obs.costmodel import (  # noqa: E402
     decode_stream_bytes as _decode_stream_bytes,
     device_peaks as _device_peaks,

@@ -185,6 +185,9 @@ class JaxLocalProvider(Provider):
     # the serving endpoint may attach the failover side-channel
     # (delivered-token export + teacher-forced resume)
     supports_resume = True
+    # the serving endpoint may name the request (its response id + accept
+    # time): complete/stream hand it down to the scheduler's trace
+    supports_request_id = True
 
     def __init__(
         self,
@@ -315,11 +318,12 @@ class JaxLocalProvider(Provider):
         return out
 
     def complete(self, messages, system=None, tools=None, max_tokens=4000,
-                 gen_overrides=None, export=None, resume=None):
+                 gen_overrides=None, export=None, resume=None,
+                 request=None):
         chunks = []
         gen = self.stream(messages, system, tools, max_tokens,
                           gen_overrides=gen_overrides, export=export,
-                          resume=resume)
+                          resume=resume, request=request)
         while True:
             try:
                 chunks.append(next(gen))
@@ -328,9 +332,13 @@ class JaxLocalProvider(Provider):
                 return resp
 
     def stream(self, messages, system=None, tools=None, max_tokens=4000,
-               gen_overrides=None, export=None, resume=None):
+               gen_overrides=None, export=None, resume=None, request=None):
         """``gen_overrides`` (e.g. per-request temperature/top_p from the
         serving endpoint) layer over the provider-level defaults.
+
+        ``request`` (``{"id", "t_accepted"}``) is the serving endpoint's
+        name for this request; a paged engine's scheduler adopts the id
+        for its trace, flight records and journal (scheduler.submit).
 
         ``export``/``resume`` are the mid-stream failover side-channel
         (plain generation only — tool-grammar and speculative routes
@@ -392,7 +400,7 @@ class JaxLocalProvider(Provider):
 
             stream_fn = functools.partial(
                 self.engine.generate_stream_toolcalls,
-                grammar=grammar, trigger=self.tool_trigger,
+                grammar=grammar, trigger=self.tool_trigger, request=request,
             )
         elif speculate and resume is None:
             stream_fn = self.engine.generate_stream_lookahead
@@ -401,6 +409,7 @@ class JaxLocalProvider(Provider):
 
             stream_fn = functools.partial(
                 self.engine.generate_stream, export=export, resume=resume,
+                request=request,
             )
         t_start = time.perf_counter()
         with METRICS.span("provider.jax_local"):

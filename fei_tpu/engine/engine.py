@@ -703,6 +703,7 @@ class InferenceEngine:
                 gen_d.get("stop_token_ids") or ()
             )
             restore = {
+                "rid": rid,  # the session keeps the id it was journaled under
                 "generated": sess.get("generated") or [],
                 "resume_key": sess.get("resume_key"),
             }
@@ -924,6 +925,7 @@ class InferenceEngine:
         logit_mask_fn: Callable[[list[int]], jnp.ndarray | None] | None = None,
         export: dict | None = None,
         resume: dict | None = None,
+        request: dict | None = None,
     ) -> Iterator[int]:
         """Stream sampled token ids for a single prompt (batch=1).
 
@@ -936,6 +938,8 @@ class InferenceEngine:
         state; ``resume`` teacher-forces an already-delivered suffix so a
         surviving replica continues a dead peer's stream byte-identically.
         Paged engines only — the dense path has no session journal.
+        ``request`` names the request for the scheduler's trace
+        (scheduler.submit); the dense path keeps no per-request trace.
 
         Unmasked dense decoding is FUSED-CHUNKED: one device dispatch per
         ``gen.chunk`` tokens (default ``FEI_TPU_DECODE_CHUNK``=16) with
@@ -950,7 +954,7 @@ class InferenceEngine:
             # batch slot; any number of concurrent streams share the pool
             yield from self.scheduler.stream(
                 prompt_ids, gen, logit_mask_fn,
-                export=export, resume=resume,
+                export=export, resume=resume, request=request,
             )
             return
         if resume is not None:
@@ -1033,6 +1037,7 @@ class InferenceEngine:
         trigger: str = "<tool_call>",
         close: str = "</tool_call>",
         chunk: int = 16,
+        request: dict | None = None,
     ) -> Iterator[int]:
         """Stream an agent turn with ON-DEVICE tool-call grammar enforcement.
 
@@ -1055,7 +1060,7 @@ class InferenceEngine:
         """
         gen = gen or GenerationConfig()
         if grammar is None:
-            yield from self.generate_stream(prompt_ids, gen)
+            yield from self.generate_stream(prompt_ids, gen, request=request)
             return
         from fei_tpu.engine.grammar import TriggerScanner
 
@@ -1065,7 +1070,8 @@ class InferenceEngine:
             # device-native in the scheduler: free decode until the trigger,
             # then the DFA constrains inside the batched step program
             seq = self.scheduler.submit(
-                prompt_ids, gen, grammar=grammar, grammar_trigger=trigger
+                prompt_ids, gen, grammar=grammar, grammar_trigger=trigger,
+                request=request,
             )
             yield from self.scheduler.drain(seq)
             if seq.gaccepted:

@@ -41,7 +41,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from fei_tpu.engine.sampling import sample_logits, stop_mask
-from fei_tpu.obs import costmodel
 from fei_tpu.obs.flight import FLIGHT
 from fei_tpu.parallel.mesh import mesh_tag
 from fei_tpu.utils.metrics import METRICS
@@ -231,11 +230,6 @@ class ChunkDecoder:
                     # ONE host transfer per chunk; this is the only
                     # blocking point — chunk k+1 is already in flight
                     host = np.asarray(toks_p)[0].tolist()
-                slots = int(self._token.shape[0])
-                costmodel.account_dispatch(
-                    self._engine, n_p, fed0_p * slots, slots,
-                    time.perf_counter() - t0_p,
-                )
                 yield DecodedChunk(tokens=host, rngs=rngs_p, fed0=fed0_p)
             pending = nxt
 

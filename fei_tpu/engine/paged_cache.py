@@ -350,6 +350,7 @@ def build_block_table(
     return jnp.asarray(rows, dtype=jnp.int32)
 
 
+@jax.named_scope("kv_write")
 def write_token_kv(
     k_pages: jnp.ndarray,  # [P, K, ps, D] one layer's pool
     v_pages: jnp.ndarray,

@@ -550,7 +550,7 @@ class AdmissionMixin:
                     FLIGHT.dispatch(
                         "dispatch.prefill_chunk", t0, t_issue, t_issue,
                         rid=seq.rid, mesh=mesh_tag(eng.mesh),
-                        slot=st["slot"], tokens=hi - lo, replay=True,
+                        slot=st["slot"], lo=lo, tokens=hi - lo, replay=True,
                     )
                     METRICS.incr(
                         "scheduler.resume_replayed_tokens", hi - lo
@@ -606,7 +606,7 @@ class AdmissionMixin:
         FLIGHT.dispatch(
             "dispatch.prefill_chunk", t0, t_issue, time.perf_counter(),
             rid=seq.rid, mesh=mesh_tag(eng.mesh), slot=st["slot"],
-            tokens=hi - lo,
+            lo=lo, tokens=hi - lo,
         )
         st["pos"] = hi
         if hi < n:
@@ -646,7 +646,7 @@ class AdmissionMixin:
             "dispatch.prefill_chunk", t0, t_issue,
             time.perf_counter(), rid=seq.rid,
             mesh=mesh_tag(eng.mesh), slot=st["slot"],
-            tokens=hi - lo, paged=True,
+            lo=lo, tokens=hi - lo, paged=True,
         )
         st["pos"] = hi
         if not final or hi < n:
@@ -1188,6 +1188,7 @@ class AdmissionMixin:
 
             def gather(pool, pages, dense, true_tokens):
                 # pool pages: [L, P, K, ps, D]; pages: [gm]
+                @jax.named_scope("kv_read")
                 def pick(pool_pages, scales):
                     g = pool_pages[:, pages]  # [L, gm, K, ps, D]
                     if scales is not None:

@@ -19,7 +19,6 @@ import numpy as np
 from fei_tpu.engine.faults import FAULTS
 from fei_tpu.engine.sampling import sample_logits_dynamic
 from fei_tpu.models.llama import forward_paged
-from fei_tpu.obs import costmodel
 from fei_tpu.obs.flight import FLIGHT
 from fei_tpu.parallel.mesh import mesh_tag
 from fei_tpu.utils.logging import get_logger
@@ -40,29 +39,32 @@ def _make_sampler(grammared: bool, masked: bool):
     def sample(logits, keys, temps, topks, topps, minps,
                gstates=None, gremain=None, table=None, mind=None,
                mask=None):
+        with jax.named_scope("grammar_mask"):
+            if grammared:
+                # per-slot DFA mask, entirely on device: slots with
+                # gstate < 0 (free/unconstrained) pass through. Budget
+                # feasibility is the shared rule (grammar.feasible_mask,
+                # same as the dense scan).
+                use = gstates >= 0
+                srow = table[jnp.maximum(gstates, 0)]  # [B, V]
+                gmask = feasible_mask(srow, mind, gremain, xp=jnp)
+                gmask = jnp.where(use[:, None], gmask, True)
+                logits = jnp.where(gmask, logits, -jnp.inf)
+            if masked:
+                logits = jnp.where(mask, logits, -jnp.inf)
+        with jax.named_scope("sample"):
+            outs = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
+            new_keys, subs = outs[:, 0], outs[:, 1]
+            nxt = sample_logits_dynamic(
+                logits, subs, temps, topks, topps, minps
+            )
         if grammared:
-            # per-slot DFA mask, entirely on device: slots with
-            # gstate < 0 (free/unconstrained) pass through. Budget
-            # feasibility is the shared rule (grammar.feasible_mask,
-            # same as the dense scan).
-            use = gstates >= 0
-            srow = table[jnp.maximum(gstates, 0)]  # [B, V]
-            gmask = feasible_mask(srow, mind, gremain, xp=jnp)
-            gmask = jnp.where(use[:, None], gmask, True)
-            logits = jnp.where(gmask, logits, -jnp.inf)
-        if masked:
-            logits = jnp.where(mask, logits, -jnp.inf)
-        outs = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
-        new_keys, subs = outs[:, 0], outs[:, 1]
-        nxt = sample_logits_dynamic(
-            logits, subs, temps, topks, topps, minps
-        )
-        if grammared:
-            nstate = jnp.take_along_axis(
-                srow, nxt[:, None], axis=1
-            )[:, 0].astype(jnp.int32)
-            gstates = jnp.where(use, nstate, gstates)
-            gremain = jnp.where(use, gremain - 1, gremain)
+            with jax.named_scope("grammar_mask"):
+                nstate = jnp.take_along_axis(
+                    srow, nxt[:, None], axis=1
+                )[:, 0].astype(jnp.int32)
+                gstates = jnp.where(use, nstate, gstates)
+                gremain = jnp.where(use, gremain - 1, gremain)
         return nxt, new_keys, gstates, gremain
 
     return sample
@@ -259,20 +261,8 @@ class DecodeMixin:
             # the device-native grammar path is measured against
             METRICS.incr("scheduler.host_mask_uploads", len(masks))
         toks = self._dispatch_steps(active, 1, mask=mask)
-        # per-token PRNG resume states (journal/export consumers only):
-        # _step_keys already synced with the dispatch, this is one D2H copy
-        keys_h = (
-            np.asarray(self._step_keys) if self._want_token_keys() else None
-        )
-        for b, s in active:
-            # defensive symmetry with the multi-step loop; with n=1 nothing
-            # can replace a slot between assembly and delivery
-            if self._slots[b] is not s:
-                continue
-            self._deliver(
-                s, int(toks[b, 0]),
-                key=None if keys_h is None else keys_h[0, b],
-            )
+        with FLIGHT.span("loop.deliver"):
+            self._deliver_scan(active, toks, 1)
 
 
     def _try_multi_step(self) -> bool:
@@ -347,6 +337,12 @@ class DecodeMixin:
         METRICS.incr("scheduler.multi_tokens", n)
         if under_admission:
             METRICS.incr("scheduler.turbo_under_admission")
+        with FLIGHT.span("loop.deliver"):
+            self._deliver_scan(active, toks, n)
+        return True
+
+    def _deliver_scan(self, active, toks: np.ndarray, n: int) -> None:
+        """Hand a scan's [B, n] tokens to their sequences, step by step."""
         # stacked per-step PRNG states ([n, B, 2]): step_keys[i] is the
         # chain after i+1 splits — exactly the per-token reference state
         # after delivering i+1 tokens, which is what the journal records
@@ -376,7 +372,6 @@ class DecodeMixin:
                     break
         if rollback:
             self._rollback_slots(rollback, n)
-        return True
 
 
     def _rollback_slots(self, rollback: dict[int, int], n: int) -> None:
@@ -468,7 +463,86 @@ class DecodeMixin:
         be re-evaluated between steps. The stacked per-step rng keys land
         in ``self._step_keys`` ([n, B, 2], stays on device) so a
         free-phase trigger rollback can restore a slot's exact mid-scan
-        key state."""
+        key state.
+
+        Host work before the dispatch is issued (growth, batch vectors,
+        uploads) is the ``loop.build`` span; the flight record carries
+        what the dispatch ran: ``ctx``, each active slot's context length
+        (prompt + generated) at the first step, in the order of ``rids``,
+        and for a merged dispatch ``chunk_lo``, the tokens of the riding
+        request already in pages before its chunk."""
+        eng = self.engine
+        with FLIGHT.span("loop.build"):
+            args, kw, grammared, pc = self._build_step_args(active, n, mask)
+        ctx = [len(s.prompt_ids) + len(s.generated) for _, s in active]
+        METRICS.incr("scheduler.decode_steps", n)
+        METRICS.incr("scheduler.decode_slot_steps", len(active) * n)
+        METRICS.gauge("scheduler.batch_slots_active", len(active))
+        chunk_logits = None
+        merged = pc is not None
+        t0 = time.perf_counter()
+        if merged:
+            step = self._ragged_fn(
+                n, pc["toks"].shape[1], pc["final"], grammared
+            )
+            rargs = args[:2] + [
+                jnp.asarray(pc["toks"]),
+                jnp.asarray(pc["st"]["row"][None]),
+                jnp.asarray([pc["lo"]], dtype=jnp.int32),
+                jnp.int32(pc["ntok"] - 1 - pc["lo"]),
+            ] + args[2:]
+            with METRICS.span("decode_step", jax_trace=True):
+                res = self._device_call("ragged merged dispatch", step,
+                                        *rargs, **kw)
+                if pc["final"]:
+                    (chunk_logits, nxt, self._step_keys, self._pool,
+                     self._keys) = res
+                else:
+                    nxt, self._step_keys, self._pool, self._keys = res
+                t_issue = time.perf_counter()
+                out = np.asarray(nxt)  # host sync inside the span
+        else:
+            step = self._multi_fn(n, grammared, masked=mask is not None)
+            with METRICS.span("decode_step", jax_trace=True):
+                nxt, self._step_keys, self._pool, self._keys = step(*args, **kw)
+                t_issue = time.perf_counter()
+                out = np.asarray(nxt)  # host sync inside the span
+        t1 = time.perf_counter()
+        METRICS.timing("dispatch_issue", t_issue - t0)
+        METRICS.timing("dispatch_sync", t1 - t_issue)
+        extra = {}
+        if merged:
+            # NO separate "dispatch.prefill_chunk" record for a merged
+            # chunk — that count dropping under overlap IS the measured
+            # dispatch reduction (pinned in tests/test_ragged_attention)
+            extra = {
+                "ragged": True, "chunk_tokens": pc["hi"] - pc["lo"],
+                "chunk_rid": pc["st"]["seq"].rid, "chunk_lo": pc["lo"],
+            }
+            METRICS.incr("engine.ragged_dispatches")
+            METRICS.gauge("engine.kernel_loop_depth", n * eng.cfg.num_layers)
+        FLIGHT.dispatch(
+            "dispatch.step", t0, t_issue, t1,
+            rids=[s.rid for _, s in active], mesh=mesh_tag(eng.mesh),
+            n_steps=n, slots=len(active), ctx=ctx, **extra,
+        )
+        for _, s in active:
+            s.shield = False  # survived a dispatch: victimizable again
+        if merged:
+            st = pc["st"]
+            with FLIGHT.span("loop.deliver", chunk=True):
+                try:
+                    self._finish_merged_chunk(pc, chunk_logits)
+                except BaseException as exc:  # noqa: BLE001
+                    # same containment as _admit_ready's solo-chunk wrapper
+                    self._abort_admission(st["seq"], st["slot"], exc)
+        return out
+
+    def _build_step_args(self, active, n: int, mask):
+        """Host half of ``_dispatch_steps``: grow lazy reservations, fill
+        the [B] batch vectors, claim the deferred admission chunk and put
+        everything on the device. Returns (positional args, keyword args,
+        grammared, pending chunk or None)."""
         self._grow_for_steps(active, n)
         FAULTS.check("decode.dispatch")
         eng = self.engine
@@ -515,91 +589,7 @@ class DecodeMixin:
             )
         if mask is not None:
             kw["mask"] = jnp.asarray(mask)
-        METRICS.incr("scheduler.decode_steps", n)
-        METRICS.incr("scheduler.decode_slot_steps", len(active) * n)
-        METRICS.gauge("scheduler.batch_slots_active", len(active))
-        chunk_logits = None
-        merged = pc is not None
-        t0 = time.perf_counter()
-        if merged:
-            step = self._ragged_fn(
-                n, pc["toks"].shape[1], pc["final"], grammared
-            )
-            rargs = args[:2] + [
-                jnp.asarray(pc["toks"]),
-                jnp.asarray(pc["st"]["row"][None]),
-                jnp.asarray([pc["lo"]], dtype=jnp.int32),
-                jnp.int32(pc["ntok"] - 1 - pc["lo"]),
-            ] + args[2:]
-            with METRICS.span("decode_step"):
-                res = self._device_call("ragged merged dispatch", step,
-                                        *rargs, **kw)
-                if pc["final"]:
-                    (chunk_logits, nxt, self._step_keys, self._pool,
-                     self._keys) = res
-                else:
-                    nxt, self._step_keys, self._pool, self._keys = res
-                t_issue = time.perf_counter()
-                out = np.asarray(nxt)  # host sync inside the span
-        else:
-            step = self._multi_fn(n, grammared, masked=mask is not None)
-            with METRICS.span("decode_step"):
-                nxt, self._step_keys, self._pool, self._keys = step(*args, **kw)
-                t_issue = time.perf_counter()
-                out = np.asarray(nxt)  # host sync inside the span
-        t1 = time.perf_counter()
-        self._record_collective_time(t1 - t0)
-        METRICS.timing("dispatch_issue", t_issue - t0)
-        METRICS.timing("dispatch_sync", t1 - t_issue)
-        extra = {}
-        if merged:
-            # NO separate "dispatch.prefill_chunk" record for a merged
-            # chunk — that count dropping under overlap IS the measured
-            # dispatch reduction (pinned in tests/test_ragged_attention)
-            extra = {
-                "ragged": True, "chunk_tokens": pc["hi"] - pc["lo"],
-                "chunk_rid": pc["st"]["seq"].rid,
-            }
-        FLIGHT.dispatch(
-            "dispatch.step", t0, t_issue, t1,
-            rids=[s.rid for _, s in active], mesh=mesh_tag(eng.mesh),
-            n_steps=n, slots=len(active), **extra,
-        )
-        ctx = sum(len(s.prompt_ids) + len(s.generated) for _, s in active)
-        if merged:
-            METRICS.incr("engine.ragged_dispatches")
-            METRICS.gauge("engine.kernel_loop_depth", n * eng.cfg.num_layers)
-            costmodel.account_ragged_dispatch(
-                eng, n, ctx, len(active),
-                pc["hi"] - pc["lo"], pc["lo"], t1 - t0,
-            )
-        else:
-            costmodel.account_dispatch(eng, n, ctx, len(active), t1 - t0)
-        for _, s in active:
-            s.shield = False  # survived a dispatch: victimizable again
-        if merged:
-            st = pc["st"]
-            try:
-                self._finish_merged_chunk(pc, chunk_logits)
-            except BaseException as exc:  # noqa: BLE001
-                # same containment as _admit_ready's solo-chunk wrapper
-                self._abort_admission(st["seq"], st["slot"], exc)
-        return out
-
-    def _record_collective_time(self, dt: float) -> None:
-        """Attribute a sharded dispatch's wall time to each active mesh
-        axis (collective.<axis>_seconds histograms). Without an on-device
-        profiler this is an upper bound — the step includes compute — but
-        a per-axis regression (a tp4 step suddenly 2x a tp2 step at equal
-        batch) still reads directly off the histogram deltas."""
-        from fei_tpu.parallel.mesh import AXES, axis_size
-
-        mesh = self.engine.mesh
-        if mesh is None:
-            return
-        for ax in AXES:
-            if axis_size(mesh, ax) > 1:
-                METRICS.timing(f"collective.{ax}", dt)
+        return args, kw, grammared, pc
 
 
     def _multi_fn(self, n_steps: int, grammared: bool, masked: bool = False):
@@ -642,9 +632,11 @@ class DecodeMixin:
                     (pool, tokens, keys, gstates, gremain) if grammared
                     else (pool, tokens, keys)
                 )
-                carry, (toks, step_keys) = jax.lax.scan(
-                    body, init, None, length=n_steps
-                )
+                # the step scan carries the whole pool from step to step
+                with jax.named_scope("pool_carry"):
+                    carry, (toks, step_keys) = jax.lax.scan(
+                        body, init, None, length=n_steps
+                    )
                 # step_keys[i] is the key state after i+1 splits — exactly
                 # the per-token reference chain after delivering i+1 tokens,
                 # so the host can re-enter mid-scan (free-phase trigger
@@ -719,9 +711,10 @@ class DecodeMixin:
                         (pool, nxt[:, None], new_keys, gstates, gremain)
                         if grammared else (pool, nxt[:, None], new_keys)
                     )
-                    carry, (toks_r, keys_r) = jax.lax.scan(
-                        body, init, None, length=n_steps - 1
-                    )
+                    with jax.named_scope("pool_carry"):
+                        carry, (toks_r, keys_r) = jax.lax.scan(
+                            body, init, None, length=n_steps - 1
+                        )
                     pool, keys_out = carry[0], carry[2]
                     toks = jnp.concatenate([toks, toks_r], axis=0)
                     step_keys = jnp.concatenate([step_keys, keys_r], axis=0)

@@ -380,6 +380,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         grammar_trigger: str | None = None,
         export: dict | None = None,
         resume: dict | None = None,
+        request: dict | None = None,
     ) -> Iterator[int]:
         """Submit a request and yield its tokens as they decode.
 
@@ -390,11 +391,12 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         ``export`` (a caller-owned dict) receives live resume state per
         delivered token (see _Seq.export); ``resume`` is a restore dict
         (``generated`` + optional ``resume_key``) teacher-forcing an
-        already-delivered suffix — the fleet resurrection path."""
+        already-delivered suffix — the fleet resurrection path;
+        ``request`` names the request (see ``submit``)."""
         seq = self.submit(
             prompt_ids, gen, logit_mask_fn,
             grammar=grammar, grammar_trigger=grammar_trigger,
-            _restore=resume, _export=export,
+            _restore=resume, _export=export, request=request,
         )
         yield from self.drain(seq)
 
@@ -416,8 +418,17 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         grammar=None, grammar_trigger: str | None = None,
         _restore: dict | None = None,
         _export: dict | None = None,
+        request: dict | None = None,
     ) -> _Seq:
-        """``grammar`` (a TokenGrammar) runs DEVICE-NATIVE: the DFA mask is
+        """``request`` is what the caller already knows about the request:
+        ``{"id": ..., "t_accepted": ...}`` — the id it handed its client
+        (the server's ``chatcmpl-…``) and the perf_counter value at which
+        it accepted the request. The trace, the flight records, the
+        journal and the KV tier all key on that one id; a restored
+        session (``_restore["rid"]``) keeps the id it had; with neither,
+        a ``req-…`` id is minted here.
+
+        ``grammar`` (a TokenGrammar) runs DEVICE-NATIVE: the DFA mask is
         computed inside the compiled step from per-slot states — unlike
         ``logit_mask_fn`` there is no per-step host mask evaluation or
         [B, vocab] upload. With ``grammar_trigger`` the request decodes
@@ -493,7 +504,12 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
             seq.deadline = seq.t_queued + dl
         from fei_tpu.parallel.mesh import mesh_tag
 
-        seq.trace = TRACES.start(prompt_tokens=n, mesh=mesh_tag(eng.mesh))
+        request = request or {}
+        seq.trace = TRACES.start(
+            prompt_tokens=n, mesh=mesh_tag(eng.mesh),
+            rid=request.get("id") or (_restore or {}).get("rid"),
+            t_accepted=request.get("t_accepted"),
+        )
         seq.rid = seq.trace.rid
         if _restore is not None:
             # warm restart: rebuild the preempt-resume state BEFORE the seq
@@ -905,10 +921,49 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
 
     _IDLE_PARKS = 600  # ~60 s of nothing to do -> park the thread
 
+    def _has_work(self) -> bool:
+        """Anything queued, admitting, armed, or asked of the loop."""
+        return bool(
+            self._waiting or self._ctl or self._admitting is not None
+            or self._draining or self._closed or any(self._slots)
+        )
+
     def _loop(self) -> None:
+        # Every phase of a working iteration is a host span in the flight
+        # recorder (loop.reap / loop.ctl / loop.admit here, loop.build /
+        # loop.deliver around the dispatch in sched_decode); an unbroken
+        # stretch with nothing to do is ONE loop.idle span, closed when
+        # work arrives or the thread parks — not one record per poll.
         idle = 0
+        idle_t0 = None
+
+        def end_idle() -> None:
+            nonlocal idle, idle_t0
+            idle = 0
+            if idle_t0 is not None:
+                FLIGHT.record_span("loop.idle", idle_t0, time.perf_counter())
+                idle_t0 = None
+
         while True:
             try:
+                if not self._has_work():
+                    if idle_t0 is None:
+                        idle_t0 = time.perf_counter()
+                    idle += 1
+                    if idle > self._IDLE_PARKS:
+                        # park instead of polling forever: every live
+                        # engine otherwise keeps a 10 Hz daemon thread
+                        # for its whole lifetime (test suites stack
+                        # dozens). submit() restarts the loop.
+                        with self._lock:
+                            if not self._waiting and not any(self._slots):
+                                end_idle()
+                                self._thread = None
+                                return
+                    self._wake.wait(timeout=0.1)
+                    self._wake.clear()
+                    continue
+                end_idle()
                 if self._closed:
                     # drain requests but KEEP the healthy pool + prefix
                     # cache (unlike _fail_all, which handles device
@@ -921,8 +976,10 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
                             self._thread = None
                             return
                     continue
-                self._reap_cancelled()
-                self._run_ctl_pending()
+                with FLIGHT.span("loop.reap"):
+                    self._reap_cancelled()
+                with FLIGHT.span("loop.ctl"):
+                    self._run_ctl_pending()
                 if self._draining:
                     if self._admitting is not None:
                         # an ACCEPTED chunked admission finishes its
@@ -934,23 +991,14 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
                             self._thread = None
                             return
                     continue
-                self._admit_ready()
+                with FLIGHT.span("loop.admit"):
+                    self._admit_ready()
                 if not any(self._slots):
-                    if not self._waiting and self._admitting is None:
-                        idle += 1
-                        if idle > self._IDLE_PARKS:
-                            # park instead of polling forever: every live
-                            # engine otherwise keeps a 10 Hz daemon thread
-                            # for its whole lifetime (test suites stack
-                            # dozens). submit() restarts the loop.
-                            with self._lock:
-                                if not self._waiting and not any(self._slots):
-                                    self._thread = None
-                                    return
+                    # queued work that cannot be admitted yet (every
+                    # waiting tenant over budget): poll, as before
                     self._wake.wait(timeout=0.1)
                     self._wake.clear()
                     continue
-                idle = 0
                 self._step_active()
             except BaseException as exc:  # noqa: BLE001
                 log.error("scheduler loop error: %r", exc)

@@ -1056,7 +1056,7 @@ class Router:
         )
         body2 = {k: v for k, v in body.items() if k != "resume"}
         body2["resume"] = {"generated": [int(t) for t in st["toks"]],
-                           "resume_key": st["key"]}
+                           "resume_key": st["key"], "id": st["id"]}
         fwd = dict(headers)
         if remaining is not None:
             fwd["X-FEI-Deadline-S"] = f"{remaining:.3f}"

@@ -210,6 +210,7 @@ _FLASH_MIN_T = 64  # below this, kernel launch overhead beats the fusion win
 _ROUTED_MIN_TOKENS = 16  # below this, sort/gather overhead beats the k/E win
 
 
+@jax.named_scope("mlp")
 def _moe(cfg: ModelConfig, y, lp, allow_routed: bool, moe_mesh=None):
     """Pick the MoE formulation at trace time.
 
@@ -254,6 +255,7 @@ def _moe(cfg: ModelConfig, y, lp, allow_routed: bool, moe_mesh=None):
     return fn(*args)
 
 
+@jax.named_scope("norm")
 def _norm(x, w, cfg: ModelConfig, b=None):
     """RMSNorm (Llama families) or LayerNorm with bias (Phi family,
     cfg.norm_kind == "layernorm"; ``b`` is the bias leaf or None)."""
@@ -269,6 +271,7 @@ def _norm(x, w, cfg: ModelConfig, b=None):
     return rms_norm(x, w, cfg.rms_norm_eps, offset=cfg.norm_offset)
 
 
+@jax.named_scope("rope")
 def _rope(x, cos, sin, positions, rope_dim: int):
     """apply_rope over the first ``rope_dim`` head dims (Phi partial
     rotary; the HF convention rotates the leading slice split-half and
@@ -283,6 +286,7 @@ def _rope(x, cos, sin, positions, rope_dim: int):
     return apply_rope(x, cos, sin, positions)
 
 
+@jax.named_scope("mlp")
 def _mlp_dense(cfg: ModelConfig, y, lp, kernel_mesh=None):
     """The dense (non-MoE) MLP: gated SwiGLU/GeGLU (w_gate*w_up -> w_down)
     for the Llama families, fc1 -> act -> fc2 with biases for Phi
@@ -318,6 +322,7 @@ def model_dtype(params: dict):
     return params["layers"]["attn_norm"].dtype
 
 
+@jax.named_scope("embed")
 def embed_tokens(params: dict, cfg: ModelConfig, tokens, dtype):
     """Embedding lookup (plain or row-quantized table — ops.quant
     embed_lookup); Gemma scales by sqrt(hidden_size) (in the compute
@@ -350,6 +355,7 @@ def _mm_k(x, w, kernel_mesh):
     return mm(x, w)
 
 
+@jax.named_scope("attn_qkv")
 def qkv_proj(lp, y, Hq: int, K: int, d: int, kernel_mesh=None):
     """Project y -> (q [B,T,Hq,d], k [B,T,K,d], v [B,T,K,d]), applying the
     Qwen2-style qkv biases when the layer carries them (cfg.attn_bias)."""
@@ -364,6 +370,7 @@ def qkv_proj(lp, y, Hq: int, K: int, d: int, kernel_mesh=None):
     )
 
 
+@jax.named_scope("attention")
 def _attend(q, k, v, kv_length, positions, window: int = 0,
             kernel_mesh=None):
     """Pick the attention path at trace time.
@@ -424,16 +431,18 @@ def _layer(
         def write(buf, new, start):
             return jax.lax.dynamic_update_slice(buf, new, (start, 0, 0))
 
-        new_k = jax.vmap(write)(cache_k, k, kv_length)
-        new_v = jax.vmap(write)(cache_v, v, kv_length)
+        with jax.named_scope("kv_write"):
+            new_k = jax.vmap(write)(cache_k, k, kv_length)
+            new_v = jax.vmap(write)(cache_v, v, kv_length)
 
     attn_out = _attend(
         q, new_k, new_v, kv_length, positions,
         window=cfg.sliding_window or 0, kernel_mesh=kernel_mesh,
     )
-    o = mm(attn_out.reshape(B, T, Hq * d), lp["wo"])
-    if "bo" in lp:  # HF Llama attention_bias=true also biases o_proj
-        o = o + lp["bo"]
+    with jax.named_scope("attn_out"):
+        o = mm(attn_out.reshape(B, T, Hq * d), lp["wo"])
+        if "bo" in lp:  # HF Llama attention_bias=true also biases o_proj
+            o = o + lp["bo"]
 
     if cfg.parallel_block:
         # Phi: attention and MLP both read the ONE shared norm output and
@@ -453,6 +462,7 @@ def _layer(
     return x + mlp_out, new_k, new_v
 
 
+@jax.named_scope("lm_head")
 def _logits(x, params, cfg: ModelConfig, kernel_mesh=None) -> jnp.ndarray:
     """LM head (quantization-aware). Tied embeddings project through the
     (possibly row-quantized) embed table — ops.quant.tied_logits applies
@@ -621,38 +631,43 @@ def forward_paged_block(
                 kp, vp, ksc, vsc = written
             else:
                 kp, vp = written
-        if block_kernel:
-            if sharded:
-                attn = paged_attention_block_sharded(
-                    q, kp, vp, cache.block_table, cache.lengths,
-                    kernel_mesh, axis_name="tp", k_scales=ksc, v_scales=vsc,
-                    window=win,
-                )
-            else:
-                attn = paged_attention_block(
-                    q, kp, vp, cache.block_table, cache.lengths,
-                    k_scales=ksc, v_scales=vsc, window=win,
-                )  # [B, T, Hq, D]
-        else:
-            attns = []
-            for i in range(T):  # per-position fallback
+        # the scope sits OUTSIDE the kernels' jitted wrappers: the
+        # innermost name on a Pallas call's path is the name its
+        # operation gets in a device trace, and that stays the kernel's
+        with jax.named_scope("attention"):
+            if block_kernel:
                 if sharded:
-                    a = paged_attention_sharded(
-                        q[:, i], kp, vp, cache.block_table,
-                        cache.lengths + i + 1, kernel_mesh, axis_name="tp",
-                        k_scales=ksc, v_scales=vsc, window=win,
+                    attn = paged_attention_block_sharded(
+                        q, kp, vp, cache.block_table, cache.lengths,
+                        kernel_mesh, axis_name="tp", k_scales=ksc, v_scales=vsc,
+                        window=win,
                     )
                 else:
-                    a = paged_attention(
-                        q[:, i], kp, vp, cache.block_table,
-                        cache.lengths + i + 1, k_scales=ksc, v_scales=vsc,
-                        window=win,
-                    )  # [B, Hq, D]
-                attns.append(a)
-            attn = jnp.stack(attns, axis=1)  # [B, T, Hq, D]
-        o = mm(attn.reshape(B, T, Hq * d), lp["wo"])
-        if "bo" in lp:
-            o = o + lp["bo"]
+                    attn = paged_attention_block(
+                        q, kp, vp, cache.block_table, cache.lengths,
+                        k_scales=ksc, v_scales=vsc, window=win,
+                    )  # [B, T, Hq, D]
+            else:
+                attns = []
+                for i in range(T):  # per-position fallback
+                    if sharded:
+                        a = paged_attention_sharded(
+                            q[:, i], kp, vp, cache.block_table,
+                            cache.lengths + i + 1, kernel_mesh, axis_name="tp",
+                            k_scales=ksc, v_scales=vsc, window=win,
+                        )
+                    else:
+                        a = paged_attention(
+                            q[:, i], kp, vp, cache.block_table,
+                            cache.lengths + i + 1, k_scales=ksc, v_scales=vsc,
+                            window=win,
+                        )  # [B, Hq, D]
+                    attns.append(a)
+                attn = jnp.stack(attns, axis=1)  # [B, T, Hq, D]
+        with jax.named_scope("attn_out"):
+            o = mm(attn.reshape(B, T, Hq * d), lp["wo"])
+            if "bo" in lp:
+                o = o + lp["bo"]
         out = (kp, vp, ksc, vsc) if kv_int8 else (kp, vp)
         if cfg.parallel_block:  # Phi: x + attn(ln x) + mlp(ln x)
             mlp_out = (
@@ -674,10 +689,15 @@ def forward_paged_block(
             params["layers"], cache.k_pages, cache.v_pages,
             cache.k_scales, cache.v_scales,
         )
-        x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(body, x, xs)
+        with jax.named_scope("pool_carry"):
+            x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(body, x, xs)
     else:
         xs = (params["layers"], cache.k_pages, cache.v_pages)
-        x, (new_k, new_v) = jax.lax.scan(body, x, xs)
+        # the layer scan slices each layer's pool out of the stack and
+        # writes it back: those operations are the scan's own, and this
+        # is the only name they can be given
+        with jax.named_scope("pool_carry"):
+            x, (new_k, new_v) = jax.lax.scan(body, x, xs)
         new_ks = new_vs = None
 
     x = _norm(x, params["final_norm"], cfg, b=params.get("final_norm_b"))
@@ -816,25 +836,27 @@ def forward_paged_merged(
             jnp.pad(qc, ((0, 0), (0, Cp - C), (0, 0), (0, 0)))
             .reshape(nG, R, Hq, d),
         ], axis=0)  # [Bv, R, Hq, d]
-        if sharded:
-            av = ragged_paged_attention_sharded(
-                qv, kp, vp, btv, limits, q_lens, modes, kernel_mesh,
-                axis_name="tp", k_scales=ksc, v_scales=vsc, window=win,
-            )
-        else:
-            av = ragged_paged_attention(
-                qv, kp, vp, btv, limits, q_lens, modes,
-                k_scales=ksc, v_scales=vsc, window=win,
-            )
+        with jax.named_scope("attention"):
+            if sharded:
+                av = ragged_paged_attention_sharded(
+                    qv, kp, vp, btv, limits, q_lens, modes, kernel_mesh,
+                    axis_name="tp", k_scales=ksc, v_scales=vsc, window=win,
+                )
+            else:
+                av = ragged_paged_attention(
+                    qv, kp, vp, btv, limits, q_lens, modes,
+                    k_scales=ksc, v_scales=vsc, window=win,
+                )
         dec_attn = av[:B, :1]  # [B, 1, Hq, d]
         chunk_attn = av[B:].reshape(1, Cp, Hq, d)[:, :C]
 
         out = (kp, vp, ksc, vsc) if kv_int8 else (kp, vp)
 
         def tail(x, y, attn, T, nB):
-            o = mm(attn.reshape(nB, T, Hq * d), lp["wo"])
-            if "bo" in lp:
-                o = o + lp["bo"]
+            with jax.named_scope("attn_out"):
+                o = mm(attn.reshape(nB, T, Hq * d), lp["wo"])
+                if "bo" in lp:
+                    o = o + lp["bo"]
             if cfg.parallel_block:  # Phi: x + attn(ln x) + mlp(ln x)
                 mlp_out = (
                     _moe(cfg, y, lp, routed_moe, moe_mesh) if cfg.is_moe
@@ -858,12 +880,14 @@ def forward_paged_merged(
             params["layers"], cache.k_pages, cache.v_pages,
             cache.k_scales, cache.v_scales,
         )
-        (xc, xd), (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-            body, (xc, xd), xs
-        )
+        with jax.named_scope("pool_carry"):
+            (xc, xd), (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
+                body, (xc, xd), xs
+            )
     else:
         xs = (params["layers"], cache.k_pages, cache.v_pages)
-        (xc, xd), (new_k, new_v) = jax.lax.scan(body, (xc, xd), xs)
+        with jax.named_scope("pool_carry"):
+            (xc, xd), (new_k, new_v) = jax.lax.scan(body, (xc, xd), xs)
         new_ks = new_vs = None
 
     xc = _norm(xc, params["final_norm"], cfg, b=params.get("final_norm_b"))

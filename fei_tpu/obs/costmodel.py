@@ -4,14 +4,14 @@ estimates from the model config + batch/page geometry.
 Single-stream decode is weight-streaming-bound, so achieved tok/s ×
 bytes-streamed-per-token against the chip's HBM bandwidth — not MFU — is
 the lens that says whether there is headroom. This module owns the byte
-model bench.py reports against, plus live per-dispatch accounting the
-scheduler feeds into the ``roofline.frac`` / ``roofline.tok_s_per_chip``
-gauges so ``/metrics`` and every bench line carry the fraction-of-roofline
-a run actually achieved.
+model bench.py reports against, and the KV tier's bytes-moved
+accounting. Nothing here runs per dispatch: a kernel's share of its
+roofline is read from a profiler trace (benchmarks/layer_metrics), with
+what each dispatch ran taken from its flight record (obs/flight.py).
 
 The peak comes from ``DEVICE_PEAKS``, keyed by the ``device_kind`` JAX
-reports. A device that is not in the table has no roofline: the gauges
-are not published and a bench line carries no roofline field.
+reports. A device that is not in the table has no roofline: a bench line
+then carries no roofline field.
 """
 
 from __future__ import annotations
@@ -177,64 +177,3 @@ def account_kv_transfer(direction: str, nbytes: int, dt_s: float) -> None:
         METRICS.gauge(
             f"kv.{direction}_gbps", round(nbytes / dt_s / 1e9, 6)
         )
-
-
-def account_dispatch(engine, n_steps: int, total_ctx: int, slots: int,
-                     dt_s: float) -> None:
-    """Live roofline accounting for one decode dispatch: update the
-    ``roofline.frac`` and ``roofline.tok_s_per_chip`` gauges from the
-    analytical byte estimate and the measured wall time."""
-    from fei_tpu.obs.metrics import METRICS
-    from fei_tpu.parallel.mesh import AXES, axis_size
-
-    if dt_s <= 0:
-        return
-    mesh = getattr(engine, "mesh", None)
-    n_chips = 1
-    for ax in AXES:
-        n_chips *= axis_size(mesh, ax)
-    est = dispatch_bytes(engine, n_steps, total_ctx, slots)
-    _roofline_gauges(engine, est, n_steps * slots, dt_s, n_chips)
-
-
-def account_ragged_dispatch(
-    engine, n_steps: int, total_ctx: int, slots: int,
-    chunk_tokens: int, chunk_ctx: int, dt_s: float,
-) -> None:
-    """Roofline accounting for one MERGED ragged dispatch (decode scan +
-    prefill chunk in one program). The byte estimate credits the chunk's
-    K/V traffic but NOT a second weight stream (see
-    ``ragged_dispatch_bytes``), and tok_s_per_chip keeps counting decode
-    tokens only — prefill positions are not served tokens, so the gauge
-    stays comparable across the merged and legacy paths."""
-    from fei_tpu.parallel.mesh import AXES, axis_size
-
-    if dt_s <= 0:
-        return
-    mesh = getattr(engine, "mesh", None)
-    n_chips = 1
-    for ax in AXES:
-        n_chips *= axis_size(mesh, ax)
-    est = ragged_dispatch_bytes(
-        engine, n_steps, total_ctx, slots, chunk_tokens, chunk_ctx
-    )
-    _roofline_gauges(engine, est, n_steps * slots, dt_s, n_chips)
-
-
-def _roofline_gauges(engine, est_bytes: int, tokens: int, dt_s: float,
-                     n_chips: int) -> None:
-    from fei_tpu.obs.metrics import METRICS
-
-    peaks = device_peaks()
-    if peaks is None:
-        return
-    METRICS.gauge(
-        "roofline.frac",
-        round(roofline_fraction(
-            est_bytes, dt_s, peaks["hbm_gbps"], n_chips
-        ), 9),
-    )
-    METRICS.gauge(
-        "roofline.tok_s_per_chip",
-        round(tokens / dt_s / max(1, n_chips), 3),
-    )

@@ -1,7 +1,7 @@
 """Engine flight recorder: a bounded, lock-cheap ring of timestamped
 engine events, exportable as Chrome-trace/Perfetto JSON.
 
-Two record shapes share the ring:
+Three record shapes share the ring:
 
 - **instant** events — scheduler decisions and lifecycle edges (admission,
   turbo arm/depth, rollback, preempt/snapshot/resume, drain, breaker
@@ -13,10 +13,21 @@ Two record shapes share the ring:
   (block-until-ready / np.asarray returned). The Chrome-trace export
   splits each into an ``<name>.issue`` and ``<name>.sync`` complete
   ("X") event, so a Perfetto timeline shows host-issue vs
-  device+transport time per dispatch.
+  device+transport time per dispatch. Each carries ``seq``, the
+  recorder's running dispatch number;
+- **host spans** — ``with FLIGHT.span(name, **tags):`` records a named
+  stretch of host work with its begin and end (the scheduler loop's
+  phases between dispatches: ``loop.reap``, ``loop.ctl``, ``loop.admit``,
+  ``loop.build``, ``loop.deliver``, ``loop.idle``). A span is two
+  ``perf_counter`` calls and one append; it is also the one place that
+  opens a ``jax.profiler.TraceAnnotation``, so a profiler capture
+  (``POST /debug/profile``) shows the host phases beside the device
+  operations in the profiler's own trace, with no clock mapping.
 
-Every record is tagged with the request trace id(s) it served, the
-serving-mesh tag, and (where meaningful) the batch slot. The hot path is
+All timestamps are ``time.perf_counter()`` values — the clock request
+traces (obs/trace.py) use too. Every record is tagged with the request
+id(s) it served, the serving-mesh tag, and (where meaningful) the batch
+slot. The hot path is
 one ``deque.append`` of a plain tuple — CPython's deque append is atomic
 under the GIL, so recording takes no lock; only snapshot/export does.
 
@@ -39,17 +50,21 @@ must read as zero.
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import json
 import os
 import threading
 import time
 from collections import Counter, deque
 
-from fei_tpu.obs.metrics import METRICS
+from fei_tpu.obs.metrics import METRICS, _jax_annotation
 
 # record tuples: ("i", name, ts, tags) | ("X", name, t0, t_issue, t1, tags)
+# | ("S", name, t0, t1, tags)
 _INSTANT = "i"
 _DISPATCH = "X"
+_SPAN = "S"
 
 
 def _ring_size() -> int:
@@ -67,6 +82,7 @@ class FlightRecorder:
             maxlen=_ring_size() if maxlen is None else max(16, int(maxlen))
         )
         self._lock = threading.Lock()  # guards export/reset, not recording
+        self._seq = itertools.count()  # next() is atomic under the GIL
 
     # -- recording (lock-free: one atomic deque.append) ------------------
 
@@ -86,9 +102,33 @@ class FlightRecorder:
                  **args) -> None:
         """Record one device dispatch: ``t0`` call begin, ``t_issue`` the
         jitted call returned (dispatch issued, device running), ``t1``
-        host sync complete. All three are time.perf_counter() values."""
+        host sync complete. All three are time.perf_counter() values.
+        The record is stamped ``seq``, this recorder's running dispatch
+        number, so a reader can tell whether the ring dropped any."""
         tags = self._tags(rid, rids, mesh, slot, args)
+        tags["seq"] = next(self._seq)
         rec = (_DISPATCH, name, t0, t_issue, t1, tags)
+        self._ring.append(rec)
+        self._spill(rec)
+
+    @contextlib.contextmanager
+    def span(self, name: str, **tags):
+        """Record the enclosed host work as one span (begin, end). Also
+        opens a ``jax.profiler.TraceAnnotation`` of the same name, which
+        costs nothing measurable while no profiler capture is running
+        and puts the span into the capture's host plane while one is."""
+        with _jax_annotation(name):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.record_span(name, t0, time.perf_counter(), **tags)
+
+    def record_span(self, name: str, t0: float, t1: float, **tags) -> None:
+        """Append a host span whose ends the caller timed itself — for a
+        stretch that no ``with`` block can enclose (the loop's idle
+        stretch runs over many iterations)."""
+        rec = (_SPAN, name, t0, t1, tags)
         self._ring.append(rec)
         self._spill(rec)
 
@@ -123,6 +163,10 @@ class FlightRecorder:
             _, name, ts, tags = rec
             return {"kind": "instant", "name": name,
                     "ts": round(ts, 6), "tags": tags}
+        if rec[0] == _SPAN:
+            _, name, t0, t1, tags = rec
+            return {"kind": "span", "name": name, "ts": round(t0, 6),
+                    "dur_s": round(t1 - t0, 6), "tags": tags}
         _, name, t0, t_issue, t1, tags = rec
         return {"kind": "dispatch", "name": name, "ts": round(t0, 6),
                 "issue_s": round(t_issue - t0, 6),
@@ -167,6 +211,13 @@ class FlightRecorder:
                     "name": name, "ph": "i", "s": "g",
                     "ts": round(ts * 1e6, 3), "pid": 1, "tid": 1,
                     "args": tags,
+                })
+            elif rec[0] == _SPAN:
+                _, name, t0, t1, tags = rec
+                events.append({
+                    "name": name, "ph": "X", "ts": round(t0 * 1e6, 3),
+                    "dur": round(max(0.0, t1 - t0) * 1e6, 3),
+                    "pid": 1, "tid": 2, "args": tags,
                 })
             else:
                 _, name, t0, t_issue, t1, tags = rec

@@ -362,16 +362,6 @@ METRIC_REGISTRY: dict[str, tuple[str, str]] = {
     "pool.pages_free": ("gauge", "Free KV pages."),
     "pool.pages_in_use": ("gauge", "KV pages currently referenced."),
     "prefix.entries": ("gauge", "Entries resident in the prefix cache."),
-    "roofline.frac": ("gauge",
-                      "Fraction of the aggregate HBM roofline achieved by "
-                      "the most recent decode dispatch (analytical bytes "
-                      "estimate / wall time vs the device_kind's peak × "
-                      "chips). Not published on a device without a row "
-                      "in obs/costmodel.DEVICE_PEAKS."),
-    "roofline.tok_s_per_chip": ("gauge",
-                                "Delivered tokens/s per chip over the most "
-                                "recent decode dispatch (published with "
-                                "roofline.frac)."),
     "tenant.*.queued": ("gauge",
                         "Sequences from one tenant waiting for admission "
                         "(emitted only when tenant budgets are "
@@ -425,11 +415,6 @@ METRIC_REGISTRY: dict[str, tuple[str, str]] = {
     "agent.completion": ("span", "One LLM call from the assistant loop."),
     "provider.jax_local": ("span", "One local-engine provider call."),
     "tool.*": ("span", "One tool execution (per-tool family)."),
-    "collective.*": ("span",
-                     "Sharded decode-dispatch wall time attributed to one "
-                     "active mesh axis (per-axis family; an upper bound "
-                     "on that axis's collective time — the step includes "
-                     "compute)."),
     # --- histograms (observed directly, not via span) -------------------
     "ttft_seconds": ("histogram",
                      "Time from submit to first emitted token."),

@@ -1,21 +1,32 @@
 """Per-request lifecycle traces for the paged scheduler.
 
 Every submitted sequence gets a request id and an ordered list of phase
-events — queued → admitted → prefill → first_token → completed/cancelled/
-failed/deadline_exceeded/snapshotted — kept in a bounded ring buffer
-(``FEI_TPU_TRACE_RING``, default 256) and served by ``GET /v1/traces`` on
-ui/server.py. Preempt-and-resume scheduling adds non-terminal
-``preempted`` / ``resumed`` events mid-trace: a sequence evicted under
-KV-pool pressure re-admits and continues byte-identically; ``snapshotted``
-is the terminal state of a request persisted to disk by a graceful drain
-for warm restart. Setting
-``FEI_TPU_TRACE_FILE`` additionally appends each finished trace as one
-JSONL line, the flight-recorder shape production schedulers use to debug
-tail latency after the fact.
+events — http_accepted → queued → admitted → prefill → first_token →
+first_frame → completed/cancelled/failed/deadline_exceeded/snapshotted →
+last_frame — kept in a bounded ring buffer (``FEI_TPU_TRACE_RING``,
+default 256) and served by ``GET /v1/traces`` on ui/server.py. The
+interval between two neighbouring events is a span whose parent is the
+request. ``http_accepted`` / ``first_frame`` / ``last_frame`` are the
+server's boundaries (ui/server.py) and exist only for requests that came
+over HTTP; an engine caller's trace starts at ``queued``. The id is the
+caller's where it gives one (the server's ``chatcmpl-…``, a restored
+session's own) and a fresh ``req-…`` otherwise.
 
-Timestamps are time.time() clamped to be non-decreasing within a trace,
-so consumers can rely on monotonically ordered phases even across clock
-adjustments.
+Preempt-and-resume scheduling adds non-terminal ``preempted`` /
+``resumed`` events mid-trace: a sequence evicted under KV-pool pressure
+re-admits and continues byte-identically; ``snapshotted`` is the terminal
+state of a request persisted to disk by a graceful drain for warm restart.
+Setting ``FEI_TPU_TRACE_FILE`` additionally appends each finished trace as
+one JSONL line, the flight-recorder shape production schedulers use to
+debug tail latency after the fact.
+
+One clock: events are stamped with ``time.perf_counter()``, the flight
+recorder's clock (obs/flight.py), so a request's boundaries, the
+dispatches that served it and the loop's host spans subtract from each
+other directly. ``as_dict()`` renders each event twice: ``t`` is the
+perf_counter value and ``ts`` the epoch time it maps to through the one
+``(time.time(), time.perf_counter())`` pair taken when this module is
+imported — ``ts - t`` is one constant per process.
 """
 
 from __future__ import annotations
@@ -27,6 +38,9 @@ import time
 import uuid
 from collections import deque
 from dataclasses import dataclass, field
+
+# the process's one wall-clock anchor: epoch seconds = perf_counter + this
+_EPOCH_MINUS_PERF = time.time() - time.perf_counter()
 
 TERMINAL_PHASES = (
     "completed", "cancelled", "failed", "deadline_exceeded", "snapshotted",
@@ -46,16 +60,19 @@ class RequestTrace:
     # serving-mesh tag ("ms1", "tp2", "tp2dp2", …) — post-hoc tail-latency
     # debugging needs to know which mesh mode served the request
     mesh: str = "ms1"
-    events: list = field(default_factory=list)  # [(phase, ts), ...]
+    events: list = field(default_factory=list)  # [(phase, perf_counter), ...]
 
-    def event(self, phase: str) -> None:
-        now = time.time()
-        if self.events and now < self.events[-1][1]:
-            now = self.events[-1][1]
-        self.events.append((phase, now))
+    def event(self, phase: str, t: float | None = None) -> None:
+        """Record a boundary now, or at the perf_counter value ``t`` a
+        caller took earlier (the server's ``http_accepted``)."""
+        self.events.append((phase, time.perf_counter() if t is None else t))
 
     def as_dict(self) -> dict:
-        spans = [{"phase": p, "ts": round(ts, 6)} for p, ts in self.events]
+        spans = [
+            {"phase": p, "ts": round(t + _EPOCH_MINUS_PERF, 6),
+             "t": round(t, 6)}
+            for p, t in self.events
+        ]
         dur = 0.0
         if len(self.events) >= 2:
             dur = self.events[-1][1] - self.events[0][1]
@@ -81,15 +98,31 @@ class TraceBuffer:
                 maxlen = 256
         self._lock = threading.Lock()
         self._ring: deque[RequestTrace] = deque(maxlen=max(1, maxlen))
+        # id -> newest trace with that id (a restored session re-uses its
+        # id, so an id may outlive one trace); kept in step with the ring
+        self._by_id: dict[str, RequestTrace] = {}
 
-    def start(self, prompt_tokens: int = 0, mesh: str = "ms1") -> RequestTrace:
+    def start(self, prompt_tokens: int = 0, mesh: str = "ms1",
+              rid: str | None = None,
+              t_accepted: float | None = None) -> RequestTrace:
+        """Open a trace at ``queued``. ``rid`` is the id the caller already
+        holds (None mints ``req-…``); ``t_accepted`` the perf_counter
+        value at which the server accepted the request, recorded as
+        ``http_accepted`` ahead of ``queued``."""
         tr = RequestTrace(
-            rid=f"req-{uuid.uuid4().hex[:12]}", prompt_tokens=prompt_tokens,
-            mesh=mesh,
+            rid=rid or f"req-{uuid.uuid4().hex[:12]}",
+            prompt_tokens=prompt_tokens, mesh=mesh,
         )
+        if t_accepted is not None:
+            tr.event("http_accepted", t_accepted)
         tr.event("queued")
         with self._lock:
+            if len(self._ring) == self._ring.maxlen:
+                old = self._ring[0]
+                if self._by_id.get(old.rid) is old:
+                    del self._by_id[old.rid]
             self._ring.append(tr)
+            self._by_id[tr.rid] = tr
         return tr
 
     def finish(self, trace: RequestTrace, status: str,
@@ -117,10 +150,7 @@ class TraceBuffer:
         """The trace with request id ``rid``, or None if it was never
         recorded or has been evicted from the ring."""
         with self._lock:
-            for tr in reversed(self._ring):
-                if tr.rid == rid:
-                    return tr
-        return None
+            return self._by_id.get(rid)
 
     def recent(self, limit: int = 50) -> list[dict]:
         """Most recent traces first (active ones included)."""

@@ -258,6 +258,9 @@ def _fwd_impl(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        # the kernel's name in a device trace, pinned here and not left to
+        # the enclosing function's (benchmarks/kernel_costs/names.json)
+        name="flash_attention",
     )(q_start.astype(jnp.int32), kv_length.astype(jnp.int32), qt, kt, vt)
 
     out = jnp.transpose(outs[0][:, :, :T], (0, 2, 1, 3))
@@ -485,6 +488,7 @@ def _bwd_impl(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention_dq",
     )(*args)
 
     # dk/dv per query head (grid swaps: k blocks outer, q blocks inner).
@@ -547,6 +551,7 @@ def _bwd_impl(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention_dkv",
     )(*args)
 
     dq = jnp.transpose(dq[:, :, :T], (0, 2, 1, 3))  # [B, T, H, D]

@@ -131,6 +131,7 @@ def _int4_mm_kernel(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="int4_matmul",  # its name in a device trace, pinned
     )(x[:, : K // 2], x[:, K // 2 :], p, s[:G2], s[G2:])
 
 

@@ -147,9 +147,14 @@ def _paged_call(
     k_scales: jnp.ndarray | None,
     v_scales: jnp.ndarray | None,
     window: int = 0,
+    name: str,
 ) -> jnp.ndarray:
     """Shared pallas_call plumbing for the single-query and block wrappers
-    — ONE assembly of specs/grid/scratch so the two paths cannot drift."""
+    — ONE assembly of specs/grid/scratch so the two paths cannot drift.
+    ``name`` is the kernel's name in a device trace (its operation is
+    ``<name>.<n>`` on the XLA Ops line): given here in so many words, so
+    that renaming a Python function cannot rename it
+    (benchmarks/kernel_costs/names.json lists the names readers match)."""
     B, K, rows, D = qg.shape
     page_size = k_pages.shape[2]
     max_pages = block_table.shape[1]
@@ -201,6 +206,7 @@ def _paged_call(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=name,
     )(block_table.astype(jnp.int32), limits.astype(jnp.int32), *args)
 
 
@@ -241,6 +247,7 @@ def paged_attention(
         qg, k_pages, v_pages, block_table, lengths,
         qt=1, g=G, scale=scale, interpret=interpret,
         k_scales=k_scales, v_scales=v_scales, window=window,
+        name="paged_attention",
     )
     return out.reshape(B, H, D)
 
@@ -282,6 +289,7 @@ def paged_attention_block(
         qg, k_pages, v_pages, block_table, lengths + 1,
         qt=T, g=G, scale=scale, interpret=interpret,
         k_scales=k_scales, v_scales=v_scales, window=window,
+        name="paged_attention_block",
     )
     return jnp.swapaxes(out.reshape(B, K, T, G, D), 1, 2).reshape(B, T, H, D)
 

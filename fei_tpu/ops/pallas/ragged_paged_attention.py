@@ -265,6 +265,8 @@ def _ragged_call(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        # the kernel's name in a device trace, pinned (see _paged_call)
+        name="ragged_paged_attention",
     )(
         block_table.astype(jnp.int32), limits.astype(jnp.int32),
         q_lens.astype(jnp.int32), modes.astype(jnp.int32), *args,

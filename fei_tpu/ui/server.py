@@ -376,7 +376,15 @@ class ServeAPI:
                        headers: dict | None = None) -> dict:
         """Decode the request into provider kwargs; raises on bad input
         BEFORE any engine work (the streaming path validates here before
-        committing SSE headers)."""
+        committing SSE headers).
+
+        The request is named HERE, once, before anything is parsed:
+        ``kw["request"]`` holds the id every layer below keys on (the
+        response's ``id``, the trace's, the flight records', the
+        journal's) and the perf_counter value at which the server took
+        the request up (the trace's ``http_accepted``). A fleet
+        resurrection carries the id its stream already had."""
+        request = self._new_request()
         msgs, system = _from_openai_messages(body.get("messages") or [])
         mt = body.get("max_tokens")
         if mt is None:
@@ -384,22 +392,51 @@ class ServeAPI:
         mt = 1024 if mt is None else int(mt)  # 0 is a valid explicit budget
         if mt < 0:
             raise ValueError(f"max_tokens must be >= 0, got {mt}")
+        resume = self._resume_kw(body)
+        if resume and resume["resume"].get("rid"):
+            request["id"] = resume["resume"]["rid"]
         return {
             "messages": msgs,
             "system": system,
             "tools": _from_openai_tools(body.get("tools")),
             "max_tokens": mt,
+            "request": request,
             **self._overrides_kw(body, headers),
-            **self._resume_kw(body),
+            **resume,
         }
+
+    @staticmethod
+    def _new_request() -> dict:
+        return {"id": f"chatcmpl-{uuid.uuid4().hex[:24]}",
+                "t_accepted": time.perf_counter()}
+
+    def _take_request(self, kw: dict) -> dict:
+        """Pop the request's identity out of the provider kwargs (a caller
+        that built ``kw`` without ``_parse_request`` is named here), and
+        hand it on to a provider that carries it down to the scheduler
+        (``supports_request_id``); others never see the key."""
+        request = kw.pop("request", None) or self._new_request()
+        if getattr(self.provider, "supports_request_id", False):
+            kw["request"] = request
+        return request
+
+    @staticmethod
+    def _mark(rid: str, phase: str) -> None:
+        """Stamp a server-side boundary on the request's trace (there is
+        one only where a paged scheduler served the request)."""
+        tr = TRACES.get(rid)
+        if tr is not None:
+            tr.event(phase)
 
     def _resume_kw(self, body: dict) -> dict:
         """Fleet-router resurrection extension: ``"resume": {"generated":
         [ids...], "resume_key": [a, b] | null}`` teacher-forces a dead
         replica's delivered suffix so this replica's stream replays it
-        byte-identically. Only providers that own a paged engine support
-        it; others reject loudly (silently restarting from token 0 would
-        duplicate the user-visible stream)."""
+        byte-identically; ``"id"`` is the id the stream already had, which
+        this replica's trace and records then carry on. Only providers
+        that own a paged engine support it; others reject loudly (silently
+        restarting from token 0 would duplicate the user-visible
+        stream)."""
         raw = body.get("resume")
         if raw is None:
             return {}
@@ -416,6 +453,8 @@ class ServeAPI:
             if not isinstance(key, list) or not key:
                 raise ValueError("resume.resume_key must be a list of ints")
             resume["resume_key"] = [int(x) for x in key]
+        if isinstance(raw.get("id"), str) and raw["id"]:
+            resume["rid"] = raw["id"]
         return {"resume": resume}
 
     def _mesh_tag(self) -> str:
@@ -748,6 +787,7 @@ class ServeAPI:
         except (ValueError, KeyError, TypeError) as exc:
             return 400, {"error": {"message": str(exc),
                                    "type": "invalid_request_error"}}
+        rid = self._take_request(kw)["id"]
         try:
             msgs = kw.pop("messages")
             resp = self.provider.complete(msgs, **kw)
@@ -769,10 +809,11 @@ class ServeAPI:
             log.warning("completion failed: %r", exc)
             return 500, {"error": {"message": f"{type(exc).__name__}: {exc}",
                                    "type": "server_error"}}
-        rid = f"chatcmpl-{uuid.uuid4().hex[:24]}"
-        return 200, _to_openai_response(
+        payload = _to_openai_response(
             resp, body.get("model") or self.model_name, rid
         )
+        self._mark(rid, "last_frame")  # one body: its only frame
+        return 200, payload
 
     def _overrides_kw(self, body: dict, headers: dict | None = None) -> dict:
         """Per-request sampling knobs — only for providers that declare
@@ -790,7 +831,8 @@ class ServeAPI:
         validation already happened, so the 200 + SSE headers the caller
         committed were safe. Provider/engine errors mid-stream become an
         error frame followed by [DONE] instead of a dropped connection."""
-        rid = f"chatcmpl-{uuid.uuid4().hex[:24]}"
+        kw = dict(kw)
+        rid = self._take_request(kw)["id"]
         model = body.get("model") or self.model_name
         created = int(time.time())
 
@@ -819,9 +861,9 @@ class ServeAPI:
         # clients ignore the extra key.
         export: dict | None = None
         if getattr(self.provider, "supports_resume", False):
-            export = {}
-            kw = dict(kw, export=export)
+            export = kw["export"] = {}
         sent_toks = 0
+        framed = False  # the first content frame has left this generator
         try:
             from fei_tpu.engine.faults import FAULTS
 
@@ -848,6 +890,9 @@ class ServeAPI:
                                 }
                                 sent_toks = n
                         yield frame({"content": delta}, fei=ext)
+                        if not framed:
+                            framed = True
+                            self._mark(rid, "first_frame")
                         # the hard-kill seam the chaos_crash stage arms:
                         # dies AFTER the frame left the handler, so the
                         # client-observed suffix is the worst case the
@@ -873,6 +918,7 @@ class ServeAPI:
                 "message": f"{type(exc).__name__}: {exc}",
                 "type": etype,
             }}).encode() + b"\n\n")
+            self._mark(rid, "last_frame")
             yield b"data: [DONE]\n\n"
             return
         finish = "stop"
@@ -891,6 +937,7 @@ class ServeAPI:
                 ]
             })
         yield frame({}, finish=finish)
+        self._mark(rid, "last_frame")
         yield b"data: [DONE]\n\n"
 
 

@@ -1,0 +1,212 @@
+"""Names on the device: ``jax.named_scope`` in the step programs, and the
+names under which kernels and programs appear in a device trace.
+
+The benchmark's readers find the step programs (``jit_multi``,
+``jit_ragged``) and the attention kernels (``paged_attention.N``, …) by
+name (``benchmarks/kernel_costs/names.json``). A Pallas call's operation is
+named after the innermost scope on its path, so the kernels name
+themselves (``pl.pallas_call(name=...)``) and the ``attention`` scope sits
+outside their jitted wrappers. These tests fail where a refactor would
+otherwise rename a metric's source.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from fei_tpu.engine.engine import InferenceEngine
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(ROOT, "benchmarks", "kernel_costs", "names.json"),
+          encoding="utf-8") as _f:
+    NAMES = json.load(_f)
+
+# the flat vocabulary every step program uses (docs/OBSERVABILITY.md)
+LAYER_SCOPES = {"embed", "norm", "attn_qkv", "rope", "kv_write", "attention",
+                "attn_out", "mlp", "pool_carry"}
+STEP_SCOPES = LAYER_SCOPES | {"lm_head", "sample", "grammar_mask"}
+
+
+@pytest.fixture(scope="module")
+def programs():
+    """The raw jitted ``multi``, ``ragged`` and paged ``chunk`` programs of
+    a tiny paged engine (grammar variants, final chunk) with arguments to
+    lower them on."""
+    engine = InferenceEngine.from_config(
+        "tiny", paged=True, batch_size=2, max_seq_len=256
+    )
+    engine._compiles.wrap = lambda family, key, fn: fn  # no timing shim
+    sched = engine.scheduler
+    sched._ensure_pool()
+    B, C, V = sched.B, 16, engine.cfg.vocab_size
+    width = sched._pool.block_table.shape[1]
+    step = [jnp.zeros((B, 1), jnp.int32), jnp.zeros((B, 2), jnp.uint32),
+            jnp.zeros((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
+            jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.float32)]
+    gram = dict(gstates=jnp.zeros((B,), jnp.int32),
+                gremain=jnp.ones((B,), jnp.int32),
+                table=jnp.zeros((3, V), jnp.int32),
+                mind=jnp.zeros((3,), jnp.int32))
+    chunk = [jnp.zeros((1, C), jnp.int32), jnp.zeros((1, width), jnp.int32),
+             jnp.zeros((1,), jnp.int32), jnp.int32(0)]
+    head = [engine.params, sched._pool]
+    fns = {
+        "multi": (sched._multi_fn(2, True), head + step, gram),
+        "ragged": (sched._ragged_fn(2, C, True, True), head + chunk + step,
+                   gram),
+        "chunk": (sched._paged_chunk_fn(C, True), head + chunk, {}),
+    }
+    yield fns
+    engine.close()
+
+
+def _scopes_in(fn, args, kw) -> set[str]:
+    """Every scope word on any operation's path in the lowered program."""
+    text = fn.lower(*args, **kw).as_text(debug_info=True)
+    words: set[str] = set()
+    for path in re.findall(r'loc\("([^"]+)"', text):
+        words.update(path.split("/"))
+    return words
+
+
+@pytest.mark.parametrize("program,expected", [
+    ("multi", STEP_SCOPES),
+    ("ragged", STEP_SCOPES),
+    # the chunk program samples nothing: its first token is sampled from
+    # the logits it returns, outside any step program
+    ("chunk", LAYER_SCOPES | {"lm_head"}),
+])
+def test_step_programs_carry_every_scope(programs, program, expected):
+    fn, args, kw = programs[program]
+    missing = expected - _scopes_in(fn, args, kw)
+    assert not missing, f"{program} lost scopes {sorted(missing)}"
+
+
+def test_kv_read_marks_the_explicit_prefix_gather():
+    """The paged kernels read pages through the block table themselves;
+    the one explicit gather of pages that feeds a kernel is the dense
+    staging admission's prefix gather."""
+    from fei_tpu.models.llama import KVCache
+
+    engine = InferenceEngine.from_config(
+        "tiny", paged=True, batch_size=2, max_seq_len=256
+    )
+    try:
+        engine._compiles.wrap = lambda family, key, fn: fn
+        sched = engine.scheduler
+        sched._ensure_pool()
+        bucket = 2 * engine.page_size
+        dense = KVCache.create(engine.cfg, 1, bucket, dtype=engine.dtype)
+        fn = sched._gather_fn(1, bucket)
+        words = _scopes_in(
+            fn, [sched._pool, jnp.zeros((1,), jnp.int32), dense,
+                 jnp.int32(8)], {},
+        )
+        assert "kv_read" in words
+    finally:
+        engine.close()
+
+
+@pytest.mark.parametrize("program", ["multi", "ragged"])
+def test_step_program_trace_names(programs, program):
+    """A jitted program is ``jit_<function name>`` on the trace's XLA
+    Modules line: the names the readers match must be these functions'."""
+    fn, _, _ = programs[program]
+    assert "jit_" + fn.__name__ in NAMES["step_programs"]
+
+
+def test_chunk_program_trace_name(programs):
+    assert programs["chunk"][0].__name__ == "chunk"  # jit_chunk (PERF.md)
+
+
+def _pallas_names(fn, *args, **kw) -> list[str]:
+    """The ``name`` of every pallas_call in the traced function."""
+    found: list[str] = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn.params["name"])
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args, **kw).jaxpr)
+    return found
+
+
+def _pool(P=5, K=2, ps=4, D=8):
+    return jnp.zeros((P, K, ps, D), jnp.float32)
+
+
+def _kernel_calls():
+    from fei_tpu.ops.pallas.flash_attention import flash_attention
+    from fei_tpu.ops.pallas.paged_attention import (
+        paged_attention,
+        paged_attention_block,
+    )
+    from fei_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention,
+    )
+
+    bt = jnp.zeros((2, 2), jnp.int32)
+    ln = jnp.ones((2,), jnp.int32)
+    q1 = jnp.zeros((2, 4, 8), jnp.float32)
+    qT = jnp.zeros((2, 3, 4, 8), jnp.float32)
+    dense = jnp.zeros((2, 8, 2, 8), jnp.float32)
+    return {
+        "paged_attention": (
+            "paged_attention",
+            lambda: _pallas_names(paged_attention, q1, _pool(), _pool(), bt, ln),
+        ),
+        "paged_attention_block": (
+            "paged_attention",
+            lambda: _pallas_names(
+                paged_attention_block, qT, _pool(), _pool(), bt, ln),
+        ),
+        "ragged_paged_attention": (
+            "ragged_paged_attention",
+            lambda: _pallas_names(
+                ragged_paged_attention, qT, _pool(), _pool(), bt, ln, ln),
+        ),
+        "flash_attention": (
+            "flash_attention",
+            lambda: _pallas_names(
+                flash_attention, dense[:, :3].repeat(2, axis=2), dense, dense,
+                jnp.zeros((2,), jnp.int32), jnp.full((2,), 3, jnp.int32)),
+        ),
+    }
+
+
+@pytest.mark.parametrize("call", ["paged_attention", "paged_attention_block",
+                                  "ragged_paged_attention", "flash_attention"])
+def test_kernels_name_themselves_as_names_json_lists(call):
+    kernel, names = _kernel_calls()[call]
+    got = names()
+    assert got, "no pallas_call traced"
+    assert set(got) == {call}  # the explicit name=, not a function's
+    assert call in NAMES["kernels"][kernel]
+    assert kernel in NAMES["attention"]
+
+
+def test_flash_backward_kernels_are_named():
+    from fei_tpu.ops.pallas.flash_attention import flash_attention
+
+    q = jnp.asarray(np.random.default_rng(0).normal(size=(1, 8, 2, 8)),
+                    jnp.float32)
+    zero, full = jnp.zeros((1,), jnp.int32), jnp.full((1,), 8, jnp.int32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, zero, full).sum()
+
+    got = _pallas_names(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert {"flash_attention_dq", "flash_attention_dkv"} <= set(got)

@@ -21,8 +21,9 @@ The claims under test:
   compiles and zero recompiles, while deliberately dropping a jit cache
   reads as a recompile (the silent-20s-shard_map-recompile tripwire);
 - the analytical cost model matches hand-computed arithmetic from the
-  model config (weights-minus-embed stream, K/V row bytes), and the live
-  roofline gauges are populated by real scheduler dispatches;
+  model config (weights-minus-embed stream, K/V row bytes); nothing
+  publishes a per-dispatch roofline or collective gauge any more (a
+  kernel's roofline share is a benchmark metric, read from a trace);
 - a KV-pressure preempt → resume round trip leaves rid-tagged
   ``preempt`` / ``resume`` / ``admit`` instants on the timeline,
   retrievable per-request via ``for_rid`` and ``GET /v1/traces/<id>``.
@@ -341,11 +342,22 @@ class TestSchedulerFlight:
                        if r["name"] == "resume")
         assert resumed["tags"]["generated"] >= 1
 
-    def test_no_roofline_gauges_off_the_peak_table(self, flown):
-        # the CPU has no row in DEVICE_PEAKS: nothing is published
-        gauges = METRICS.snapshot()["gauges"]
-        assert "roofline.frac" not in gauges
-        assert "roofline.tok_s_per_chip" not in gauges
+    def test_dispatch_path_publishes_no_roofline_or_collective(self, flown):
+        # PR 25 took the per-dispatch accountants out: a real scheduler
+        # run leaves no such gauge or histogram, the registry declares
+        # none, and the call sites are gone
+        from fei_tpu.engine import sched_decode
+        from fei_tpu.obs.registry import METRIC_REGISTRY
+
+        snap = METRICS.snapshot()
+        for section in ("gauges", "histograms", "spans"):
+            assert not [k for k in snap[section]
+                        if k.startswith(("roofline.", "collective."))]
+        assert not [k for k in METRIC_REGISTRY
+                    if k.startswith(("roofline.", "collective."))]
+        assert not hasattr(costmodel, "account_dispatch")
+        assert not hasattr(costmodel, "account_ragged_dispatch")
+        assert not hasattr(sched_decode.DecodeMixin, "_record_collective_time")
 
     def test_timeline_endpoint_end_to_end(self, flown):
         from fei_tpu.ui.server import ServeAPI
@@ -422,29 +434,11 @@ class TestCostModel:
         ) == pytest.approx(0.25)
         assert costmodel.roofline_fraction(int(50e9), 0.0, 100.0) == 0.0
 
-    def test_peak_table_known_kind_publishes_unknown_does_not(
-        self, engine, monkeypatch
-    ):
-        from fei_tpu.obs import metrics
-
-        # a private registry: the process-wide one must stay free of
-        # roofline gauges for test_no_roofline_gauges_off_the_peak_table
-        own = metrics.Metrics()
-        monkeypatch.setattr(metrics, "METRICS", own)
+    def test_peak_table_is_keyed_by_device_kind(self):
         assert costmodel.device_peaks() is None  # the CPU is not a row
-        costmodel.account_dispatch(engine, 1, 10, 1, 0.01)
-        assert own.snapshot()["gauges"] == {}
-
-        v5e = costmodel.DEVICE_PEAKS["TPU v5 lite"]
-        assert v5e == {"hbm_gbps": 819.0, "bf16_tflops": 197.0}
-        monkeypatch.setattr(costmodel, "device_peaks", lambda: v5e)
-        costmodel.account_dispatch(engine, 1, 10, 1, 0.01)
-        est = costmodel.dispatch_bytes(engine, 1, 10, 1)
-        gauges = own.snapshot()["gauges"]
-        assert gauges["roofline.frac"] == pytest.approx(
-            est / 0.01 / 819e9, rel=1e-3
-        )
-        assert gauges["roofline.tok_s_per_chip"] == pytest.approx(100.0)
+        assert costmodel.DEVICE_PEAKS["TPU v5 lite"] == {
+            "hbm_gbps": 819.0, "bf16_tflops": 197.0,
+        }
 
     def test_chips_for_tag(self):
         assert costmodel.chips_for_tag(None) == 1
