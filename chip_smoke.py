@@ -139,6 +139,7 @@ def kernels_child(rehearse: bool) -> int:
         paged_attention_block,
     )
     from fei_tpu.ops.pallas.ragged_paged_attention import (
+        query_tile,
         ragged_paged_attention,
     )
     from fei_tpu.utils.platform import device_info, enable_compile_cache
@@ -149,14 +150,14 @@ def kernels_child(rehearse: bool) -> int:
     if rehearse:
         # same structure at toy extents: the window bites, pages are
         # shuffled, rows are mixed
-        D, ps, K, G, win, max_pages, R, C = 32, 8, 2, 2, 24, 16, 4, 16
+        D, ps, K, G, win, max_pages, C = 32, 8, 2, 2, 24, 16, 16
         flash_T, flash_S, flash_q0 = 64, 128, 32
     else:
         # mistral-7b as served: head_dim 128, 64-token pages, 8 kv heads,
         # 4 query heads each, window 4096, 4 slots x 8192 positions,
-        # FEI_TPU_RAGGED_ROWS 8, FEI_TPU_PREFILL_CHUNK 256; the dense
-        # engine prefills a 4096 bucket into an 8192 cache
-        D, ps, K, G, win, max_pages, R, C = 128, 64, 8, 4, 4096, 128, 8, 256
+        # FEI_TPU_PREFILL_CHUNK 256; the dense engine prefills a 4096
+        # bucket into an 8192 cache
+        D, ps, K, G, win, max_pages, C = 128, 64, 8, 4, 4096, 128, 256
         flash_T, flash_S, flash_q0 = 4096, 8192, 2048
     B, H = 4, K * G
     max_len = max_pages * ps
@@ -214,7 +215,9 @@ def kernels_child(rehearse: bool) -> int:
 
     def check_ragged_mixed():
         # the merged dispatch's one call: B decode rows padded to the
-        # R-row tile, then the chunk in groups of R positions
+        # chunk's query tile, then the chunk: one tile of C positions
+        # at every shape here (forward_paged_merged asks the same rule)
+        R = query_tile(C, G, D)
         nG = C // R
         qd = rand(keys[4], (B, H, D))
         qc = rand(keys[5], (1, C, H, D))

@@ -10,6 +10,7 @@ PagedScheduler state — see sched_admission.py for the rationale.
 
 from __future__ import annotations
 
+import math
 import time
 
 import jax
@@ -20,6 +21,7 @@ from fei_tpu.engine.faults import FAULTS
 from fei_tpu.engine.sampling import sample_logits_dynamic
 from fei_tpu.models.llama import forward_paged
 from fei_tpu.obs.flight import FLIGHT
+from fei_tpu.ops.pallas.ragged_paged_attention import grid_of as ragged_grid_of
 from fei_tpu.parallel.mesh import mesh_tag
 from fei_tpu.utils.logging import get_logger
 from fei_tpu.utils.metrics import METRICS
@@ -470,7 +472,9 @@ class DecodeMixin:
         what the dispatch ran: ``ctx``, each active slot's context length
         (prompt + generated) at the first step, in the order of ``rids``,
         and for a merged dispatch ``chunk_lo``, the tokens of the riding
-        request already in pages before its chunk."""
+        request already in pages before its chunk, and ``attn_steps``,
+        the grid steps of one layer's ragged attention call (a shard's,
+        under tp), from the shapes."""
         eng = self.engine
         with FLIGHT.span("loop.build"):
             args, kw, grammared, pc = self._build_step_args(active, n, mask)
@@ -515,9 +519,17 @@ class DecodeMixin:
             # NO separate "dispatch.prefill_chunk" record for a merged
             # chunk — that count dropping under overlap IS the measured
             # dispatch reduction (pinned in tests/test_ragged_attention)
+            cfg = eng.cfg
+            tp = eng.mesh.shape.get("tp", 1) if eng.mesh is not None else 1
             extra = {
                 "ragged": True, "chunk_tokens": pc["hi"] - pc["lo"],
                 "chunk_rid": pc["st"]["seq"].rid, "chunk_lo": pc["lo"],
+                "attn_steps": math.prod(ragged_grid_of(
+                    self.B, pc["toks"].shape[1], cfg.num_kv_heads // tp,
+                    cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_,
+                    eng.page_size, self._pool.block_table.shape[1],
+                    cfg.sliding_window or 0,
+                )),
             }
             METRICS.incr("engine.ragged_dispatches")
             METRICS.gauge("engine.kernel_loop_depth", n * eng.cfg.num_layers)
@@ -663,7 +675,6 @@ class DecodeMixin:
         if key not in self._step_jit:
             cfg = self.engine.cfg
             mesh = self.engine.mesh
-            rows = self.ragged_rows
             from fei_tpu.models.llama import _logits, forward_paged_merged
 
             def ragged(params, pool, ctoks, crow, cpos, clast, tokens,
@@ -672,7 +683,7 @@ class DecodeMixin:
                 sampler = _make_sampler(grammared, False)
                 chunk_hidden, logits, pool = forward_paged_merged(
                     params, cfg, ctoks, crow, cpos, tokens, pool,
-                    kernel_mesh=mesh, rows=rows,
+                    kernel_mesh=mesh,
                 )
                 logits = logits[:, -1, :]
                 nxt, new_keys, gstates, gremain = sampler(

@@ -223,12 +223,6 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
                 f"unknown FEI_TPU_ATTENTION {attn!r} (ragged | paged)"
             )
         self.ragged_attention = attn == "ragged"
-        # query-row tile of the ragged kernel: the chunk splits into
-        # groups of this many positions (decode rows pad up to it). Any
-        # value is bitwise-equivalent; 8 keeps the f32 row scratch small
-        self.ragged_rows = max(
-            1, int(_os.environ.get("FEI_TPU_RAGGED_ROWS", "8"))
-        )
         self._pending_chunk: dict | None = None  # deferred merge chunk
         # paged-NATIVE chunked prefill: admission chunks write K/V straight
         # into pool pages and attend via the multi-query block kernel

@@ -720,7 +720,6 @@ def forward_paged_merged(
     routed_moe: bool = False,
     moe_mesh=None,
     kernel_mesh=None,
-    rows: int = 8,
 ) -> tuple[jnp.ndarray, jnp.ndarray, object]:
     """One ragged dispatch serves a prefill chunk AND a decode step.
 
@@ -731,14 +730,17 @@ def forward_paged_merged(
     decode batch's [B, 1] tokens each keep their own legacy-shaped
     projections/norms/MLP matmuls (bitwise the ops the solo programs run),
     and only the two attention invocations merge into a single ragged
-    kernel call over ``B + ceil(C/rows)`` virtual rows — decode rows at
-    q_len=1 against the live table, the chunk split into ``rows``-position
-    groups against the admitting slot's row. Splitting is bitwise-neutral:
-    each query row's online softmax walks the same pages in the same
-    order, and pages beyond a row's causal limit are exact no-ops for it
-    (masked scores underflow to p=0 with correction=1 once any live page
-    has been seen — the property the legacy block kernel's per-row limits
-    already rely on).
+    kernel call over ``B + ceil(C/R)`` virtual rows — decode rows at
+    q_len=1 against the live table, the chunk as ONE query tile of
+    ``R = C`` positions against the admitting slot's row (the tile the
+    solo block kernel runs it at, so its pages are fetched once a kv
+    head). Only a chunk whose ``C * g`` rows would not fit the kernel's
+    tile (``query_tile``) splits into ``R``-position groups. Splitting is
+    bitwise-neutral: each query row's online softmax walks the same pages
+    in the same order, and pages beyond a row's causal limit are exact
+    no-ops for it (masked scores underflow to p=0 with correction=1 once
+    any live page has been seen — the property the legacy block kernel's
+    per-row limits already rely on).
 
     Writes commute: chunk K/V lands in the admitting slot's pages (its
     LIVE row is still zeroed, so no decode row reads them), decode K/V in
@@ -749,6 +751,7 @@ def forward_paged_merged(
     """
     from fei_tpu.engine.paged_cache import write_token_kv
     from fei_tpu.ops.pallas.ragged_paged_attention import (
+        query_tile,
         ragged_paged_attention,
         ragged_paged_attention_sharded,
     )
@@ -756,8 +759,8 @@ def forward_paged_merged(
     B, _ = dec_tokens.shape
     _, C = chunk_toks.shape
     K, d, Hq = cfg.num_kv_heads, cfg.head_dim_, cfg.num_heads
-    R = rows
-    nG = -(-C // R)  # chunk groups of R query positions
+    R = query_tile(C, Hq // K, d)
+    nG = -(-C // R)  # chunk groups of R query positions: 1 where C fits
     Cp = nG * R
     Bv = B + nG
     chunk_positions = chunk_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]

@@ -14,17 +14,45 @@ first query row's causal bound (kv positions < limit are visible, i.e.
 start+1 in the block wrapper's convention) and ``q_len`` is how many of
 the tile's R query positions are live. Decode rows run with q_len=1,
 chunked-prefill rows with q_len up to R, in the SAME invocation over the
-shared page pool:
+shared page pool.
 
-- the page-liveness predicate becomes per-row dynamic
-  (``pi*page_size < limit + (q_len-1)`` instead of the static ``qt``),
-  so decode rows stop DMAing pages exactly where the single-token kernel
-  would and prefill rows read exactly the pages their chunk group covers;
-- everything else — online-softmax (m, l, acc) scratch, per-row causal
-  mask ``pos < limit + row_t``, int8 scale folding, sliding-window page
-  clamp — is the legacy body unchanged, so each row's arithmetic is
-  bitwise the row the legacy kernel computes (tests/test_ragged_attention
-  pins this per row, greedy and seeded, ms1 and tp2).
+The grid is ``(rows, kv heads, steps)``:
+
+- a row's query tile is R positions x g heads of the group. The merged
+  dispatch gives the chunk ONE row of R = C positions (``query_tile``:
+  the tile the solo block kernel runs it at, so the chunk's pages are
+  fetched once a kv head), decode rows the same tile with one live
+  position, whose update touches its first g rows only;
+- a step covers ``n`` consecutive page slots (``pages_per_step``: 8 at
+  64-token pages), each through a block spec of its own, and takes them
+  one at a time, in table order, each with the legacy per-page
+  online-softmax update at the legacy shapes — so each row's arithmetic
+  is bitwise the row the legacy kernel computes
+  (tests/test_ragged_attention pins this per row, greedy and seeded, ms1
+  and tp2). Liveness is per row and per step
+  (``slot*page_size < limit + (q_len-1)``): decode rows stop where the
+  single-token kernel would, and a dead slot inside a live step is an
+  exact no-op (see ``_ragged_kernel``);
+- the steps walk what a row can have live, not the table
+  (``walk_pages``): from the row's first live page (under a sliding
+  window, the page of position ``limit - window``) as many slots as
+  ``window + R - 1`` positions can touch, through a per-row walk table
+  in which dead slots repeat the last live page, so their copies are
+  elided. At mistral-7b's serving shapes (4 decode rows + a 256-token
+  chunk, 8 kv heads, 128 slots of 64, window 4096) that is 5 x 8 x 9 =
+  360 steps a layer.
+
+What a v5e charges (my chip run, PR 26; PERF.md): a grid step costs about
+0.05-0.1 us per page block whether the page is live or dead, so the time
+went into steps x blocks, which more pages a step do not lower; what did:
+fewer visits (one tile for the chunk, no steps below the window), index
+maps that are one scalar load, and a straight-line step body. The page
+update itself stays the solo kernels': 64-wide products at a 1024-row
+tile, about 1.3 us a page, is what is left (ROADMAP S3).
+
+Everything else — online-softmax (m, l, acc) scratch, per-row causal
+mask ``pos < limit + row_t``, int8 scale folding, sliding-window mask —
+is the legacy body unchanged.
 
 Pad rows (t >= q_len) compute garbage that is confined to their own
 (m, l, acc) rows and never read back — the same argument the legacy
@@ -51,26 +79,98 @@ from jax.experimental.pallas import tpu as pltpu
 from fei_tpu.ops.pallas.paged_attention import NEG_INF
 
 
+# a grid step covers up to this many kv positions, in at most this many
+# pages: a step's fixed cost grows with its page blocks (about 0.1 us a
+# block and step on a v5e, live or dead), so more pages a step buy no
+# time by themselves — what they buy is a step body long enough for the
+# compiler to overlap one page's matmuls with the next page's loads
+_STEP_POSITIONS = 512
+_STEP_PAGES_MAX = 8
+# query rows (positions x head group) of one tile at head_dim <= 128: the
+# tile the solo block kernel runs a 256-token chunk at (g = 4). q and out
+# blocks, the f32 (m, l, acc) scratch and one page's scores then take
+# about 4 MB of VMEM, far inside the default limit
+_TILE_ROWS_MAX = 1024
+
+
+def pages_per_step(page_size: int, max_pages: int) -> int:
+    """Page slots one grid step walks (8 at page 64 x 128 slots)."""
+    return max(1, min(_STEP_PAGES_MAX, _STEP_POSITIONS // page_size, max_pages))
+
+
+def query_tile(C: int, g: int, D: int) -> int:
+    """Query positions of one tile for a chunk of ``C``: the whole chunk
+    where its ``C * g`` rows fit the tile, else the largest tile that
+    does (the chunk then takes several virtual rows, as bitwise-neutral
+    as it ever was: each query row walks the same pages in the same
+    order)."""
+    rows = _TILE_ROWS_MAX * 128 // max(D, 128)
+    return max(1, min(C, rows // g))
+
+
+def walk_pages(page_size: int, max_pages: int, R: int, window: int) -> int:
+    """Page slots a row of ``R`` query positions can have live: the whole
+    table, or under a window only the pages that ``window + R - 1``
+    consecutive positions can touch (69 of 128 at window 4096, R 256,
+    page 64). The grid walks these, from the row's first live page."""
+    if not window:
+        return max_pages
+    return min(max_pages, (window + R - 2) // page_size + 2)
+
+
+def _steps(page_size: int, max_pages: int, R: int, window: int) -> tuple[int, int]:
+    """(page slots a grid step, grid steps a row) of a call."""
+    n = pages_per_step(page_size, max_pages)
+    return n, -(-walk_pages(page_size, max_pages, R, window) // n)
+
+
+def grid_of(
+    B: int, C: int, K: int, g: int, D: int, page_size: int, max_pages: int,
+    window: int = 0,
+) -> tuple[int, int, int]:
+    """The grid of one merged call: ``B`` decode rows and a ``C``-token
+    chunk over ``K`` (local) kv heads. Its product is the ``attn_steps``
+    tag of a merged dispatch's flight record."""
+    R = query_tile(C, g, D)
+    return (B + -(-C // R), K, _steps(page_size, max_pages, R, window)[1])
+
+
 def _ragged_kernel(
     # scalar prefetch
-    block_table_ref,  # [Bv, max_pages] page index per (row, slot)
+    walk_ref,  # [Bv, steps*n] page index per (row, walked slot)
     limit_ref,  # [Bv] first query row's causal bound (kv pos < limit)
     qlen_ref,  # [Bv] live query positions in this row's tile (1..R)
     mode_ref,  # [Bv] 1 = decode row (qt=1 program arithmetic), 0 = prefill
-    # blocks: q [1,1,R*G,D], k/v [1,1,page_size,D]; int8 pools add
-    # ks/vs [1,1,1,page_size] per-slot scale rows before o [1,1,R*G,D]
+    # blocks: q [1,1,R*G,D], then n x k, n x v [1,1,page_size,D] (the
+    # step's consecutive page slots); int8 pools add n x ks, n x vs
+    # [1,1,1,page_size] per-slot scale rows before o [1,1,R*G,D]
     *refs,
     page_size: int,
+    n: int,
     scale: float,
     kv_int8: bool,
     g: int = 1,
     window: int = 0,
 ):
     """Online-softmax ragged attention over one (virtual seq, kv-head)
-    tile. Identical to paged_attention._decode_kernel except the static
-    ``qt`` becomes the per-row dynamic ``qlen_ref[b]`` — a decode row
-    (q_len=1) and a chunk row (q_len=R) predicate their pages
-    independently inside one grid.
+    tile, ``n`` page slots a grid step, from the row's first live page
+    on. Each slot's update is paged_attention._decode_kernel's body
+    except that the static ``qt`` is the per-row dynamic ``qlen_ref[b]``
+    — a decode row (q_len=1) and a chunk row (q_len=R) predicate their
+    pages independently inside one grid — and the slots of a step are
+    taken one at a time, in table order, so a row walks its pages exactly
+    as it would one a step.
+
+    Liveness is the step's: a step any of whose slots is live runs all n
+    updates, unconditionally, so that the compiler sees one straight line
+    (page j+1's loads and first matmul overlap page j's softmax). A dead
+    slot inside a live step is an exact no-op for every live query row:
+    its positions are all masked, so past the row's last page p = 0 and
+    correction = 1, and before its first whatever it adds (m is still
+    NEG_INF there, p = 1) is wiped by the first visible page's
+    correction = exp(NEG_INF - m) = 0 — the argument the chunk's rows
+    have always needed for pages only their later positions see. Its
+    block is the row's nearest live page (``_ragged_call``), finite data.
 
     ``mode``: the two legacy programs run their dots at different row
     counts (qt=1 → g rows, block → qt*g rows), and small-row matmuls can
@@ -78,18 +178,17 @@ def _ragged_kernel(
     apart. Bitwise identity to BOTH therefore needs per-row arithmetic
     shape, not just per-row masking: mode=1 rows run the online update
     on the tile's first g rows only (exactly the decode token's head
-    group) at the qt=1 program's [g]-row shapes, branch-selected per row
-    so neither side pays the other's matmul. mode=0 rows run the
-    full-tile update, whose R*g-row blocks are bitwise the block
-    program's qt*g-row blocks."""
+    group) at the qt=1 program's [g]-row shapes, through ref slices, so
+    a decode row's update never touches the rest of the tile. mode=0
+    rows run the full-tile update, whose R*g-row blocks are bitwise the
+    block program's qt*g-row blocks."""
+    q_ref = refs[0]
+    k_refs, v_refs = refs[1:1 + n], refs[1 + n:1 + 2 * n]
     if kv_int8:
-        q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
-        ks_ref = vs_ref = None
+        ks_refs, vs_refs = refs[1 + 2 * n:1 + 3 * n], refs[1 + 3 * n:1 + 4 * n]
+    o_ref, m_ref, l_ref, acc_ref = refs[-4:]
     b = pl.program_id(0)
     pi = pl.program_id(2)
-    num_pages = pl.num_programs(2)
 
     @pl.when(pi == 0)
     def _init():
@@ -99,96 +198,77 @@ def _ragged_kernel(
 
     limit = limit_ref[b]
     qlive = qlen_ref[b]
+    # the walk starts at the row's first live page: under a window the
+    # pages entirely below every row's window are never visited
+    first = jnp.maximum((limit - window) // page_size, 0) if window else 0
+    slot0 = first + pi * n
+    # live while the step starts under the LAST live query row's bound
+    step_live = slot0 * page_size < limit + (qlive - 1)
 
-    # per-row page liveness: the LAST live query row's causal bound
-    page_live = pi * page_size < limit + (qlive - 1)
-    if window:  # pages entirely below every row's window are dead
-        page_live = jnp.logical_and(
-            page_live, (pi + 1) * page_size > limit - window
+    def online(rows, j):
+        """One page's online-softmax update — the legacy kernel body
+        verbatim, on the tile's rows ``rows``."""
+        q = q_ref[0, 0, rows]
+        k = k_refs[j][0, 0]  # [page_size, D]
+        v = v_refs[j][0, 0]
+        s = jax.lax.dot_general(
+            q, k.astype(q.dtype) if kv_int8 else k,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [rows, page_size]
+        if kv_int8:
+            # dequant folds into the score row: k_slot scale is constant
+            # along the contracted D axis, so (q·k_int8)·ks == q·(k_int8·ks)
+            s = s * ks_refs[j][0, 0]  # [1, page_size] broadcasts over rows
+
+        pos = (slot0 + j) * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1
         )
-
-    @pl.when(page_live)
-    def _compute():
-        k = k_ref[0, 0]  # [page_size, D]
-        v = v_ref[0, 0]
-        m_prev = m_ref[:]
-        l_prev = l_ref[:]
-        acc_prev = acc_ref[:]
-
-        def online(q, m_p, l_p, acc_p):
-            """One page's online-softmax update — the legacy kernel body
-            verbatim, at whatever row count ``q`` carries."""
-            s = jax.lax.dot_general(
-                q, k.astype(q.dtype) if kv_int8 else k,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [rows, page_size]
-            if kv_int8:
-                # dequant folds into the score row: k_slot scale is
-                # constant along the contracted D axis, so
-                # (q·k_int8)·ks == q·(k_int8·ks)
-                s = s * ks_ref[0, 0]  # [1, page_size] broadcasts over rows
-
-            pos = pi * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1
+        # per-row causal limit: row r is query position (limit-1) + r//g
+        row_t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // g
+        visible = pos < limit + row_t
+        if window:  # sliding window: only the last `window` positions
+            visible = jnp.logical_and(
+                visible, pos > limit - 1 + row_t - window
             )
-            # per-row causal limit: row r is query position (limit-1) + r//g
-            row_t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // g
-            visible = pos < limit + row_t
-            if window:  # sliding window: only the last `window` positions
-                visible = jnp.logical_and(
-                    visible, pos > limit - 1 + row_t - window
-                )
-            s = jnp.where(visible, s, NEG_INF)
+        s = jnp.where(visible, s, NEG_INF)
 
-            m_n = jnp.maximum(m_p, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_n)
-            correction = jnp.exp(m_p - m_n)
+        m_prev = m_ref[rows]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        correction = jnp.exp(m_prev - m_new)
 
-            l_n = correction * l_p + jnp.sum(p, axis=-1, keepdims=True)
-            if kv_int8:
-                # fold v's per-slot scale into p (constant along the
-                # contracted slot axis per output channel):
-                # (p·vs)·v_int8 == p·(v_int8·vs)
-                pv = (p * vs_ref[0, 0]).astype(jnp.float32)
-                vv = v.astype(jnp.float32)
-            else:
-                pv = p.astype(v.dtype)
-                vv = v
-            acc_n = correction * acc_p + jax.lax.dot_general(
-                pv, vv,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            return m_n, l_n, acc_n
+        l_ref[rows] = correction * l_ref[rows] + jnp.sum(
+            p, axis=-1, keepdims=True
+        )
+        if kv_int8:
+            # fold v's per-slot scale into p (constant along the contracted
+            # slot axis per output channel): (p·vs)·v_int8 == p·(v_int8·vs)
+            pv = (p * vs_refs[j][0, 0]).astype(jnp.float32)
+            v = v.astype(jnp.float32)
+        else:
+            pv = p.astype(v.dtype)
+        acc_ref[rows] = correction * acc_ref[rows] + jax.lax.dot_general(
+            pv, v,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[rows] = m_new
 
-        q = q_ref[0, 0]  # [R*G, D]
-        dec = mode_ref[b] == 1
+    dec = mode_ref[b] == 1
 
-        def decode_path(_):
-            # rows 0..g-1 are the decode token's head group (row_t = 0,
-            # same mask) — run exactly the qt=1 program's [g]-row shapes.
-            # The tile's padding rows keep their init state: they are
-            # never read downstream, and skipping them keeps a decode
-            # row's per-page cost at the legacy kernel's, not the tile's.
-            m_d, l_d, acc_d = online(
-                q[:g], m_prev[:g], l_prev[:g], acc_prev[:g]
-            )
-            return (
-                jnp.concatenate([m_d, m_prev[g:]]),
-                jnp.concatenate([l_d, l_prev[g:]]),
-                jnp.concatenate([acc_d, acc_prev[g:]]),
-            )
+    # rows 0..g-1 of a decode row's tile are its token's head group
+    # (row_t = 0, same mask): exactly the qt=1 program's [g]-row shapes.
+    # The tile's other rows keep their init state: they are never read
+    # downstream, and skipping them keeps a decode row's per-page cost at
+    # the legacy kernel's, not the tile's.
+    for rows, mine in ((pl.ds(0, g), dec), (slice(None), jnp.logical_not(dec))):
+        @pl.when(jnp.logical_and(step_live, mine))
+        def _row(rows=rows):
+            for j in range(n):
+                online(rows, j)
 
-        def block_path(_):
-            return online(q, m_prev, l_prev, acc_prev)
-
-        m_n, l_n, acc_n = jax.lax.cond(dec, decode_path, block_path, None)
-        m_ref[:] = m_n
-        l_ref[:] = l_n
-        acc_ref[:] = acc_n
-
-    @pl.when(pi == num_pages - 1)
+    @pl.when(pi == pl.num_programs(2) - 1)
     def _finalize():
         l = l_ref[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -211,47 +291,66 @@ def _ragged_call(
     v_scales: jnp.ndarray | None,
     window: int = 0,
 ) -> jnp.ndarray:
-    """pallas_call plumbing — mirrors paged_attention._paged_call with the
-    per-row metadata as scalar-prefetch arrays so the two modules cannot
-    drift far."""
+    """pallas_call plumbing: grid (rows, kv heads, steps of n page
+    slots), the per-row metadata as scalar-prefetch arrays, and one block
+    spec a page slot of the step.
+
+    The table the kernel sees is the row's WALK, made here: entry i is
+    the page of slot ``first + i`` (``first`` the row's first live slot:
+    0, or under a window the page of position ``limit - window``),
+    clamped to the row's last live slot. So the grid has only as many
+    steps as a row can have live pages (``walk_pages``), an index map is
+    one scalar load, and every dead slot maps the block of the step
+    before: Pallas elides a block copy when consecutive steps map the
+    same index, so pages past a row's last live position cost no bytes,
+    and pages below its window are never visited at all."""
     Bv, K, rows, D = qg.shape
     page_size = k_pages.shape[2]
     max_pages = block_table.shape[1]
     kv_int8 = k_scales is not None
+    n, steps = _steps(page_size, max_pages, rows // g, window)
+
+    limits = limits.astype(jnp.int32)
+    q_lens = q_lens.astype(jnp.int32)
+    last = jnp.clip((limits + q_lens - 2) // page_size, 0, max_pages - 1)
+    first = jnp.zeros_like(last)
+    if window:
+        first = jnp.minimum(jnp.maximum((limits - window) // page_size, 0), last)
+    slots = jnp.minimum(
+        first[:, None] + jnp.arange(steps * n, dtype=jnp.int32)[None, :],
+        last[:, None],
+    )
+    walk = jnp.take_along_axis(block_table.astype(jnp.int32), slots, axis=1)
 
     kernel = functools.partial(
-        _ragged_kernel, page_size=page_size, scale=scale, kv_int8=kv_int8,
-        g=g, window=window,
+        _ragged_kernel, page_size=page_size, n=n, scale=scale,
+        kv_int8=kv_int8, g=g, window=window,
     )
-    if window:
-        # clamp dead leading grid steps to the FIRST in-window page:
-        # Pallas elides a block copy when consecutive steps map the same
-        # index, so pages entirely below every row's window are never
-        # DMA'd (see paged_attention._paged_call)
-        def _page_idx(b, kh, pi, bt, ln, ql, md):
-            first = jnp.maximum((ln[b] - window) // page_size, 0)
-            return (bt[b, jnp.maximum(pi, first)], kh, 0, 0)
-    else:
-        def _page_idx(b, kh, pi, bt, ln, ql, md):
-            return (bt[b, pi], kh, 0, 0)
 
-    page_spec = pl.BlockSpec((1, 1, page_size, D), _page_idx)
-    scale_spec = pl.BlockSpec((1, 1, 1, page_size), _page_idx)
+    def _page_idx(j):
+        return lambda b, kh, pi, wk, ln, ql, md: (wk[b, pi * n + j], kh, 0, 0)
+
     row_spec = pl.BlockSpec(
         (1, 1, rows, D),
-        lambda b, kh, pi, bt, ln, ql, md: (b, kh, 0, 0),
+        lambda b, kh, pi, wk, ln, ql, md: (b, kh, 0, 0),
     )
-    in_specs = [row_spec, page_spec, page_spec]
-    args = [qg, k_pages, v_pages]
+    page_specs = [
+        pl.BlockSpec((1, 1, page_size, D), _page_idx(j)) for j in range(n)
+    ]
+    in_specs = [row_spec] + page_specs + page_specs
+    args = [qg] + [k_pages] * n + [v_pages] * n
     if kv_int8:
-        in_specs += [scale_spec, scale_spec]
-        args += [k_scales, v_scales]
+        scale_specs = [
+            pl.BlockSpec((1, 1, 1, page_size), _page_idx(j)) for j in range(n)
+        ]
+        in_specs += scale_specs + scale_specs
+        args += [k_scales] * n + [v_scales] * n
 
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(Bv, K, max_pages),
+            grid=(Bv, K, steps),
             in_specs=in_specs,
             out_specs=row_spec,
             scratch_shapes=[
@@ -267,10 +366,7 @@ def _ragged_call(
         interpret=interpret,
         # the kernel's name in a device trace, pinned (see _paged_call)
         name="ragged_paged_attention",
-    )(
-        block_table.astype(jnp.int32), limits.astype(jnp.int32),
-        q_lens.astype(jnp.int32), modes.astype(jnp.int32), *args,
-    )
+    )(walk, limits, q_lens, modes.astype(jnp.int32), *args)
 
 
 @functools.partial(

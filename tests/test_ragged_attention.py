@@ -20,6 +20,7 @@ The claims under test (docs/ENGINE.md "Decode dispatch model"):
 
 from __future__ import annotations
 
+import math
 import os
 import threading
 
@@ -200,6 +201,172 @@ class TestRaggedKernel:
         _assert_rows(got, want, "tp2 shard_map diverged from local")
 
 
+    # one call per case: decode rows (against ``paged_attention``) and a
+    # chunk row of T live positions in an R-position tile (against
+    # ``paged_attention_block``), over a table of ``pps`` slots walked n
+    # slots a grid step (n = 8 at these page sizes)
+    MIXED = {
+        # 11 slots: the second step ends inside the table
+        "table_not_a_multiple_of_step": dict(
+            ps=8, pps=11, lengths=[85, 30], base=70, T=8, R=8),
+        # the window's low edge (131 - 40 -> slot 11) and every length
+        # fall inside a step of 8 slots, and rows start their walk on
+        # different slots
+        "length_and_window_edges_inside_a_step": dict(
+            ps=8, pps=24, lengths=[130, 77, 9], base=101, T=12, R=12,
+            window=40),
+        # a window wider than any context: the walk covers the table
+        "window_wider_than_context": dict(
+            ps=8, pps=10, lengths=[60, 5], base=20, T=8, R=8, window=64),
+        # q_len 0 rows (an idle slot; a chunk group past the chunk's end)
+        "dead_rows": dict(
+            ps=8, pps=12, lengths=[50, 17], base=40, T=8, R=8, dead=True),
+        "int8_scales": dict(
+            ps=8, pps=11, lengths=[85, 30], base=61, T=8, R=8, int8=True),
+        "int8_scales_windowed": dict(
+            ps=16, pps=9, lengths=[130, 40], base=97, T=8, R=8, int8=True,
+            window=48),
+        # the chunk's last piece: 5 live positions in a 16-position tile
+        "chunk_shorter_than_its_tile": dict(
+            ps=8, pps=12, lengths=[50, 17], base=70, T=5, R=16),
+        "chunk_shorter_than_its_tile_windowed": dict(
+            ps=16, pps=12, lengths=[150, 33], base=120, T=3, R=16,
+            window=64),
+        "sharded_wrapper": dict(
+            ps=8, pps=11, lengths=[85, 30], base=70, T=8, R=8, tp=2),
+        "sharded_wrapper_int8_windowed": dict(
+            ps=8, pps=24, lengths=[130, 77], base=101, T=12, R=12,
+            window=40, int8=True, tp=2),
+    }
+
+    @pytest.mark.parametrize("case", sorted(MIXED))
+    def test_mixed_rows_match_solo_kernels(self, case):
+        c = dict(window=0, int8=False, dead=False, tp=1)
+        c.update(self.MIXED[case])
+        ps, pps, T, R, win = c["ps"], c["pps"], c["T"], c["R"], c["window"]
+        H, K, D = 4, 2, 32
+        nd = len(c["lengths"])
+        kp, vp, bt = _pool(jax.random.PRNGKey(20), nd + 1, K, D, ps, pps)
+        scales = {}
+        if c["int8"]:
+            kp, ksc = _rowquant(kp)
+            vp, vsc = _rowquant(vp)
+            scales = dict(k_scales=ksc, v_scales=vsc)
+        qd = _rand(jax.random.PRNGKey(21), (nd, H, D))
+        qc = _rand(jax.random.PRNGKey(22), (1, T, H, D))
+        lengths = jnp.asarray(c["lengths"], jnp.int32)
+        base = jnp.asarray([c["base"]], jnp.int32)
+
+        want_dec = paged_attention(
+            qd, kp, vp, bt[:nd], lengths + 1, window=win, **scales
+        )
+        want_chunk = paged_attention_block(
+            qc, kp, vp, bt[nd:], base, window=win, **scales
+        )
+
+        qv = jnp.concatenate([
+            jnp.pad(qd[:, None], ((0, 0), (0, R - 1), (0, 0), (0, 0))),
+            jnp.pad(qc, ((0, 0), (0, R - T), (0, 0), (0, 0))),
+        ])
+        table = bt
+        limits = jnp.concatenate([lengths + 1, base + 1])
+        q_lens = jnp.asarray([1] * nd + [T], jnp.int32)
+        modes = jnp.asarray([1] * nd + [0], jnp.int32)
+        if c["dead"]:  # one dead decode row, one dead chunk group
+            qv = jnp.concatenate([qv, qv[:1], qv[-1:]])
+            table = jnp.concatenate([table, bt[:1], bt[nd:]])
+            limits = jnp.concatenate(
+                [limits, jnp.asarray([1, c["base"] + R + 1], jnp.int32)]
+            )
+            q_lens = jnp.concatenate([q_lens, jnp.zeros((2,), jnp.int32)])
+            modes = jnp.concatenate([modes, jnp.asarray([1, 0], jnp.int32)])
+        if c["tp"] > 1:
+            from fei_tpu.ops.pallas.ragged_paged_attention import (
+                ragged_paged_attention_sharded,
+            )
+            from fei_tpu.parallel.mesh import make_mesh
+
+            if len(jax.devices()) < c["tp"]:
+                pytest.skip(f"needs {c['tp']} devices")
+            mesh = make_mesh({"tp": c["tp"]}, devices=jax.devices()[:c["tp"]])
+            out = ragged_paged_attention_sharded(
+                qv, kp, vp, table, limits, q_lens, modes, mesh, window=win,
+                **scales,
+            )
+        else:
+            out = ragged_paged_attention(
+                qv, kp, vp, table, limits, q_lens, modes, window=win,
+                **scales,
+            )
+        _assert_rows(out[:nd, 0], want_dec, f"{case}: decode rows")
+        _assert_rows(out[nd, :T][None], want_chunk, f"{case}: chunk rows")
+        assert np.isfinite(np.asarray(out, np.float32)).all(), case
+
+    def test_grid_at_the_served_shapes(self):
+        """The grid pays for what a row can have live, not for the table:
+        at the benchmark cell's shapes (mistral-7b: 4 decode rows, a
+        256-token chunk, 8 kv heads, 128 slots of 64, window 4096) one
+        layer's call has at most 1,000 grid steps (36,864 before the
+        chunk was one tile and a step several pages), and ``grid_of``,
+        which the flight record's ``attn_steps`` is made from, says the
+        call's own number."""
+        from fei_tpu.ops.pallas.ragged_paged_attention import grid_of
+
+        B, C, H, K, D, ps, slots, win = 4, 256, 32, 8, 128, 64, 128, 4096
+        want = grid_of(B, C, K, H // K, D, ps, slots, win)
+        assert want[0] == B + 1, "the chunk is one query tile"
+        assert want[1] == K
+        assert math.prod(want) <= 1000, want
+        got = _traced_grid(B + 1, C, H, K, D, ps, slots, win)
+        assert got == want
+        # no window: every slot of the table may be live
+        assert _traced_grid(B + 1, C, H, K, D, ps, slots, 0) == grid_of(
+            B, C, K, H // K, D, ps, slots, 0
+        ) == (B + 1, K, slots // 8)
+
+    def test_query_tile_adapts(self):
+        """The tile is the chunk where its rows fit, else the largest
+        that does: by the same rule for every model, no error."""
+        from fei_tpu.ops.pallas.ragged_paged_attention import (
+            grid_of, query_tile,
+        )
+
+        assert query_tile(256, 4, 128) == 256  # the cell: 1024 rows
+        assert query_tile(1024, 8, 128) == 128  # would be 8192 rows
+        assert query_tile(1024, 1, 80) == 1024  # phi-2: g 1, head 80
+        assert query_tile(16, 2, 32) == 16
+        assert query_tile(512, 4, 256) == 128  # gemma's head: half the rows
+        assert grid_of(4, 1024, 8, 8, 128, 64, 128)[0] == 4 + 8
+
+
+def _traced_grid(Bv, R, H, K, D, ps, slots, window):
+    """The grid of the pallas_call that ``ragged_paged_attention`` makes
+    at these shapes, from its jaxpr: nothing runs."""
+    S = jax.ShapeDtypeStruct
+    i32 = S((Bv,), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: ragged_paged_attention(*a, window=window, interpret=True)
+    )(
+        S((Bv, R, H, D), jnp.bfloat16), S((Bv * slots, K, ps, D), jnp.bfloat16),
+        S((Bv * slots, K, ps, D), jnp.bfloat16), S((Bv, slots), jnp.int32),
+        i32, i32, i32,
+    )
+
+    def find(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return tuple(eqn.params["grid_mapping"].grid)
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    got = find(inner)
+                    if got:
+                        return got
+        return None
+
+    return find(jaxpr.jaxpr)
+
+
 # --- engine-level identity ------------------------------------------------
 
 LIVE = list(range(40, 72))  # 32 tokens: 2 chunks at prefill_chunk=16
@@ -338,6 +505,28 @@ class TestMergedDispatch:
             - (_counter("scheduler.multi_tokens") - c0["scheduler.multi_tokens"])
             + (_counter("scheduler.multi_steps") - c0["scheduler.multi_steps"])
         )
+
+    def test_attn_steps_in_flight_record(self, ragged_eng):
+        """A merged dispatch's record says how many grid steps one
+        layer's attention call took: the traced call's own grid."""
+        from fei_tpu.ops.pallas.ragged_paged_attention import query_tile
+
+        FLIGHT.reset()
+        _overlap(ragged_eng, GEN_LIVE, GEN_LONG)
+        merged = [
+            r for r in FLIGHT.records()
+            if r["name"] == "dispatch.step" and r["tags"].get("ragged")
+        ]
+        assert merged, "overlap never produced a merged dispatch"
+        cfg, sched = ragged_eng.cfg, ragged_eng.scheduler
+        C = sched.prefill_chunk
+        R = query_tile(C, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_)
+        want = math.prod(_traced_grid(
+            sched.B + -(-C // R), R, cfg.num_heads, cfg.num_kv_heads,
+            cfg.head_dim_, ragged_eng.page_size,
+            sched._pool.block_table.shape[1], cfg.sliding_window or 0,
+        ))
+        assert {r["tags"]["attn_steps"] for r in merged} == {want}
 
     def test_overlap_seeded_identity(self, legacy_refs, ragged_eng):
         live, long_, _ = _overlap(ragged_eng, SEED_LIVE, SEED_LONG)
