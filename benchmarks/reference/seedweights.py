@@ -26,13 +26,40 @@ _U = jnp.uint32
 _MEAN = 510.0
 _STD = math.sqrt(4 * (256 ** 2 - 1) / 12.0)
 
-# tensor ids: stable small integers, one per tensor name
+# tensor ids: stable small integers for the names the first families use
+# (every weight made from them so far hangs on these), and for any other
+# name an id worked out from the name itself, see ``tensor_id``
 TENSOR_IDS = {name: i + 1 for i, name in enumerate((
     "embed", "lm_head", "lm_head_b", "final_norm", "final_norm_b",
     "attn_norm", "attn_norm_b", "mlp_norm", "mlp_norm_b",
     "wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo",
     "w_gate", "w_up", "w_down", "b_gate", "b_up", "b_down",
 ))}
+_HASHED_FROM = 1 << 16  # ids from names start here, clear of the table's
+
+
+def tensor_id(name: str) -> int:
+    """The table's id, or for a name outside it 32-bit FNV-1a of the name
+    folded into [2**16, 2**32): a function of the name alone, so a family
+    brings its tensors without an entry here."""
+    if name in TENSOR_IDS:
+        return TENSOR_IDS[name]
+    h = 0x811C9DC5
+    for b in name.encode("utf-8"):
+        h = ((h ^ b) * 0x01000193) & 0xFFFFFFFF
+    return _HASHED_FROM + h % ((1 << 32) - _HASHED_FROM)
+
+
+def check_names(names) -> None:
+    """Two tensors of one family with one id would be one stream of
+    values: an error when the family is loaded."""
+    seen: dict[int, str] = {}
+    for name in names:
+        tid = tensor_id(name)
+        other = seen.setdefault(tid, name)
+        if other != name:
+            raise ValueError(f"tensor names {other!r} and {name!r} hash to one "
+                             f"id ({tid}): rename one of them")
 
 
 def seed32(seed: int) -> int:
@@ -58,7 +85,7 @@ def _mix(x):
 def _stream_key(seed, name: str, layer):
     """``seed`` is seed32(...) as a Python int or a traced uint32: traced,
     one compiled reference serves every seed."""
-    tid = TENSOR_IDS[name]
+    tid = tensor_id(name)
     k = _mix(jnp.asarray(seed).astype(_U) ^ _U((tid * 0x9E3779B9) & 0xFFFFFFFF))
     return _mix(k + jnp.asarray(layer).astype(_U) * _U(0x85EBCA6B) + _U(1))
 

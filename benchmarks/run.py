@@ -96,7 +96,11 @@ def cache_is_warm() -> bool:
 
 def check_sizes(cfg: dict, mc) -> None:
     """The file holds the configuration as it is run: its numbers are the
-    program's own for that model."""
+    program's own for that model. A family adds what is its own (a layer
+    pattern, heads of a second kind, experts held) with ``size_pairs(cfg,
+    mc)``: ``{key of the file: the program's value}``."""
+    from benchmarks.reference import decoder
+
     pairs = {
         "hidden_size": mc.hidden_size, "intermediate_size": mc.intermediate_size,
         "num_hidden_layers": mc.num_layers, "num_attention_heads": mc.num_heads,
@@ -107,6 +111,9 @@ def check_sizes(cfg: dict, mc) -> None:
         pairs["sliding_window"] = mc.sliding_window
     if "partial_rotary_factor" in cfg:
         pairs["partial_rotary_factor"] = mc.rotary_dim / mc.head_dim_
+    fam = decoder.family_of(cfg)
+    if hasattr(fam, "size_pairs"):
+        pairs.update(fam.size_pairs(cfg, mc))
     bad = {k: (cfg[k], v) for k, v in pairs.items() if cfg[k] != v}
     if bad:
         raise SystemExit(f"configuration file and program disagree: {bad}")
