@@ -149,6 +149,11 @@ class InferenceEngine:
                 "shared pages of the paged pool)"
             )
         self.prefix_cache = prefix_cache
+        if model_cfg.layer_kinds and not paged:
+            raise EngineError(
+                f"{model_cfg.name} (layers of several kinds) is served from "
+                "pages and state only: paged=True"
+            )
         self._pool = None  # lazy PagedKVCache page pool
         self._allocator = None
         # the scheduler object is created eagerly (it is cheap — no device
@@ -228,6 +233,8 @@ class InferenceEngine:
         if quantize == "int4" and has_axis(mesh, "tp"):
             int4_exclude = frozenset({"wo", "w_down"})
         tok = load_tokenizer(tokenizer)
+        if checkpoint_dir and cfg.layer_kinds:
+            raise ValueError(f"{cfg.name}: no checkpoint name map for its tree yet")
         if checkpoint_dir:
             from fei_tpu.engine.weights import load_checkpoint
 
@@ -239,7 +246,9 @@ class InferenceEngine:
             )
         else:
             # quantize-at-init keeps peak memory to one tensor's bf16 copy
-            params = init_params(
+            from fei_tpu.models import family
+
+            params = family(cfg).init_params(
                 cfg, jax.random.PRNGKey(seed), dtype=dtype, quantize=quantize,
                 int4_exclude=int4_exclude,
             )

@@ -9,10 +9,21 @@ sequences grow, indirected by a per-sequence block table — the design from
 the ragged-paged-attention literature (PAPERS.md #1), realized here with the
 Pallas decode kernel (fei_tpu.ops.pallas.paged_attention).
 
-Layouts (L=layers, P=pool pages, K=kv heads, ps=page size, D=head dim):
+Layouts (L=layers that keep pages, P=pool pages, K=kv heads, ps=page size,
+D=head dim):
   k_pages/v_pages: [L, P, K, ps, D]   (head-major pages — kernel layout)
   block_table:     [B, max_pages]     int32 page ids, row-ragged
   lengths:         [B]                int32 valid token count
+
+Two kinds of cache in one manager (models/sala.py: a model whose layers
+are of several kinds). Pages hold the keys and values of the attention
+layers only, and beside each page ``kc_pages`` [L, P, K, rows, D] float32:
+the compressed keys a block-sparse layer selects its pages from, indexed
+by page like the pages themselves, so a shared prefix page shares them
+too. The linear-attention layers keep no pages: ``state`` [Ll, B + 1, H, d,
+d] float32 is their recurrent state, one row a slot (indexed like
+``lengths``) and a last row for the admission in flight, which becomes the
+slot's when the slot is armed. Both are None for every other model.
 
 The allocator is deliberately host-side Python (free-list): allocation
 happens once per prefill and at page boundaries during decode, never inside
@@ -46,6 +57,8 @@ class PagedKVCache(NamedTuple):
     lengths: jnp.ndarray  # [B] int32
     k_scales: jnp.ndarray | None = None  # [L, P, K, 1, ps] fp32 (int8 mode)
     v_scales: jnp.ndarray | None = None
+    kc_pages: jnp.ndarray | None = None  # [L, P, K, rows, D] fp32 (sparse)
+    state: jnp.ndarray | None = None  # [Ll, B + 1, H, d, d] fp32 (linear)
 
     @property
     def page_size(self) -> int:
@@ -68,8 +81,23 @@ class PagedKVCache(NamedTuple):
     ) -> "PagedKVCache":
         if kv_quant not in (None, "int8"):
             raise EngineError(f"unsupported kv_quant mode: {kv_quant!r}")
-        L, K, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim_
+        L, K, D = cfg.kv_layers, cfg.num_kv_heads, cfg.head_dim_
         shape = (L, num_pages, K, page_size, D)
+        kc = state = None
+        if cfg.layer_kinds:
+            if kv_quant or page_size != cfg.sparse_block:
+                raise EngineError(
+                    f"{cfg.name}: a page is the model's block of "
+                    f"{cfg.sparse_block} keys, unquantized"
+                )
+            kc = jnp.zeros(
+                (L, num_pages, K, page_size // cfg.sparse_stride, D),
+                dtype=jnp.float32,
+            )
+            state = jnp.zeros(
+                (cfg.state_layers, batch + 1, cfg.lin_heads,
+                 cfg.lin_head_dim, cfg.lin_head_dim), dtype=jnp.float32,
+            )
         pool_dtype = jnp.int8 if kv_quant == "int8" else dtype
         # two distinct arrays: a shared buffer would be donated twice when
         # the pool threads through a donating dispatch
@@ -85,6 +113,8 @@ class PagedKVCache(NamedTuple):
             lengths=jnp.zeros((batch,), dtype=jnp.int32),
             k_scales=scales(),
             v_scales=scales(),
+            kc_pages=kc,
+            state=state,
         )
 
 
@@ -96,6 +126,27 @@ def replace_lengths(pool: "PagedKVCache", lengths) -> "PagedKVCache":
     below ``lengths``) and later writes land at the running length,
     overwriting any rolled-back garbage in place."""
     return pool._replace(lengths=jnp.asarray(lengths, dtype=jnp.int32))
+
+
+def adopt_state(pool: "PagedKVCache", slot) -> "PagedKVCache":
+    """The admission in flight becomes ``slot``'s: the recurrent state's
+    last row is copied to the slot's row (beside arming its table row)."""
+    st = pool.state
+    row = jax.lax.dynamic_slice_in_dim(st, st.shape[1] - 1, 1, axis=1)
+    return pool._replace(
+        state=jax.lax.dynamic_update_slice_in_dim(st, row, slot, axis=1)
+    )
+
+
+def load_state(pool: "PagedKVCache", snap: jnp.ndarray) -> "PagedKVCache":
+    """An admission resumes from a snapshot ([Ll, H, d, d]): it becomes
+    the state of the admission in flight (the last row)."""
+    st = pool.state
+    return pool._replace(
+        state=jax.lax.dynamic_update_slice_in_dim(
+            st, snap[:, None].astype(st.dtype), st.shape[1] - 1, axis=1
+        )
+    )
 
 
 def quant_kv_rows(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -261,13 +312,31 @@ class PrefixCache:
     suffix. Entries hold allocator references (one per page per entry) so
     shared pages outlive their first sequence; LRU eviction under pool
     pressure returns them.
+
+    A model with recurrent layers (``state_bytes`` > 0: one snapshot's
+    bytes) cannot resume from pages alone: a prefix hit needs the layers'
+    state at the boundary too. Admissions leave snapshots of it at page
+    boundaries (``register(..., states=)``), ``match`` returns the longest
+    boundary that has pages AND a snapshot (``state_at`` gives it), and
+    the tokens behind it are recomputed. Snapshots count against
+    ``state_budget`` bytes and go with their entries. Of snapshots, one
+    that exactly one longer snapshot grew out of is dropped first: it is
+    an inner point of one conversation's chain, which that conversation's
+    next turn no longer needs, while one that several grew out of is a
+    prefix sessions share, and one that none did is a conversation's
+    newest; among equals the least recently matched goes.
     """
 
-    def __init__(self, alloc: PageAllocator, max_entries: int = 512):
+    def __init__(self, alloc: PageAllocator, max_entries: int = 512,
+                 state_bytes: int = 0, state_budget: int = 0):
         self.alloc = alloc
         self.max_entries = max_entries
         self._entries: dict[bytes, tuple[tuple[int, ...], int]] = {}
         self._clock = 0
+        self.state_bytes = state_bytes
+        self.state_budget = state_budget
+        # key -> [snapshot, clock, longer snapshots grown out of this one]
+        self._states: dict[bytes, list] = {}
 
     @staticmethod
     def _boundary_keys(prompt_ids, n_pages: int, page_size: int) -> list[bytes]:
@@ -287,43 +356,108 @@ class PrefixCache:
             keys.append(prev)
         return keys
 
+    def _proper_keys(self, prompt_ids) -> list[bytes]:
+        """Keys of the page boundaries STRICTLY inside the prompt."""
+        ps = self.alloc.page_size
+        return self._boundary_keys(prompt_ids, (len(prompt_ids) - 1) // ps, ps)
+
     def match(self, prompt_ids) -> list[int]:
         """Longest cached page-aligned prefix STRICTLY shorter than the
-        prompt (at least one suffix token must remain to produce logits).
-        Returns its pages ([] on miss) and touches the entry's LRU clock."""
-        ps = self.alloc.page_size
-        max_m = (len(prompt_ids) - 1) // ps
-        keys = self._boundary_keys(prompt_ids, max_m, ps)
-        for m in range(max_m, 0, -1):
+        prompt (at least one suffix token must remain to produce logits);
+        with recurrent layers, the longest that also has a snapshot.
+        Returns its pages ([] on miss). A hit is a use of every boundary
+        of the prefix, not of the longest alone: all take the clock's new
+        value, so what many conversations start with stays newer than any
+        one conversation's tail."""
+        keys = self._proper_keys(prompt_ids)
+        for m in range(len(keys), 0, -1):
             hit = self._entries.get(keys[m - 1])
-            if hit is not None:
-                self._clock += 1
-                self._entries[keys[m - 1]] = (hit[0], self._clock)
-                METRICS.incr("prefix.hits")
-                return list(hit[0])
+            if hit is None or (self.state_bytes and keys[m - 1] not in self._states):
+                continue
+            self._clock += 1
+            for key in keys[:m]:
+                if key in self._entries:
+                    self._entries[key] = (self._entries[key][0], self._clock)
+                if key in self._states:
+                    self._states[key][1] = self._clock
+            METRICS.incr("prefix.hits")
+            return list(hit[0])
         METRICS.incr("prefix.misses")
         return []
 
-    def register(self, prompt_ids, pages: list[int]) -> None:
-        """Register every full-page boundary of a freshly admitted prompt."""
+    def pages_matched(self, prompt_ids) -> int:
+        """Pages of the longest cached prefix, snapshot or not (no LRU
+        touch): past a shorter ``match`` it is where this prompt leaves
+        what other requests share, a boundary worth a snapshot."""
+        keys = self._proper_keys(prompt_ids)
+        for m in range(len(keys), 0, -1):
+            if keys[m - 1] in self._entries:
+                return m
+        return 0
+
+    def state_at(self, prompt_ids, n_pages: int):
+        """The snapshot at the ``n_pages`` boundary of ``prompt_ids``
+        (what ``match`` just returned pages for)."""
+        ps = self.alloc.page_size
+        return self._states[self._boundary_keys(prompt_ids, n_pages, ps)[-1]][0]
+
+    def register(self, prompt_ids, pages: list[int], states: dict | None = None,
+                 grown_from: int = 0) -> None:
+        """Register every full-page boundary of a freshly admitted prompt.
+        ``states``: {pages at a boundary: the recurrent layers' snapshot
+        there}; ``grown_from``: pages of the snapshot this admission
+        resumed from (0: none)."""
         ps = self.alloc.page_size
         full = len(prompt_ids) // ps
-        for m, key in enumerate(self._boundary_keys(prompt_ids, full, ps), 1):
+        keys = self._boundary_keys(prompt_ids, full, ps)
+        self._clock += 1  # one use: every new boundary of this prompt
+        for m, key in enumerate(keys, 1):
             if key in self._entries:
                 continue
             entry_pages = tuple(pages[:m])
             self.alloc.take_ref(list(entry_pages))
-            self._clock += 1
             self._entries[key] = (entry_pages, self._clock)
+        added = False
+        for m, snap in sorted((states or {}).items()):
+            if 0 < m <= full and keys[m - 1] not in self._states:
+                self._states[keys[m - 1]] = [snap, self._clock, 0]
+                METRICS.incr("state.snapshots")
+                added = True
+        if added and 0 < grown_from <= full and keys[grown_from - 1] in self._states:
+            self._states[keys[grown_from - 1]][2] += 1
         while len(self._entries) > self.max_entries:
             self._evict_one()
+        while self.state_bytes and (
+            len(self._states) * self.state_bytes > self.state_budget
+            and self._drop_state()
+        ):
+            pass
         METRICS.gauge("prefix.entries", len(self._entries))
+        METRICS.gauge("state.snapshot_bytes", len(self._states) * self.state_bytes)
+
+    def _drop_state(self) -> bool:
+        if not self._states:
+            return False
+        key = min(self._states,
+                  key=lambda k: (self._states[k][2] != 1, self._states[k][1],
+                                 len(self._entries[k][0])))
+        del self._states[key]
+        METRICS.incr("state.snapshot_evictions")
+        return True
 
     def _evict_one(self) -> bool:
         if not self._entries:
             return False
-        key = min(self._entries, key=lambda k: self._entries[k][1])
+        # least recently used; of one use, the longest boundary first: it
+        # alone holds the last reference to its last page, so each eviction
+        # frees a page and a conversation goes tail first
+        key = min(self._entries,
+                  key=lambda k: (self._entries[k][1], -len(self._entries[k][0])))
         pages, _ = self._entries.pop(key)
+        if self._states.pop(key, None) is not None:
+            METRICS.gauge(
+                "state.snapshot_bytes", len(self._states) * self.state_bytes
+            )
         self.alloc.drop_ref(list(pages))
         METRICS.incr("prefix.evictions")
         METRICS.gauge("prefix.entries", len(self._entries))

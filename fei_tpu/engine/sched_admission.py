@@ -230,6 +230,7 @@ class AdmissionMixin:
                 # byte-identical continuation
                 if (
                     prefix or n_tok > self.prefill_chunk or seq.generated
+                    or self._hybrid  # pages and state only: no dense prefill
                 ) and not sp_long:
                     if self.paged_native_prefill:
                         self._start_chunked_paged(seq, slot, prefix)
@@ -491,7 +492,38 @@ class AdmissionMixin:
             "row": self._slot_row(slot),
             "pos": m * self.engine.page_size, "prefix": m,
         }
+        if self._hybrid:
+            self._resume_state(self._admitting, seq, m)
         self._admit_chunk()
+
+    def _resume_state(self, st: dict, seq: _Seq, m: int) -> None:
+        """A model with recurrent layers: the admission starts from the
+        snapshot at its ``m`` prefix pages (from nothing at 0), and leaves
+        snapshots at the page boundaries worth one: where the prompt
+        leaves the pages other requests share, if no snapshot is there
+        yet, and the prompt's last page boundary, from which the same
+        conversation's next turn goes on."""
+        ps = self.engine.page_size
+        ids = self._prefill_ids(seq)
+        st["snap_at"], st["snaps"] = [], {}
+        if self._prefix is None:
+            return
+        if m:
+            self._move_state("load_state", self._prefix.state_at(ids, m))
+            METRICS.incr("state.snapshot_hits")
+            METRICS.incr("state.resumed_tokens", m * ps)
+        for pages in (self._prefix.pages_matched(ids),
+                      len(seq.prompt_ids) // ps):
+            if pages > m and pages * ps not in st["snap_at"]:
+                st["snap_at"].append(pages * ps)
+
+    def _snap_offset(self, st: dict, lo: int, C: int) -> tuple[int, int]:
+        """(where in the chunk ``[lo, lo + C)`` the recurrent state is
+        snapshot, the boundary's pages); (0, 0): nowhere."""
+        for at in st.get("snap_at", ()):
+            if lo < at <= lo + C:
+                return at - lo, at // self.engine.page_size
+        return 0, 0
 
 
     def _admit_chunk(self) -> None:
@@ -544,6 +576,8 @@ class AdmissionMixin:
                             eng.params, self._pool, jnp.asarray(rt),
                             jnp.asarray(st["row"]), jnp.int32(st["slot"]),
                             jnp.asarray(lo, dtype=jnp.int32),
+                            # a recurrent state must not take in the padding
+                            *((jnp.int32(hi - lo),) if self._hybrid else ()),
                         )
                     # no host sync: the replayed pool stays on device
                     t_issue = time.perf_counter()
@@ -561,7 +595,7 @@ class AdmissionMixin:
                 self._admitting = None
                 self._complete_admission_paged(
                     seq, st["slot"], None, st["row"],
-                    prefix_pages=st.get("prefix", 0),
+                    prefix_pages=st.get("prefix", 0), snaps=st.get("snaps"),
                 )
                 return
             # prompt phase of a resume: walk the SAME chunk programs the
@@ -627,6 +661,10 @@ class AdmissionMixin:
         no decode scan to merge with)."""
         eng = self.engine
         C = toks.shape[1]
+        extra, snap_pages = (), 0
+        if self._hybrid:
+            off, snap_pages = self._snap_offset(st, lo, C)
+            extra = (jnp.int32(off),)
         t0 = time.perf_counter()
         with METRICS.span("prefill_chunk", jax_trace=True):
             out = self._device_call(
@@ -634,9 +672,17 @@ class AdmissionMixin:
                 eng.params, self._pool, jnp.asarray(toks),
                 jnp.asarray(st["row"][None]),
                 jnp.asarray([lo], dtype=jnp.int32),
-                jnp.int32(n - 1 - lo),
+                # the index of the prompt's last token in the chunk; with a
+                # recurrent state also how many of the chunk's tokens are
+                # real, which on a resume's prompt walk ends at ``hi``
+                jnp.int32((hi if self._hybrid else n) - 1 - lo), *extra,
             )
             t_issue = time.perf_counter()
+            if self._hybrid:
+                *out, snap = out
+                out = out if final else out[0]
+                if snap_pages:
+                    st["snaps"][snap_pages] = snap
             if final:
                 last_logits, self._pool = out
                 last_logits.block_until_ready()
@@ -656,7 +702,7 @@ class AdmissionMixin:
         self._admitting = None
         self._complete_admission_paged(
             seq, st["slot"], last_logits, st["row"],
-            prefix_pages=st.get("prefix", 0),
+            prefix_pages=st.get("prefix", 0), snaps=st.get("snaps"),
         )
 
     def _flush_pending_chunk(self) -> None:
@@ -691,12 +737,14 @@ class AdmissionMixin:
         first token from the chunk's LM-head logits, arm the slot)."""
         st = pc["st"]
         st["pos"] = pc["hi"]
+        if pc.get("snap_pages"):
+            st["snaps"][pc["snap_pages"]] = pc.pop("snap")
         if not pc["final"]:
             return
         self._admitting = None
         self._complete_admission_paged(
             st["seq"], st["slot"], chunk_logits, st["row"],
-            prefix_pages=st.get("prefix", 0),
+            prefix_pages=st.get("prefix", 0), snaps=st.get("snaps"),
         )
 
     def _paged_chunk_fn(self, C: int, final: bool):
@@ -714,24 +762,42 @@ class AdmissionMixin:
         if key not in self._pchunk_jit:
             cfg = self.engine.cfg
             mesh = self.engine.mesh
-            from fei_tpu.models.llama import _logits, forward_paged_block
+            from fei_tpu.models import family
+            from fei_tpu.models.llama import forward_paged_block
 
-            def chunk(params, pool, toks, row, pos, last_idx):
-                view = pool._replace(block_table=row, lengths=pos)
-                hidden, view = forward_paged_block(
-                    params, cfg, toks, view, kernel_mesh=mesh, lm_head=False
-                )
-                # hand the updated pages back under the LIVE table/lengths:
-                # decode must keep seeing the zeroed row until completion
-                out_pool = view._replace(
-                    block_table=pool.block_table, lengths=pool.lengths
-                )
+            fam = family(cfg)
+            _logits = fam._logits
+
+            def chunk(params, pool, toks, row, pos, last_idx, *snap_at):
+                if snap_at:
+                    # layers of several kinds: the family's own chunk step
+                    # (pages, compressed keys, the state's last row), and
+                    # the snapshot it takes is one more result
+                    hidden, out_pool, snap = fam.forward_chunk(
+                        params, cfg, toks, pool, row, pos, last_idx,
+                        snap_at[0], kernel_mesh=mesh,
+                    )
+                    snap = (snap,)
+                else:
+                    view = pool._replace(block_table=row, lengths=pos)
+                    hidden, view = forward_paged_block(
+                        params, cfg, toks, view, kernel_mesh=mesh,
+                        lm_head=False,
+                    )
+                    # hand the updated pages back under the LIVE table/
+                    # lengths: decode must keep seeing the zeroed row
+                    # until completion
+                    out_pool = view._replace(
+                        block_table=pool.block_table, lengths=pool.lengths
+                    )
+                    snap = ()
                 if not final:
-                    return out_pool
+                    return (out_pool, *snap) if snap else out_pool
                 h_last = jax.lax.dynamic_slice_in_dim(
                     hidden, last_idx, 1, axis=1
                 )  # [1, 1, H] — already final-normed (lm_head=False contract)
-                return _logits(h_last, params, cfg, kernel_mesh=mesh)[:, 0], out_pool
+                return (_logits(h_last, params, cfg, kernel_mesh=mesh)[:, 0],
+                        out_pool, *snap)
 
             self._pchunk_jit[key] = self.engine._compiles.wrap(
                 "sched.paged_chunk", key, jax.jit(chunk, donate_argnums=(1,))
@@ -752,9 +818,12 @@ class AdmissionMixin:
         if R not in self._replay_jit:
             cfg = self.engine.cfg
             mesh = self.engine.mesh
-            from fei_tpu.models.llama import forward_paged
+            from fei_tpu.engine.paged_cache import adopt_state, load_state
+            from fei_tpu.models import family
 
-            def replay(params, pool, toks, row, slot, start):
+            forward_paged = family(cfg).forward_paged
+
+            def replay(params, pool, toks, row, slot, start, *n_real):
                 bt0, ln0 = pool.block_table, pool.lengths
                 bt = jax.lax.dynamic_update_slice(
                     jnp.zeros_like(bt0), row[None], (slot, 0)
@@ -763,19 +832,34 @@ class AdmissionMixin:
                     jnp.zeros_like(ln0), start[None], (slot,)
                 )
                 view = pool._replace(block_table=bt, lengths=ln)
+                if pool.state is not None:
+                    # the half-built state is the admission's (the last
+                    # row): the replay runs on it in the slot's row, and
+                    # every slot's own row comes back as it was
+                    view = adopt_state(view, slot)
                 B = bt0.shape[0]
 
-                def body(carry, tok):
+                def body(carry, tok_i):
+                    tok, i = tok_i
                     tokens = jax.lax.dynamic_update_slice(
                         jnp.zeros((B, 1), dtype=jnp.int32),
                         tok[None, None], (slot, 0),
                     )
-                    _, carry = forward_paged(
+                    _, new = forward_paged(
                         params, cfg, tokens, carry, kernel_mesh=mesh
                     )
-                    return carry, None
+                    if n_real:  # padding behind the suffix leaves the state
+                        new = new._replace(state=jnp.where(
+                            i < n_real[0], new.state, carry.state))
+                    return new, None
 
-                view, _ = jax.lax.scan(body, view, toks)
+                view, _ = jax.lax.scan(
+                    body, view, (toks, jnp.arange(toks.shape[0])))
+                if pool.state is not None:
+                    built = jax.lax.dynamic_index_in_dim(
+                        view.state, slot, axis=1, keepdims=False
+                    )
+                    view = load_state(view._replace(state=pool.state), built)
                 return view._replace(block_table=bt0, lengths=ln0)
 
             self._replay_jit[R] = self.engine._compiles.wrap(
@@ -806,12 +890,14 @@ class AdmissionMixin:
 
     def _complete_admission_paged(
         self, seq: _Seq, slot: int, last_logits, row: np.ndarray,
-        prefix_pages: int = 0,
+        prefix_pages: int = 0, snaps: dict | None = None,
     ) -> None:
         """Admission tail for the paged-native path: sample the first
         token (or re-install the resume key), arm the slot's table row +
         length, register the prefix. ``row`` is the block-table row the
-        chunks wrote through (pages cannot change mid-admission)."""
+        chunks wrote through (pages cannot change mid-admission).
+        ``snaps``: the recurrent state's snapshots the chunks took, by
+        boundary pages (a model with such layers)."""
         eng = self.engine
         alloc = eng._allocator
         ids = self._prefill_ids(seq)
@@ -830,13 +916,20 @@ class AdmissionMixin:
             self._pool, jnp.asarray(row), jnp.int32(slot),
             jnp.asarray(n, dtype=jnp.int32),
         )
+        if self._hybrid:
+            # the finished admission's state (the state's last row) becomes
+            # the slot's, beside its armed table row
+            self._move_state("adopt_state", jnp.int32(slot))
         self._keys = self._keys.at[slot].set(rng)
         seq.prefilling = False
         seq.row = np.array(row)
         if seq.trace is not None:
             seq.trace.event("prefill")
         if self._prefix is not None:
-            self._prefix.register(ids, pages[: alloc.pages_needed(n)])
+            self._prefix.register(
+                ids, pages[: alloc.pages_needed(n)], states=snaps,
+                grown_from=prefix_pages,
+            )
         if resume:
             self._resume_delivered(seq, n, prefix_pages)
             return
@@ -857,6 +950,18 @@ class AdmissionMixin:
             first_key = np.asarray(rng)
         self._deliver(seq, tok0, key=first_key)
 
+
+    def _move_state(self, which: str, arg) -> None:
+        """``paged_cache.adopt_state`` / ``load_state`` on the owned pool,
+        jitted once each and donating it like every other pool program."""
+        if which not in self._state_jit:
+            from fei_tpu.engine import paged_cache
+
+            self._state_jit[which] = self.engine._compiles.wrap(
+                "sched." + which, 0,
+                jax.jit(getattr(paged_cache, which), donate_argnums=(0,)),
+            )
+        self._pool = self._state_jit[which](self._pool, arg)
 
     def _resume_delivered(self, seq: _Seq, n: int, prefix_pages: int,
                           recomputed: int | None = None) -> None:

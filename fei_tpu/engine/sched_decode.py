@@ -19,7 +19,7 @@ import numpy as np
 
 from fei_tpu.engine.faults import FAULTS
 from fei_tpu.engine.sampling import sample_logits_dynamic
-from fei_tpu.models.llama import forward_paged
+from fei_tpu.models import family
 from fei_tpu.obs.flight import FLIGHT
 from fei_tpu.ops.pallas.ragged_paged_attention import grid_of as ragged_grid_of
 from fei_tpu.parallel.mesh import mesh_tag
@@ -323,6 +323,10 @@ class DecodeMixin:
         for _, s in active:
             if s.mask_fn is not None:
                 return False
+            if self._hybrid and s.grammar is not None and s.gstate < 0:
+                # the fused free phase rolls a slot back mid-scan, which a
+                # recurrent state cannot follow: such slots step one token
+                return False
         headroom = max(s.budget - len(s.generated) for _, s in active)
         n = 1
         while n < headroom and n < cap:
@@ -493,11 +497,18 @@ class DecodeMixin:
                 jnp.asarray(pc["toks"]),
                 jnp.asarray(pc["st"]["row"][None]),
                 jnp.asarray([pc["lo"]], dtype=jnp.int32),
-                jnp.int32(pc["ntok"] - 1 - pc["lo"]),
+                jnp.int32((pc["hi"] if self._hybrid else pc["ntok"])
+                          - 1 - pc["lo"]),
             ] + args[2:]
+            if self._hybrid:
+                off, pc["snap_pages"] = self._snap_offset(
+                    pc["st"], pc["lo"], pc["toks"].shape[1])
+                kw["csnap"] = jnp.int32(off)
             with METRICS.span("decode_step", jax_trace=True):
                 res = self._device_call("ragged merged dispatch", step,
                                         *rargs, **kw)
+                if self._hybrid:
+                    *res, pc["snap"] = res
                 if pc["final"]:
                     (chunk_logits, nxt, self._step_keys, self._pool,
                      self._keys) = res
@@ -524,15 +535,25 @@ class DecodeMixin:
             extra = {
                 "ragged": True, "chunk_tokens": pc["hi"] - pc["lo"],
                 "chunk_rid": pc["st"]["seq"].rid, "chunk_lo": pc["lo"],
-                "attn_steps": math.prod(ragged_grid_of(
+            }
+            if not self._hybrid:  # its merged step calls no ragged kernel
+                extra["attn_steps"] = math.prod(ragged_grid_of(
                     self.B, pc["toks"].shape[1], cfg.num_kv_heads // tp,
                     cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_,
                     eng.page_size, self._pool.block_table.shape[1],
                     cfg.sliding_window or 0,
-                )),
-            }
+                ))
             METRICS.incr("engine.ragged_dispatches")
             METRICS.gauge("engine.kernel_loop_depth", n * eng.cfg.num_layers)
+        if self._hybrid:
+            # pages one sparse layer reads a kv head at the first step,
+            # and pages its contexts hold, over the active slots: a query
+            # with topk blocks or fewer behind it reads them all
+            ps, topk = eng.page_size, eng.cfg.sparse_topk
+            extra["ctx_pages"] = sum(-(-c // ps) for c in ctx)
+            extra["sel_pages"] = sum(min(topk, -(-c // ps)) for c in ctx)
+            METRICS.incr("sparse.pages_selected", extra["sel_pages"] * n)
+            METRICS.incr("sparse.pages_in_context", extra["ctx_pages"] * n)
         FLIGHT.dispatch(
             "dispatch.step", t0, t_issue, t1,
             rids=[s.rid for _, s in active], mesh=mesh_tag(eng.mesh),
@@ -613,6 +634,7 @@ class DecodeMixin:
         if key not in self._step_jit:
             cfg = self.engine.cfg
             mesh = self.engine.mesh  # tp mesh: kernel runs via shard_map
+            forward_paged = family(cfg).forward_paged
 
             def multi(params, pool, tokens, keys, temps, topks, topps,
                       minps, gstates=None, gremain=None, table=None,
@@ -675,16 +697,27 @@ class DecodeMixin:
         if key not in self._step_jit:
             cfg = self.engine.cfg
             mesh = self.engine.mesh
-            from fei_tpu.models.llama import _logits, forward_paged_merged
+            fam = family(cfg)
+            _logits, forward_paged = fam._logits, fam.forward_paged
+            forward_paged_merged = fam.forward_paged_merged
+            hybrid = self._hybrid
 
             def ragged(params, pool, ctoks, crow, cpos, clast, tokens,
                        keys, temps, topks, topps, minps, gstates=None,
-                       gremain=None, table=None, mind=None):
+                       gremain=None, table=None, mind=None, csnap=None):
                 sampler = _make_sampler(grammared, False)
-                chunk_hidden, logits, pool = forward_paged_merged(
-                    params, cfg, ctoks, crow, cpos, tokens, pool,
-                    kernel_mesh=mesh,
-                )
+                if hybrid:
+                    # the chunk's real tokens and where it snapshots the
+                    # recurrent state go in; the snapshot comes out last
+                    chunk_hidden, logits, pool, snap = forward_paged_merged(
+                        params, cfg, ctoks, crow, cpos, tokens, pool,
+                        clast, csnap, kernel_mesh=mesh,
+                    )
+                else:
+                    chunk_hidden, logits, pool = forward_paged_merged(
+                        params, cfg, ctoks, crow, cpos, tokens, pool,
+                        kernel_mesh=mesh,
+                    )
                 logits = logits[:, -1, :]
                 nxt, new_keys, gstates, gremain = sampler(
                     logits, keys, temps, topks, topps, minps,
@@ -732,6 +765,8 @@ class DecodeMixin:
                 else:
                     keys_out = new_keys
                 out = (jnp.swapaxes(toks, 0, 1), step_keys, pool, keys_out)
+                if hybrid:
+                    out = out + (snap,)
                 if not final:
                     return out
                 h_last = jax.lax.dynamic_slice_in_dim(

@@ -362,6 +362,29 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         # the loop thread between dispatches — the donated pool is
         # single-owner state and must never race a dispatch
         self._ctl: deque = deque()
+        # a model whose layers are of several kinds (models/sala.py): its
+        # linear layers' state rides beside the pages (PagedKVCache.state)
+        # through admission, decode, preemption and the prefix cache. What
+        # moves pages without that state refuses the model here, at build.
+        self._hybrid = bool(engine.cfg.layer_kinds)
+        if self._hybrid:
+            self._refuse_for_hybrid()
+        self._state_jit: dict = {}  # adopt_state / load_state, jitted
+
+    def _refuse_for_hybrid(self) -> None:
+        eng = self.engine
+        why = None
+        if self._kv_tier is not None:
+            why = "the KV tier (FEI_TPU_KV_TIER) spills and streams pages without the linear layers' state"
+        elif not self.paged_native_prefill:
+            why = "FEI_TPU_PAGED_PREFILL=0 stages a dense cache, and only pages and state are served"
+        elif self.speculate:
+            why = "FEI_TPU_SPECULATE=1 rolls a slot's length back, which a recurrent state cannot follow"
+        elif self.prefill_chunk % eng.page_size:
+            why = (f"an admission chunk ({self.prefill_chunk}) must be whole "
+                   f"pages of {eng.page_size}")
+        if why:
+            raise EngineError(f"{eng.cfg.name} (layers of several kinds): {why}")
 
     # -- public API ---------------------------------------------------------
 
@@ -856,12 +879,20 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
             finally:
                 done.set()
 
+    def _refuse_migration(self) -> None:
+        if self._hybrid:
+            raise EngineError(
+                f"{self.engine.cfg.name}: migration moves pages without "
+                "the linear layers' state"
+            )
+
     def export_prefix(self, prompt_ids) -> bytes | None:
         """Serialize the longest page-aligned cached prefix of
         ``prompt_ids`` as a portable migration blob (kv/migrate.py), or
         None when nothing is cached. Safe from any thread."""
         from fei_tpu.kv.migrate import export_blob
 
+        self._refuse_migration()
         ids = [int(t) for t in prompt_ids]
         return self.run_ctl(lambda: export_blob(self, ids))
 
@@ -872,6 +903,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         thread."""
         from fei_tpu.kv.migrate import import_blob
 
+        self._refuse_migration()
         return self.run_ctl(lambda: import_blob(self, blob))
 
     def content_prefix_status(self, prompt_ids, cap: int = 8) -> dict:
@@ -1810,7 +1842,18 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
                 if self.engine.prefix_cache and self._prefix is None:
                     from fei_tpu.engine.paged_cache import PrefixCache
 
-                    self._prefix = PrefixCache(self.engine._allocator)
+                    # snapshots of the recurrent state: two a slot (a
+                    # live conversation's newest and what it grew from)
+                    st = self._pool.state
+                    one = 0 if st is None else st[:, 0].size * st.dtype.itemsize
+                    # an entry a page boundary: as many as the pool has pages
+                    # (a context of tens of thousands of tokens registers
+                    # hundreds of boundaries)
+                    self._prefix = PrefixCache(
+                        self.engine._allocator,
+                        max_entries=max(512, self.engine._allocator.num_pages),
+                        state_bytes=one, state_budget=2 * self.B * one,
+                    )
 
     @staticmethod
     def _device_call(what: str, fn, *args, **kw):

@@ -1,10 +1,21 @@
 """Model configurations for the fei_tpu engine.
 
-Covers the model families named in BASELINE.json configs: Llama-3 (8B/70B),
-CodeLlama-34B, Mixtral-8x7B MoE — plus tiny presets for hermetic CPU tests.
-All are decoder-only transformers with RMSNorm, RoPE, SwiGLU MLPs, and
-grouped-query attention; Mixtral swaps the dense MLP for a top-2 router over
-8 experts.
+One ``ModelConfig`` describes every family the engine runs; each has a
+tiny preset for hermetic CPU tests beside its published sizes:
+
+- Llama-3, CodeLlama (pre-norm RMSNorm, RoPE, SwiGLU, grouped-query
+  attention), Mixtral and ``moe-2b`` (a top-2 router over 8 experts),
+  Qwen2 (qkv biases), Mistral (a sliding window), Gemma (norm offset,
+  GeGLU, scaled embedding, decoupled head size), Phi (one shared
+  LayerNorm feeding attention and MLP in parallel, partial rotary, biased
+  fc1/fc2): ``models/llama.py``, one block scanned over one stacked tree;
+- MiniCPM-SALA (``minicpm-sala``, ``tiny-sala``): layers of two kinds in
+  one model, block-sparse attention over pages and linear attention with a
+  per-sequence state, q/k norms, output gates, muP scalings:
+  ``models/sala.py``, a stack of weights a kind.
+
+``models.family(cfg)`` gives the module whose step functions serve a
+configuration.
 """
 
 from __future__ import annotations
@@ -51,6 +62,35 @@ class ModelConfig:
     mlp_gated: bool = True
     mlp_bias: bool = False
     lm_head_bias: bool = False
+    # Hybrid families (MiniCPM-SALA): ``layer_kinds`` names each layer's
+    # mixer, in the model's order ("minicpm4": block-sparse softmax
+    # attention over pages; "lightning-attn": linear attention with a
+    # per-head decay, whose state is a fixed [heads, d, d] per sequence).
+    # Empty = every layer is the one softmax-attention block above.
+    # models/sala.py is the family's forward; num_heads / num_kv_heads /
+    # head_dim describe its attention layers, lin_* its linear ones.
+    layer_kinds: tuple = ()
+    lin_heads: int = 0
+    lin_head_dim: int = 0
+    qk_norm: bool = False  # RMS norm over each head of q and k, learned gain
+    attn_rope: bool = True  # False: the attention layers do not rotate
+    attn_gate: bool = False  # mixer output times sigmoid(W_g x)
+    # muP: embedding x scale_emb, each residual branch x scale_depth /
+    # sqrt(num_layers), last hidden / (hidden_size / dim_model_base)
+    scale_emb: float = 1.0
+    scale_depth: float = 0.0
+    dim_model_base: int = 0
+    # block-sparse attention: keys in blocks of ``sparse_block`` (the
+    # engine's page), compressed keys = means over ``sparse_kernel`` keys
+    # every ``sparse_stride``, the ``sparse_topk`` best blocks a (query,
+    # kv head), the first ``sparse_init_blocks`` and those of the last
+    # ``sparse_window`` positions always among them
+    sparse_block: int = 0
+    sparse_kernel: int = 0
+    sparse_stride: int = 0
+    sparse_topk: int = 0
+    sparse_init_blocks: int = 0
+    sparse_window: int = 0
     # tokenizer/bos/eos defaults (overridden by a real tokenizer when loaded)
     bos_token_id: int = 1
     eos_token_id: int = 2
@@ -67,6 +107,18 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that keep keys and values in pages."""
+        if not self.layer_kinds:
+            return self.num_layers
+        return sum(k != "lightning-attn" for k in self.layer_kinds)
+
+    @property
+    def state_layers(self) -> int:
+        """Layers whose cache is a fixed-size recurrent state a sequence."""
+        return sum(k == "lightning-attn" for k in self.layer_kinds)
 
     def num_params(self) -> int:
         """Approximate parameter count (for memory planning)."""
@@ -228,6 +280,40 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         head_dim=256, rope_theta=10000.0, max_seq_len=8192,
         tie_embeddings=True, norm_offset=True, embed_scale=True,
         hidden_act="gelu", bos_token_id=2, eos_token_id=1,
+    ),
+    # MiniCPM-SALA (openbmb, 2026-02; config.json of openbmb/MiniCPM-SALA):
+    # 8 block-sparse attention layers (32 query heads over 2 kv heads, no
+    # rotation) among 24 linear-attention layers (32 heads, decay a head).
+    # The sparse sizes are MiniCPM4's sparse_config (the family's
+    # convention; the published config names none)
+    "minicpm-sala": ModelConfig(
+        name="minicpm-sala", vocab_size=73448, hidden_size=4096,
+        intermediate_size=16384, num_layers=32, num_heads=32, num_kv_heads=2,
+        head_dim=128, rope_theta=10000.0, rms_norm_eps=1e-6,
+        max_seq_len=524288,
+        layer_kinds=tuple(
+            "minicpm4" if i in (0, 9, 16, 17, 22, 29, 30, 31)
+            else "lightning-attn" for i in range(32)
+        ),
+        lin_heads=32, lin_head_dim=128, qk_norm=True, attn_rope=False,
+        attn_gate=True, scale_emb=12.0, scale_depth=1.4, dim_model_base=256,
+        sparse_block=64, sparse_kernel=32, sparse_stride=16, sparse_topk=64,
+        sparse_init_blocks=1, sparse_window=2048,
+    ),
+    # the same family at test size: blocks of 8 keys (the page), so that a
+    # context of a few hundred tokens is past where selection drops blocks
+    "tiny-sala": ModelConfig(
+        name="tiny-sala", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=5, num_heads=4, num_kv_heads=2,
+        head_dim=16, rope_theta=10000.0, rms_norm_eps=1e-6, max_seq_len=512,
+        layer_kinds=(
+            "minicpm4", "lightning-attn", "lightning-attn", "minicpm4",
+            "lightning-attn",
+        ),
+        lin_heads=4, lin_head_dim=16, qk_norm=True, attn_rope=False,
+        attn_gate=True, scale_emb=12.0, scale_depth=1.4, dim_model_base=16,
+        sparse_block=8, sparse_kernel=4, sparse_stride=2, sparse_topk=4,
+        sparse_init_blocks=1, sparse_window=16,
     ),
     "qwen2-0.5b": ModelConfig(
         name="qwen2-0.5b", vocab_size=151936, hidden_size=896,

@@ -119,6 +119,24 @@ METRIC_REGISTRY: dict[str, tuple[str, str]] = {
     "prefix.hits": ("counter", "Prefix-cache hits on admission."),
     "prefix.misses": ("counter", "Prefix-cache misses on admission."),
     "prefix.evictions": ("counter", "Prefix-cache entries evicted."),
+    "state.snapshots": ("counter",
+                        "Snapshots of the recurrent layers' state registered "
+                        "with prefix-cache boundaries."),
+    "state.snapshot_hits": ("counter",
+                            "Admissions that resumed from a snapshot of "
+                            "the recurrent state."),
+    "state.snapshot_evictions": ("counter",
+                                 "Snapshots dropped for the byte budget."),
+    "state.snapshot_bytes": ("gauge",
+                             "Bytes the registered snapshots hold."),
+    "state.resumed_tokens": ("counter",
+                             "Prompt tokens not recomputed because an "
+                             "admission resumed from a snapshot."),
+    "sparse.pages_selected": ("counter",
+                              "Pages a block-sparse layer's decode steps "
+                              "read (one layer, one kv head)."),
+    "sparse.pages_in_context": ("counter",
+                                "Pages the contexts of those steps held."),
     "server.requests": ("counter", "HTTP requests handled by the API core."),
     "provider.retries": ("counter",
                          "Remote provider HTTP attempts retried "

@@ -294,6 +294,43 @@ def paged_attention_block(
     return jnp.swapaxes(out.reshape(B, K, T, G, D), 1, 2).reshape(B, T, H, D)
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_attention_selected(
+    q: jnp.ndarray,  # [B, H, D] one decode token per sequence
+    k_pool: jnp.ndarray,  # [N, K, page_size, D]: every page of every layer
+    v_pool: jnp.ndarray,
+    pages: jnp.ndarray,  # [B, K, n] int32 rows of the pool, position order
+    lengths: jnp.ndarray,  # [B, K] int32 keys in the listed pages
+    scale: float | None = None,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """Single-token attention over a SELECTED page list that differs by kv
+    head (block-sparse attention: ops/sparse_select.py). Only the listed
+    pages are read. Every listed page is full but the last, which holds the
+    query's own position, so a (sequence, kv head) is one row of the decode
+    kernel over a compacted sequence of ``lengths`` keys: the pool is
+    viewed with one page a (page, kv head) pair, a free reshape of the
+    head-major layout, and row ``(b, k)`` lists ``pages[b, k] * K + k``.
+    The softmax has no positional term (the layers that select do not
+    rotate), so the compaction changes nothing. Returns [B, H, D]."""
+    B, H, D = q.shape
+    N, K, ps, _ = k_pool.shape
+    G = H // K
+    if scale is None:
+        scale = D ** -0.5
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    table = (pages * K + jnp.arange(K, dtype=pages.dtype)[None, :, None])
+    out = _paged_call(
+        q.reshape(B * K, 1, G, D),
+        k_pool.reshape(N * K, 1, ps, D), v_pool.reshape(N * K, 1, ps, D),
+        table.reshape(B * K, -1), lengths.reshape(B * K),
+        qt=1, g=G, scale=scale, interpret=interpret,
+        k_scales=None, v_scales=None, name="sparse_paged_attention",
+    )
+    return out.reshape(B, H, D)
+
+
 def _sharded_paged(
     local_fn,
     head_spec,

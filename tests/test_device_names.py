@@ -3,7 +3,7 @@ names under which kernels and programs appear in a device trace.
 
 The benchmark's readers find the step programs (``jit_multi``,
 ``jit_ragged``) and the attention kernels (``paged_attention.N``, …) by
-name (``benchmarks/kernel_costs/names.json``). A Pallas call's operation is
+name (the merged table of ``benchmarks/kernel_costs/names*.json``). A Pallas call's operation is
 named after the innermost scope on its path, so the kernels name
 themselves (``pl.pallas_call(name=...)``) and the ``attention`` scope sits
 outside their jitted wrappers. These tests fail where a refactor would
@@ -12,8 +12,6 @@ otherwise rename a metric's source.
 
 from __future__ import annotations
 
-import json
-import os
 import re
 
 import jax
@@ -21,26 +19,23 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks.kernel_costs import NAMES  # every names*.json, merged
 from fei_tpu.engine.engine import InferenceEngine
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-with open(os.path.join(ROOT, "benchmarks", "kernel_costs", "names.json"),
-          encoding="utf-8") as _f:
-    NAMES = json.load(_f)
 
 # the flat vocabulary every step program uses (docs/OBSERVABILITY.md)
 LAYER_SCOPES = {"embed", "norm", "attn_qkv", "rope", "kv_write", "attention",
                 "attn_out", "mlp", "pool_carry"}
 STEP_SCOPES = LAYER_SCOPES | {"lm_head", "sample", "grammar_mask"}
+# a model whose layers are of several kinds (models/sala.py): no dense
+# ``attention`` call; selection, attention over the selection, the linear
+# layers' recurrence, and the loop that carries their state
+SALA_SCOPES = (LAYER_SCOPES - {"attention"}) | {
+    "sparse_select", "sparse_attention", "linear_attn", "state_carry"}
 
 
-@pytest.fixture(scope="module")
-def programs():
-    """The raw jitted ``multi``, ``ragged`` and paged ``chunk`` programs of
-    a tiny paged engine (grammar variants, final chunk) with arguments to
-    lower them on."""
+def _programs(model: str, **kw):
     engine = InferenceEngine.from_config(
-        "tiny", paged=True, batch_size=2, max_seq_len=256
+        model, paged=True, batch_size=2, max_seq_len=256, **kw
     )
     engine._compiles.wrap = lambda family, key, fn: fn  # no timing shim
     sched = engine.scheduler
@@ -57,14 +52,44 @@ def programs():
     chunk = [jnp.zeros((1, C), jnp.int32), jnp.zeros((1, width), jnp.int32),
              jnp.zeros((1,), jnp.int32), jnp.int32(0)]
     head = [engine.params, sched._pool]
+    # with a recurrent state a chunk is also told where to snapshot it
+    snap = [jnp.int32(0)] if engine.cfg.layer_kinds else []
+    rsnap = {"csnap": jnp.int32(0)} if engine.cfg.layer_kinds else {}
     fns = {
         "multi": (sched._multi_fn(2, True), head + step, gram),
         "ragged": (sched._ragged_fn(2, C, True, True), head + chunk + step,
-                   gram),
-        "chunk": (sched._paged_chunk_fn(C, True), head + chunk, {}),
+                   {**gram, **rsnap}),
+        "chunk": (sched._paged_chunk_fn(C, True), head + chunk + snap, {}),
     }
+    return engine, fns
+
+
+@pytest.fixture(scope="module")
+def programs():
+    """The raw jitted ``multi``, ``ragged`` and paged ``chunk`` programs of
+    a tiny paged engine (grammar variants, final chunk) with arguments to
+    lower them on."""
+    engine, fns = _programs("tiny")
     yield fns
     engine.close()
+
+
+@pytest.fixture(scope="module")
+def sala_programs():
+    engine, fns = _programs("tiny-sala", page_size=8)
+    yield fns
+    engine.close()
+
+
+@pytest.mark.parametrize("program,expected", [
+    ("multi", SALA_SCOPES | {"lm_head", "sample", "grammar_mask"}),
+    ("ragged", SALA_SCOPES | {"lm_head", "sample", "grammar_mask"}),
+    ("chunk", SALA_SCOPES | {"lm_head"}),
+])
+def test_hybrid_step_programs_carry_every_scope(sala_programs, program, expected):
+    fn, args, kw = sala_programs[program]
+    missing = expected - _scopes_in(fn, args, kw)
+    assert not missing, f"{program} lost scopes {sorted(missing)}"
 
 
 def _scopes_in(fn, args, kw) -> set[str]:
@@ -153,6 +178,7 @@ def _kernel_calls():
     from fei_tpu.ops.pallas.paged_attention import (
         paged_attention,
         paged_attention_block,
+        paged_attention_selected,
     )
     from fei_tpu.ops.pallas.ragged_paged_attention import (
         ragged_paged_attention,
@@ -173,6 +199,12 @@ def _kernel_calls():
             lambda: _pallas_names(
                 paged_attention_block, qT, _pool(), _pool(), bt, ln),
         ),
+        "sparse_paged_attention": (
+            "sparse_paged_attention",
+            lambda: _pallas_names(
+                paged_attention_selected, q1, _pool(), _pool(),
+                jnp.zeros((2, 2, 3), jnp.int32), jnp.ones((2, 2), jnp.int32)),
+        ),
         "ragged_paged_attention": (
             "ragged_paged_attention",
             lambda: _pallas_names(
@@ -188,6 +220,7 @@ def _kernel_calls():
 
 
 @pytest.mark.parametrize("call", ["paged_attention", "paged_attention_block",
+                                  "sparse_paged_attention",
                                   "ragged_paged_attention", "flash_attention"])
 def test_kernels_name_themselves_as_names_json_lists(call):
     kernel, names = _kernel_calls()[call]

@@ -68,3 +68,26 @@ def test_ragged_kernel_compiles_for_v5e(one_chip, shape):
 
     compiled = jax.jit(call).lower(*args).compile()
     assert "ragged_paged_attention" in compiled.as_text()
+
+
+def test_selected_page_kernel_compiles_for_v5e_at_g16(one_chip):
+    """MiniCPM-SALA's decode attention (``sparse_paged_attention``) at the
+    cell's shapes: 8 slots, 32 query heads over 2 kv heads of 128 (g = 16,
+    which no other served shape has), 64 selected pages a (slot, kv head)
+    out of the 8 sparse layers' 3073-page pools viewed as one."""
+    from fei_tpu.ops.pallas.paged_attention import paged_attention_selected
+
+    B, H, K, D, ps, topk, pool_pages = 8, 32, 2, 128, 64, 64, 8 * 3073
+
+    def S(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pages = S((pool_pages, K, ps, D), jnp.bfloat16)
+    args = [S((B, H, D), jnp.bfloat16), pages, pages,
+            S((B, K, topk), jnp.int32), S((B, K), jnp.int32)]
+
+    def call(q, kp, vp, sel, n):
+        return paged_attention_selected(q, kp, vp, sel, n, interpret=False)
+
+    compiled = jax.jit(call).lower(*args).compile()
+    assert "sparse_paged_attention" in compiled.as_text()
