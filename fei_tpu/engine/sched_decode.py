@@ -21,6 +21,7 @@ from fei_tpu.engine.faults import FAULTS
 from fei_tpu.engine.sampling import sample_logits_dynamic
 from fei_tpu.models import family
 from fei_tpu.obs.flight import FLIGHT
+from fei_tpu.ops.pallas.paged_attention import pages_walked
 from fei_tpu.ops.pallas.ragged_paged_attention import grid_of as ragged_grid_of
 from fei_tpu.parallel.mesh import mesh_tag
 from fei_tpu.utils.logging import get_logger
@@ -478,7 +479,9 @@ class DecodeMixin:
         and for a merged dispatch ``chunk_lo``, the tokens of the riding
         request already in pages before its chunk, and ``attn_steps``,
         the grid steps of one layer's ragged attention call (a shard's,
-        under tp), from the shapes."""
+        under tp), from the shapes; for a decode-only dispatch
+        ``attn_pages``, the pages one layer's decode-kernel call fetches
+        a kv head at the first step, over the active slots."""
         eng = self.engine
         with FLIGHT.span("loop.build"):
             args, kw, grammared, pc = self._build_step_args(active, n, mask)
@@ -545,6 +548,11 @@ class DecodeMixin:
                 ))
             METRICS.incr("engine.ragged_dispatches")
             METRICS.gauge("engine.kernel_loop_depth", n * eng.cfg.num_layers)
+        elif not self._hybrid:  # its pages are the record's sel_pages
+            extra["attn_pages"] = sum(
+                pages_walked(c, eng.page_size, eng.cfg.sliding_window or 0)
+                for c in ctx
+            )
         if self._hybrid:
             # pages one sparse layer reads a kv head at the first step,
             # and pages its contexts hold, over the active slots: a query

@@ -48,7 +48,10 @@ went into steps x blocks, which more pages a step do not lower; what did:
 fewer visits (one tile for the chunk, no steps below the window), index
 maps that are one scalar load, and a straight-line step body. The page
 update itself stays the solo kernels': 64-wide products at a 1024-row
-tile, about 1.3 us a page, is what is left (ROADMAP S3).
+tile, about 1.3 us a page, is what is left (ROADMAP S3). The solo decode
+kernel (paged_attention.py) has since PR 29 no grid step a page at all:
+it copies a sequence's live pages itself, ``pages_per_step`` slots a
+group, and pays 0.18 us a live page for the same update at g rows.
 
 Everything else — online-softmax (m, l, acc) scratch, per-row causal
 mask ``pos < limit + row_t``, int8 scale folding, sliding-window mask —
@@ -79,8 +82,9 @@ from jax.experimental.pallas import tpu as pltpu
 from fei_tpu.ops.pallas.paged_attention import NEG_INF
 
 
-# a grid step covers up to this many kv positions, in at most this many
-# pages: a step's fixed cost grows with its page blocks (about 0.1 us a
+# a grid step (and a group of the solo decode kernel's page walk, which
+# asks the same rule) covers up to this many kv positions, in at most this
+# many pages: a step's fixed cost grows with its page blocks (about 0.1 us a
 # block and step on a v5e, live or dead), so more pages a step buy no
 # time by themselves — what they buy is a step body long enough for the
 # compiler to overlap one page's matmuls with the next page's loads

@@ -528,6 +528,28 @@ class TestMergedDispatch:
         ))
         assert {r["tags"]["attn_steps"] for r in merged} == {want}
 
+    def test_attn_pages_in_flight_record(self, ragged_eng):
+        """A decode-only dispatch's record says how many pages one
+        layer's decode-kernel call fetches a kv head: ``pages_walked`` of
+        the record's own ``ctx``. A merged dispatch carries none."""
+        from fei_tpu.ops.pallas.paged_attention import pages_walked
+
+        FLIGHT.reset()
+        _overlap(ragged_eng, GEN_LIVE, GEN_LONG)
+        steps = [
+            r["tags"] for r in FLIGHT.records() if r["name"] == "dispatch.step"
+        ]
+        solo = [t for t in steps if not t.get("ragged")]
+        assert solo, "no decode-only dispatch was recorded"
+        ps = ragged_eng.page_size
+        window = ragged_eng.cfg.sliding_window or 0
+        for t in solo:
+            assert len(t["ctx"]) == t["slots"]
+            assert t["attn_pages"] == sum(
+                pages_walked(c, ps, window) for c in t["ctx"]
+            )
+        assert all("attn_pages" not in t for t in steps if t.get("ragged"))
+
     def test_overlap_seeded_identity(self, legacy_refs, ragged_eng):
         live, long_, _ = _overlap(ragged_eng, SEED_LIVE, SEED_LONG)
         assert live == legacy_refs["seed_live"], "seeded live diverged"
