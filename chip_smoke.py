@@ -13,7 +13,7 @@ seed; weight-only int8; byte tokenizer):
                 the tests use, at the tolerance of the repo's bf16 kernel
                 test;
 - ``serve``     ``python -m fei_tpu --model mistral-7b serve`` — the paged
-                scheduler, chunked paged-native admission, the ragged merged
+                scheduler, chunked admission into pages, the ragged merged
                 dispatch, the prefix cache — answering a non-streamed
                 request, four concurrent SSE streams (one with a ~5000-token
                 prompt admitted while the others decode, so chunks ride

@@ -1,7 +1,7 @@
 """Weight-only int8 serving: halve weight HBM, double the decode ceiling.
 
 Three entry points, smallest to largest:
-  1. random-init int8 engine (benches; quantize-at-init, no bf16 peak)
+  1. random-init int8 engine (quantize-at-init, no bf16 peak)
   2. int8 + continuous batching (paged scheduler)
   3. checkpoint streamed straight into sharded HBM, quantizing on the read
      (the 70B-on-a-pod path — here demonstrated on the CPU test mesh)

@@ -7,8 +7,8 @@ mock-up; the reference has no model code at all. Here the Phi architecture
 (shared-norm parallel attn+MLP block, LayerNorm with bias, partial rotary,
 fc1/fc2 biased MLP) is a first-class family in the scan-stacked decoder:
 this example serves it through the paged scheduler exactly like the node
-scenario describes, and on a real chip `FEI_TPU_BENCH_MODEL=phi-2
-python bench.py` measures the real number (2.7B bf16 = 5.6 GB: one v5e).
+scenario describes (2.7B bf16 = 5.6 GB: one v5e; its speed on the chip is
+not measured: no benchmark cell serves it, PERF.md section 7).
 
 Run hermetically on CPU (tiny-phi preset, random weights):
   JAX_PLATFORMS=cpu python examples/phi2_node_serving.py

@@ -341,8 +341,8 @@ class JaxLocalProvider(Provider):
         for its trace, flight records and journal (scheduler.submit).
 
         ``export``/``resume`` are the mid-stream failover side-channel
-        (plain generation only — tool-grammar and speculative routes
-        neither journal nor resurrect): ``export`` is filled in place
+        (plain generation only — tool-grammar routes neither journal nor
+        resurrect): ``export`` is filled in place
         with the delivered token ids and per-token PRNG resume keys, and
         ``resume`` teacher-forces a dead replica's delivered suffix so
         the replayed stream is byte-identical."""
@@ -368,26 +368,11 @@ class JaxLocalProvider(Provider):
         text_so_far = ""
         emitted = 0
         grammar = self._tool_grammar(tools)
-        # prompt-lookup speculation is OPT-IN (FEI_TPU_SPECULATE=1): the
-        # round-5 on-chip A/B measured the draft-verify dispatches costing
-        # 43% of single-stream throughput (spec on 32.73 vs off 58.28
-        # tok/s), so the default path amortizes dispatches with fused
-        # chunks instead. When enabled, greedy agent turns use the dense
-        # lookahead wrapper (token-identical to plain greedy); paged
-        # engines speculate INSIDE the scheduler
-        # (PagedScheduler._maybe_spec_step). Every other dense route
-        # below — grammar turns' free phase and plain sampling streams —
-        # decodes FUSED-CHUNKED (engine/fused_decode.py): one device
-        # dispatch per FEI_TPU_DECODE_CHUNK tokens instead of one host
-        # sync per token, which is what closes the agent-e2e vs raw-decode
-        # gap. Override per provider with gen_overrides={"chunk": N}
-        # (1 = per-token reference path).
-        speculate = (
-            gen.temperature == 0.0
-            and not self.engine.paged
-            and grammar is None
-            and os.environ.get("FEI_TPU_SPECULATE", "0") == "1"
-        )
+        # Every dense route below — grammar turns' free phase and plain
+        # streams — decodes FUSED-CHUNKED (engine/fused_decode.py): one
+        # device dispatch per FEI_TPU_DECODE_CHUNK tokens instead of one
+        # host sync per token. Override per provider with
+        # gen_overrides={"chunk": N} (1 = per-token reference path).
         if resume is not None and grammar is not None:
             # constrained requests are never journaled, so there is no
             # legitimate resume payload for them; restarting the grammar
@@ -402,8 +387,6 @@ class JaxLocalProvider(Provider):
                 self.engine.generate_stream_toolcalls,
                 grammar=grammar, trigger=self.tool_trigger, request=request,
             )
-        elif speculate and resume is None:
-            stream_fn = self.engine.generate_stream_lookahead
         else:
             import functools
 

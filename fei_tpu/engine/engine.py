@@ -1233,123 +1233,6 @@ class InferenceEngine:
         total = time.perf_counter() - t0
         return self._make_result(out, len(prompt_ids), ttft or 0.0, total)
 
-    @staticmethod
-    def _find_draft(
-        ids: list[int], ngram: int, draft_len: int, window: int = 2048
-    ) -> list[int] | None:
-        """Prompt-lookup draft: the most recent earlier occurrence of the
-        last ``ngram`` tokens (within ``window`` positions) proposes the
-        tokens that followed it. Vectorized — a Python scan per decode step
-        would rival the device step itself on long contexts."""
-        if len(ids) <= ngram:
-            return None
-        arr = np.asarray(ids[-window:], dtype=np.int32)
-        tail = arr[-ngram:]
-        if arr.size <= ngram:
-            return None
-        wins = np.lib.stride_tricks.sliding_window_view(arr[:-1], ngram)
-        hits = np.nonzero((wins == tail).all(axis=1))[0]
-        # the final window (ending at the tail itself) is not a real repeat
-        hits = hits[hits < arr.size - ngram]
-        if hits.size == 0:
-            return None
-        j = int(hits[-1])  # newest repeat predicts best in agent loops
-        draft = arr[j + ngram : j + ngram + draft_len].tolist()
-        return draft or None
-
-    def generate_stream_lookahead(
-        self,
-        prompt_ids: Sequence[int],
-        gen: GenerationConfig | None = None,
-        ngram: int = 3,
-        draft_len: int = 8,
-    ) -> Iterator[int]:
-        """Streaming greedy decode with prompt-lookup speculation (assisted
-        generation): when the last ``ngram`` tokens repeat earlier context,
-        the tokens that followed that occurrence are verified in ONE
-        forward of T = 1 + draft_len — agent outputs echo prompt content
-        (paths, identifiers, code), so several tokens often land per
-        dispatch. Exactly equal to greedy ``generate_stream`` by
-        construction (accepted tokens are the model's own argmax). Sampled
-        configs and paged engines fall back to the normal stream.
-        """
-        gen = gen or GenerationConfig()
-        if gen.temperature != 0.0 or self.paged:
-            yield from self.generate_stream(prompt_ids, gen)
-            return
-        stops = self._stops(gen)
-        budget = min(gen.max_new_tokens, self.max_seq_len - len(prompt_ids))
-        tok, cache, _rng = self._prefill_sample(prompt_ids, gen)
-        emitted_n = 0
-        last = int(tok[0])
-        all_ids = list(prompt_ids)
-        T = 1 + draft_len
-        while emitted_n < budget and last not in stops:
-            yield last
-            emitted_n += 1
-            all_ids.append(last)
-            if emitted_n >= budget:
-                break
-            pos = len(all_ids)  # tokens whose KV the cache must hold next
-            draft = self._find_draft(all_ids, ngram, draft_len)
-            if draft is None or pos + T > self.max_seq_len:
-                # no draft (or no cache room for a block): single step
-                step = self._step_fn(gen)
-                with METRICS.span("decode_step"):
-                    tok, cache, _rng = step(
-                        self.params, cache, jnp.asarray([[last]]), _rng, None
-                    )
-                    last = int(tok[0])
-                continue
-            draft = draft + [0] * (draft_len - len(draft))  # static T
-            toks = jnp.asarray([[last] + draft], dtype=jnp.int32)
-            with METRICS.span("spec_step"):
-                logits, cache = self._prefill_fn(T)(self.params, toks, cache)
-                greedy = np.asarray(jnp.argmax(logits[0], axis=-1))
-            # greedy[i] is the model's token after consuming toks[:i+1];
-            # accept draft tokens while they match the model's own argmax
-            accept = 0
-            while accept < draft_len and draft[accept] == int(greedy[accept]):
-                accept += 1
-            block = [int(g) for g in greedy[: accept + 1]]
-            # cache holds T new KV rows but only 1 + accept are real; the
-            # corrected length masks the rest and later writes overwrite
-            cache = cache._replace(
-                length=jnp.full((1,), pos + accept, dtype=jnp.int32)
-            )
-            for t in block[:-1]:
-                if emitted_n >= budget or t in stops:
-                    last = t
-                    break
-                yield t
-                emitted_n += 1
-                all_ids.append(t)
-            else:
-                last = block[-1]
-                continue
-            break  # hit stop/budget inside the block
-
-    def generate_lookahead(
-        self,
-        prompt_ids: Sequence[int],
-        gen: GenerationConfig | None = None,
-        ngram: int = 3,
-        draft_len: int = 8,
-    ) -> GenerationResult:
-        """Collected form of ``generate_stream_lookahead`` with timings."""
-        gen = gen or GenerationConfig()
-        t0 = time.perf_counter()
-        ttft = None
-        out: list[int] = []
-        for tok in self.generate_stream_lookahead(
-            prompt_ids, gen, ngram=ngram, draft_len=draft_len
-        ):
-            if ttft is None:
-                ttft = time.perf_counter() - t0
-            out.append(tok)
-        total = time.perf_counter() - t0
-        return self._make_result(out, len(prompt_ids), ttft or 0.0, total)
-
     def generate_fused(
         self,
         prompt_ids: Sequence[int],
@@ -1378,8 +1261,4 @@ class InferenceEngine:
 
     def chat(self, messages: list[dict], gen: GenerationConfig | None = None) -> GenerationResult:
         ids = self.tokenizer.apply_chat_template(messages, add_generation_prompt=True)
-        gen = gen or GenerationConfig()
-        if gen.temperature == 0.0 and not self.paged:
-            # chat turns echo conversation content; prompt lookup is free
-            return self.generate_lookahead(ids, gen)
         return self.generate(ids, gen)

@@ -247,7 +247,8 @@ class SessionJournal:
     def token(self, rid: str, tok: int, key=None) -> None:
         """Journal one DELIVERED token. ``key`` is the slot's PRNG
         state after sampling it ([2] uint32 as a list), or None for
-        paths where the chain did not advance (greedy speculation)."""
+        a token delivered without advancing the chain (recovery then
+        keeps the last recorded key)."""
         self._put({"t": "tok", "rid": rid, "tok": int(tok), "key": key})
 
     def finish(self, rid: str, reason: str = "completed") -> None:

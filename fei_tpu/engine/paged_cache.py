@@ -120,11 +120,11 @@ class PagedKVCache(NamedTuple):
 
 def replace_lengths(pool: "PagedKVCache", lengths) -> "PagedKVCache":
     """Host-authoritative per-slot length override: swap ONLY the ``[B]``
-    lengths leaf. This is the rollback primitive shared by speculative
-    verification and the scheduler's turbo-scan free phase — positions at
-    or above a slot's new length are unreachable (decode attends strictly
-    below ``lengths``) and later writes land at the running length,
-    overwriting any rolled-back garbage in place."""
+    lengths leaf. This is the rollback primitive of the scheduler's
+    turbo-scan free phase — positions at or above a slot's new length are
+    unreachable (decode attends strictly below ``lengths``) and later
+    writes land at the running length, overwriting any rolled-back garbage
+    in place."""
     return pool._replace(lengths=jnp.asarray(lengths, dtype=jnp.int32))
 
 
@@ -497,10 +497,10 @@ def write_token_kv(
 ):
     """Scatter one decode token's K/V into each sequence's current page.
 
-    Returns (k_pages, v_pages) for bf16 pools, or
-    (k_pages, v_pages, k_scales, v_scales) when the pool is int8: the new
-    token quantizes per (sequence, head) over D — per-slot scales, so no
-    other slot is ever re-read or re-scaled.
+    Returns (k_pages, v_pages, k_scales, v_scales), the scales None for a
+    bf16 pool. For an int8 pool the new token quantizes per (sequence,
+    head) over D — per-slot scales, so no other slot is ever re-read or
+    re-scaled.
     """
     ps = k_pages.shape[2]
     B = k_new.shape[0]
@@ -535,9 +535,7 @@ def write_token_kv(
             v_scales = jax.lax.dynamic_update_slice(
                 v_scales, vs_upd, (page, 0, 0, offset[b])
             )
-    if quantized:
-        return k_pages, v_pages, k_scales, v_scales
-    return k_pages, v_pages
+    return k_pages, v_pages, k_scales, v_scales
 
 
 def paged_attention_reference(

@@ -3,9 +3,8 @@
 The batched decode dispatches over armed slots: the single scanned step
 program shared by every path (host-masked single step, device-grammar
 constrained step, and the multi-step turbo scan that batches N steps into
-one dispatch), plus prompt-lookup speculation for the single-stream case.
-Split out of the scheduler class body (round-4) as a MIXIN over
-PagedScheduler state — see sched_admission.py for the rationale.
+one dispatch). Split out of the scheduler class body (round-4) as a MIXIN
+over PagedScheduler state — see sched_admission.py for the rationale.
 """
 
 from __future__ import annotations
@@ -24,10 +23,7 @@ from fei_tpu.obs.flight import FLIGHT
 from fei_tpu.ops.pallas.paged_attention import pages_walked
 from fei_tpu.ops.pallas.ragged_paged_attention import grid_of as ragged_grid_of
 from fei_tpu.parallel.mesh import mesh_tag
-from fei_tpu.utils.logging import get_logger
 from fei_tpu.utils.metrics import METRICS
-
-log = get_logger("scheduler")
 
 
 def _make_sampler(grammared: bool, masked: bool):
@@ -74,152 +70,13 @@ def _make_sampler(grammared: bool, masked: bool):
 
 
 class DecodeMixin:
-    """Batched decode stepping: spec, single, and multi-step dispatches."""
-
-    def _maybe_spec_step(self) -> bool:
-        """Prompt-lookup speculation inside the scheduler: when exactly one
-        greedy, unconstrained stream is decoding (the dominant agent-loop
-        serving shape), a repeated n-gram proposes draft tokens and ONE
-        multi-token paged dispatch (forward_paged_block) verifies them —
-        token-identical to the per-step path by construction, with up to
-        1 + draft_len tokens landing per weight read. Multi-stream batches
-        keep per-token steps (their throughput already amortizes the
-        weight read across slots). Returns True if a spec step ran."""
-        if not self.speculate:
-            return False
-        if self._admitting is not None:
-            return False
-        active = [
-            (b, s) for b, s in enumerate(self._slots) if s is not None
-        ]
-        if len(active) != 1:
-            return False
-        b, s = active[0]
-        if (
-            s.prefilling
-            or s.gen.temperature != 0.0
-            or s.mask_fn is not None
-            # device-grammar requests speculate during their FREE phase
-            # (pre-trigger — the bulk of an agent turn); once the DFA
-            # engages (gstate >= 0) verification can't apply the mask,
-            # so constrained decode keeps per-token steps
-            or (s.grammar is not None and s.gstate >= 0)
-        ):
-            return False
-        eng = self.engine
-        draft = eng._find_draft(
-            s.prompt_ids + s.generated, self.spec_ngram, self.spec_draft_len
-        )
-        if draft is None:
-            return False
-        T = 1 + self.spec_draft_len
-        # pool length for the slot: prompt + generated, minus the pending
-        # next_input whose KV is written when it is fed
-        L0 = len(s.prompt_ids) + len(s.generated) - 1
-        # room is ABSOLUTE top-end capacity: rolling-buffer SWA releases
-        # drop leading pages from pages_for, but the slot's reserved high
-        # positions are unchanged — count the released pages back in or
-        # long SWA streams silently lose speculation mid-stream
-        room = (
-            s.released_pages + len(eng._allocator.pages_for(b))
-        ) * eng.page_size
-        if L0 + T > min(room, eng.max_seq_len):
-            return False
-        draft = draft + [0] * (self.spec_draft_len - len(draft))
-        tokens = np.zeros((self.B, T), dtype=np.int32)
-        tokens[b] = [s.next_input] + draft
-        try:
-            t0 = time.perf_counter()
-            with METRICS.span("spec_step"):
-                greedy_dev, self._pool = self._spec_fn(T)(
-                    eng.params, self._pool, jnp.asarray(tokens)
-                )
-                t_issue = time.perf_counter()
-                greedy = np.asarray(greedy_dev)[b]  # host sync in the span
-            FLIGHT.dispatch(
-                "dispatch.spec", t0, t_issue, time.perf_counter(),
-                rid=s.rid, mesh=mesh_tag(eng.mesh), slot=b, draft=T - 1,
-            )
-        except Exception as exc:  # noqa: BLE001
-            if self._pool_intact():
-                # compile-stage failure (e.g. Mosaic rejecting the block
-                # kernel on-chip): the donated pool was never consumed —
-                # drop to per-token steps instead of killing every stream
-                log.warning(
-                    "speculative step failed (%r); disabling speculation",
-                    exc,
-                )
-                self.speculate = False
-                METRICS.incr("scheduler.spec_disabled")
-                return False
-            raise  # pool consumed mid-execution: let _fail_all handle it
-        accept = 0
-        while (
-            accept < self.spec_draft_len
-            and draft[accept] == int(greedy[accept])
-        ):
-            accept += 1
-        # greedy[:accept + 1] are all model-chosen tokens (verified draft
-        # prefix + the bonus token)
-        METRICS.incr("scheduler.spec_steps")
-        METRICS.incr("scheduler.spec_accepted", accept)
-        delivered = 0
-        spec_key = None
-        if s.journaled or s.export is not None:
-            # the spec path is greedy-only and never advances the PRNG
-            # chain, so every token in the verified block shares the
-            # slot's current key state as its resume point
-            spec_key = np.asarray(self._keys[b])
-        for t in [int(g) for g in greedy[: accept + 1]]:
-            self._deliver(s, t, key=spec_key)
-            if s.finished:
-                break
-            delivered += 1
-            if s.grammar is not None and s.gstate >= 0:
-                # the tool-call trigger completed inside this block: the
-                # remaining verified tokens were sampled UNCONSTRAINED —
-                # drop them; the constrained phase re-decodes under the
-                # DFA mask from here
-                break
-        if not s.finished:
-            # KV is real through L0 + delivered - 1; the next fed token is
-            # s.next_input at position L0 + delivered. The block wrote T
-            # rows, so shrink the slot's length — inactive slots' lengths
-            # return to 0 (their writes landed in the null page)
-            from fei_tpu.engine.paged_cache import replace_lengths
-
-            lengths = np.zeros((self.B,), dtype=np.int32)
-            lengths[b] = L0 + delivered
-            self._pool = replace_lengths(self._pool, lengths)
-        return True
-
-
-    def _spec_fn(self, T: int):
-        key = ("spec", T)
-        if key not in self._step_jit:
-            cfg = self.engine.cfg
-            mesh = self.engine.mesh
-
-            def spec(params, pool, tokens):
-                from fei_tpu.models.llama import forward_paged_block
-
-                logits, pool = forward_paged_block(
-                    params, cfg, tokens, pool, kernel_mesh=mesh
-                )
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), pool
-
-            self._step_jit[key] = self.engine._compiles.wrap(
-                "sched.spec", key, jax.jit(spec, donate_argnums=(1,))
-            )
-        return self._step_jit[key]
-
+    """Batched decode stepping: single and multi-step dispatches."""
 
     def _step_active(self) -> None:
         self._step_active_impl()
         # a deferred admission chunk not consumed by this iteration's
-        # decode dispatch (masked single-step path, spec path, all armed
-        # slots finished mid-iteration, or the ragged program disarmed
-        # itself) still makes progress NOW — bounded-stall admission is a
+        # decode dispatch (masked single-step path, or all armed slots
+        # finished mid-iteration) still makes progress NOW — bounded-stall admission is a
         # guarantee, not a fast path. Deliberately not in a finally:
         # after a device error the loop's handler owns the pool.
         self._flush_pending_chunk()
@@ -227,8 +84,6 @@ class DecodeMixin:
     def _step_active_impl(self) -> None:
         eng = self.engine
         B, V = self.B, eng.cfg.vocab_size
-        if self._maybe_spec_step():
-            return
         if self._try_multi_step():
             return
         # evaluate per-request masks FIRST: a user mask_fn that raises (or

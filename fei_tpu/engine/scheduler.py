@@ -166,7 +166,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
     and the shared state every path mutates; the three feature surfaces
     live in sibling modules as mixins over this state (round-4 split):
     sched_admission.AdmissionMixin (queue -> armed slot), sched_decode.
-    DecodeMixin (batched/multi-step/speculative stepping), and
+    DecodeMixin (batched single- and multi-step decode), and
     sched_constrain.ConstraintMixin (grammar install + host DFA mirror +
     host masks). Mixins, not delegate objects: the interleaving invariants
     (single owner thread, lock discipline, donated pool) stay one-object.
@@ -185,7 +185,6 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         self._step_keys = None  # [n, B, 2] stacked keys of the last scan
         self._step_jit: dict = {}
         self._admit_jit: dict = {}
-        self._chunk_jit: dict = {}
         self._evict_jit = None
         # prompts longer than this admit in chunks, one chunk per loop
         # iteration, so active decode streams never stall longer than one
@@ -200,40 +199,14 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         self.sp_admit_factor = int(
             _os.environ.get("FEI_TPU_SP_ADMIT_FACTOR", "8")
         )
-        # prompt-lookup speculation for the single-stream paged case:
-        # greedy echoes of prompt content verify in one multi-token
-        # dispatch. OPT-IN (FEI_TPU_SPECULATE=1): the round-5 on-chip A/B
-        # measured the draft-verify dispatches costing 43% of single-stream
-        # throughput (spec on 32.73 vs off 58.28 tok/s) — the turbo scan is
-        # the default dispatch-amortization path instead.
-        self.spec_ngram = int(_os.environ.get("FEI_TPU_SPEC_NGRAM", "3"))
-        self.spec_draft_len = int(_os.environ.get("FEI_TPU_SPEC_DRAFT", "8"))
-        self.speculate = _os.environ.get("FEI_TPU_SPECULATE", "0") == "1"
-        # ragged merged dispatch: a paged-native prefill chunk defers one
-        # loop iteration and rides the decode scan as ONE program — the
-        # ragged paged-attention kernel serves the chunk's rows and the
-        # decode rows in a single invocation per layer, so the weights
-        # stream once for both (ops/pallas/ragged_paged_attention.py).
-        # FEI_TPU_ATTENTION=paged keeps the legacy two-program shape
-        # (solo chunk + solo scan) for A/B and rollback; token streams
-        # are bit-identical either way.
-        attn = _os.environ.get("FEI_TPU_ATTENTION", "ragged")
-        if attn not in ("ragged", "paged"):
-            raise EngineError(
-                f"unknown FEI_TPU_ATTENTION {attn!r} (ragged | paged)"
-            )
-        self.ragged_attention = attn == "ragged"
+        # ragged merged dispatch: a prefill chunk defers one loop iteration
+        # and rides the decode scan as ONE program — the ragged
+        # paged-attention kernel serves the chunk's rows and the decode rows
+        # in a single invocation per layer, so the weights stream once for
+        # both (ops/pallas/ragged_paged_attention.py). With no slot
+        # decoding, the chunk runs as its own program; token streams are
+        # bit-identical either way.
         self._pending_chunk: dict | None = None  # deferred merge chunk
-        # paged-NATIVE chunked prefill: admission chunks write K/V straight
-        # into pool pages and attend via the multi-query block kernel
-        # through a one-slot pool view — no dense staging cache (bucket ×
-        # L × K × D × 2 of HBM at 8B/8k scale), no completion scatter, and
-        # prefix-cache hits read their shared pages in place instead of
-        # gathering to dense. FEI_TPU_PAGED_PREFILL=0 restores the staging
-        # path (e.g. if Mosaic rejects the block kernel's chunk tile).
-        self.paged_native_prefill = (
-            _os.environ.get("FEI_TPU_PAGED_PREFILL", "1") != "0"
-        )
         # multi-step decode: scan up to N batched steps inside ONE device
         # dispatch — the scheduler's steady state. Runs under queued and
         # chunked admissions (one prefill chunk interleaves with one scan
@@ -314,7 +287,6 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         self._closed = False
         self._admitting: dict | None = None  # in-flight chunked admission
         self._prefix = None  # PrefixCache when engine.prefix_cache
-        self._gather_jit: dict = {}
         # active device grammar: ONE table pair serves every constrained
         # request (the agent memoizes one union grammar per tool set); a
         # second distinct grammar falls back to host masks until the first
@@ -376,10 +348,6 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         why = None
         if self._kv_tier is not None:
             why = "the KV tier (FEI_TPU_KV_TIER) spills and streams pages without the linear layers' state"
-        elif not self.paged_native_prefill:
-            why = "FEI_TPU_PAGED_PREFILL=0 stages a dense cache, and only pages and state are served"
-        elif self.speculate:
-            why = "FEI_TPU_SPECULATE=1 rolls a slot's length back, which a recurrent state cannot follow"
         elif self.prefill_chunk % eng.page_size:
             why = (f"an admission chunk ({self.prefill_chunk}) must be whole "
                    f"pages of {eng.page_size}")
@@ -1152,14 +1120,14 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         """Rolling-buffer SWA: pages wholly below (pos - window - margin)
         return to the pool mid-stream — the decode kernels' index maps
         clamp past them, so they are never read OR DMA'd again. The margin
-        covers the deepest mid-stream length shrink — a rejected spec
-        draft OR a turbo-scan grammar rollback (up to ``multistep - 1``
-        scanned tokens discarded); a page released under the longer
-        length must still be below the window after the shrink — plus one
-        page of slack for the multi-token block writes."""
+        covers the deepest mid-stream length shrink — a turbo-scan grammar
+        rollback (up to ``multistep - 1`` scanned tokens discarded); a page
+        released under the longer length must still be below the window
+        after the shrink — plus one page of slack for the multi-token
+        block writes."""
         W = self.engine.cfg.sliding_window
         ps = self.engine.page_size
-        margin = max(self.spec_draft_len, self.multistep) + ps
+        margin = self.multistep + ps
         cur = len(seq.prompt_ids) + len(seq.generated)
         releasable = max(0, (cur - W - margin)) // ps
         if releasable > seq.released_pages:
@@ -1527,8 +1495,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
             pool_fingerprint,
             shard_layout,
         )
-        from fei_tpu.kv.tier import PageEntry
-        from fei_tpu.obs.costmodel import account_kv_transfer
+        from fei_tpu.kv.tier import PageEntry, account_kv_transfer
 
         try:
             alloc = self.engine._allocator
@@ -1857,7 +1824,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
 
     @staticmethod
     def _device_call(what: str, fn, *args, **kw):
-        """Run the ragged or the paged-native program. Whatever it raises
+        """Run the merged or the solo chunk program. Whatever it raises
         — a trace- or compile-stage refusal (Mosaic rejecting the kernel)
         as much as a runtime fault — leaves as the typed ``DeviceError``:
         no other program takes over, so a kernel the chip refuses fails

@@ -237,6 +237,22 @@ def unpack_entry(blob: bytes) -> tuple[PageEntry, dict]:
     return entry, dict(header.get("extra") or {})
 
 
+def account_kv_transfer(direction: str, nbytes: int, dt_s: float) -> None:
+    """Bytes-moved accounting for the tier and migration: cumulative byte
+    counters plus an achieved-GB/s gauge per direction. ``direction`` is
+    ``spilled`` (HBM→host on preemption) or ``fetched`` (host→HBM on
+    streamed resume). The gauge tells the operator whether tier traffic
+    is anywhere near the device-transfer ceiling — spill/fetch time is
+    pure resume-latency overhead."""
+    if direction not in ("spilled", "fetched"):
+        return
+    METRICS.incr(f"kv.bytes_{direction}", int(nbytes))
+    if dt_s > 0:
+        METRICS.gauge(
+            f"kv.{direction}_gbps", round(nbytes / dt_s / 1e9, 6)
+        )
+
+
 # -- the store -------------------------------------------------------------
 
 

@@ -407,6 +407,44 @@ def _attend(q, k, v, kv_length, positions, window: int = 0,
     return attention(q, k, v, positions, kv_length + T, window=window)
 
 
+def _block_head(cfg: ModelConfig, lp, x, positions, cos, sin,
+                kernel_mesh=None):
+    """A decoder block up to its attention: the attention norm, the q/k/v
+    projections and their rotation. x: [B,T,H]. Returns (y, q, k, v), y
+    the normed input (a parallel block's MLP reads it too)."""
+    y = _norm(x, lp["attn_norm"], cfg, b=lp.get("attn_norm_b"))
+    q, k, v = qkv_proj(
+        lp, y, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_,
+        kernel_mesh=kernel_mesh,
+    )
+    q = _rope(q, cos, sin, positions, cfg.rope_dim_)
+    k = _rope(k, cos, sin, positions, cfg.rope_dim_)
+    return y, q, k, v
+
+
+def _block_tail(cfg: ModelConfig, lp, x, y, attn, allow_routed: bool = False,
+                moe_mesh=None, kernel_mesh=None):
+    """A decoder block from its attention on: the output projection of
+    attn [B,T,Hq,d] and the MLP, summed into the residual x."""
+    B, T, _ = x.shape
+    with jax.named_scope("attn_out"):
+        o = mm(attn.reshape(B, T, cfg.num_heads * cfg.head_dim_), lp["wo"])
+        if "bo" in lp:  # HF Llama attention_bias=true also biases o_proj
+            o = o + lp["bo"]
+    if not cfg.parallel_block:
+        x = x + o
+        y = _norm(x, lp["mlp_norm"], cfg, b=lp.get("mlp_norm_b"))
+    mlp_out = (
+        _moe(cfg, y, lp, allow_routed, moe_mesh) if cfg.is_moe
+        else _mlp_dense(cfg, y, lp, kernel_mesh)
+    )
+    if cfg.parallel_block:
+        # Phi: attention and MLP both read the ONE shared norm output and
+        # sum into the residual — x + attn(ln x) + mlp(ln x)
+        return x + o + mlp_out
+    return x + mlp_out
+
+
 def _layer(
     cfg: ModelConfig, x, lp, cache_k, cache_v, kv_length, positions, cos, sin,
     allow_routed: bool = False, moe_mesh=None, kernel_mesh=None,
@@ -414,15 +452,7 @@ def _layer(
     """One decoder block. x: [B,T,H]; cache_k/v: [B,S,K,D] (this layer's
     slice) or None for the cache-free training path.
     Returns (x_out, new_cache_k, new_cache_v)."""
-    B, T, h = x.shape
-    K, d = cfg.num_kv_heads, cfg.head_dim_
-    Hq = cfg.num_heads
-
-    y = _norm(x, lp["attn_norm"], cfg, b=lp.get("attn_norm_b"))
-    q, k, v = qkv_proj(lp, y, Hq, K, d, kernel_mesh=kernel_mesh)
-    rd = cfg.rope_dim_
-    q = _rope(q, cos, sin, positions, rd)
-    k = _rope(k, cos, sin, positions, rd)
+    y, q, k, v = _block_head(cfg, lp, x, positions, cos, sin, kernel_mesh)
 
     if cache_k is None:
         new_k, new_v = k, v
@@ -439,27 +469,9 @@ def _layer(
         q, new_k, new_v, kv_length, positions,
         window=cfg.sliding_window or 0, kernel_mesh=kernel_mesh,
     )
-    with jax.named_scope("attn_out"):
-        o = mm(attn_out.reshape(B, T, Hq * d), lp["wo"])
-        if "bo" in lp:  # HF Llama attention_bias=true also biases o_proj
-            o = o + lp["bo"]
-
-    if cfg.parallel_block:
-        # Phi: attention and MLP both read the ONE shared norm output and
-        # sum into the residual — x + attn(ln x) + mlp(ln x)
-        mlp_out = (
-            _moe(cfg, y, lp, allow_routed, moe_mesh) if cfg.is_moe
-            else _mlp_dense(cfg, y, lp, kernel_mesh)
-        )
-        return x + o + mlp_out, new_k, new_v
-    x = x + o
-
-    y = _norm(x, lp["mlp_norm"], cfg, b=lp.get("mlp_norm_b"))
-    if cfg.is_moe:
-        mlp_out = _moe(cfg, y, lp, allow_routed, moe_mesh)
-    else:
-        mlp_out = _mlp_dense(cfg, y, lp, kernel_mesh)
-    return x + mlp_out, new_k, new_v
+    x = _block_tail(cfg, lp, x, y, attn_out, allow_routed, moe_mesh,
+                    kernel_mesh)
+    return x, new_k, new_v
 
 
 @jax.named_scope("lm_head")
@@ -525,6 +537,50 @@ def forward(
     return _logits(x, params, cfg, kernel_mesh=kernel_mesh), new_cache
 
 
+def _kernel_sharded(kernel_mesh) -> bool:
+    """Any sharding axis (tp heads OR dp batch groups) must lift a Pallas
+    kernel through shard_map: XLA cannot auto-partition a pallas_call."""
+    return kernel_mesh is not None and (
+        kernel_mesh.shape.get("tp", 1) > 1
+        or kernel_mesh.shape.get("dp", 1) > 1
+    )
+
+
+def _write_rows(kp, vp, ksc, vsc, k, v, block_table, start):
+    """Write the T rows of k/v [B,T,K,D] at positions ``start`` [B] onward
+    into one layer's pages through ``block_table``. ksc/vsc: the pages'
+    scales (an int8 pool) or None. Returns the four, updated."""
+    from fei_tpu.engine.paged_cache import write_token_kv
+
+    for i in range(k.shape[1]):
+        kp, vp, ksc, vsc = write_token_kv(
+            kp, vp, k[:, i], v[:, i], block_table, start + i,
+            k_scales=ksc, v_scales=vsc,
+        )
+    return kp, vp, ksc, vsc
+
+
+def _scan_pool(body, carry, params: dict, cache):
+    """The layer scan of a paged step: ``body(carry, lp, kp, vp, ksc, vsc)
+    -> (carry, (kp, vp, ksc, vsc))`` runs once a layer on that layer's
+    pages (scales None for a bf16 pool). The pool rides as the scan's xs
+    and comes back as its ys. Returns (carry, cache with the new pages)."""
+    xs = (
+        params["layers"], cache.k_pages, cache.v_pages,
+        cache.k_scales, cache.v_scales,
+    )
+    # the layer scan slices each layer's pool out of the stack and writes
+    # it back: those operations are the scan's own, and this is the only
+    # name they can be given
+    with jax.named_scope("pool_carry"):
+        carry, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
+            lambda carry, x: body(carry, *x), carry, xs
+        )
+    return carry, cache._replace(
+        k_pages=new_k, v_pages=new_v, k_scales=new_ks, v_scales=new_vs
+    )
+
+
 def forward_paged(
     params: dict,
     cfg: ModelConfig,
@@ -545,44 +601,62 @@ def forward_paged(
     under shard_map with kv heads sharded (XLA cannot auto-partition a
     pallas_call), making multi-chip paged serving real; everything else in
     the layer partitions from the param/pool shardings as usual.
-
-    Implemented as the T=1 case of ``forward_paged_block`` so single-step
-    decode and speculative verification can never diverge.
     """
-    return forward_paged_block(
+    return _forward_paged_block(
         params, cfg, tokens, cache,
         routed_moe=routed_moe, moe_mesh=moe_mesh, kernel_mesh=kernel_mesh,
     )
 
 
-def forward_paged_block(
+def forward_chunk(
     params: dict,
     cfg: ModelConfig,
-    tokens: jnp.ndarray,  # [B, T] int32 — T draft tokens per sequence
+    toks: jnp.ndarray,  # [1, C] int32 — one prefill chunk
+    cache,  # PagedKVCache under the LIVE table/lengths
+    row: jnp.ndarray,  # [1, max_pages] admitting slot's table row
+    pos: jnp.ndarray,  # [1] int32 — chunk's absolute start position
+    routed_moe: bool = False,
+    moe_mesh=None,
+    kernel_mesh=None,
+) -> tuple[jnp.ndarray, object]:
+    """One admission chunk of one slot: forward ``toks`` against a
+    one-slot view of the pool (``row`` as its table, ``pos`` as its
+    length), K/V landing in the slot's pages. Returns (final-normed
+    hidden [1, C, H], cache with the updated pages under its LIVE table
+    and lengths: decode must keep seeing the slot's zeroed row until the
+    admission completes)."""
+    hidden, view = _forward_paged_block(
+        params, cfg, toks, cache._replace(block_table=row, lengths=pos),
+        routed_moe=routed_moe, moe_mesh=moe_mesh, kernel_mesh=kernel_mesh,
+        lm_head=False,
+    )
+    return hidden, view._replace(
+        block_table=cache.block_table, lengths=cache.lengths
+    )
+
+
+def _forward_paged_block(
+    params: dict,
+    cfg: ModelConfig,
+    tokens: jnp.ndarray,  # [B, T] int32 — T tokens per sequence
     cache,  # PagedKVCache
     routed_moe: bool = False,
     moe_mesh=None,
     kernel_mesh=None,
     lm_head: bool = True,
 ) -> tuple[jnp.ndarray, object]:
-    """Multi-token paged forward for speculative VERIFICATION — and, with
-    ``lm_head=False`` (returns final-norm hidden [B, T, H] instead of
-    logits), the chunk body of paged-native prefill, which only projects
-    one position.
+    """T tokens a sequence against the paged cache: the decode step (T=1)
+    and, with ``lm_head=False`` (returns final-norm hidden [B, T, H]
+    instead of logits), the body of an admission chunk, which only
+    projects one position.
 
-    All T tokens' projections/MLP batch into single matmuls (one weight
-    read for T tokens — the point of speculation on a weight-streaming-
-    bound decode) and their K/V scatter into the sequence's pool pages.
-    Attention uses the multi-query block kernel
+    All T tokens' projections/MLP batch into single matmuls and their K/V
+    scatter into the sequence's pool pages. T=1 attends through the
+    single-query kernel; T>1 through the multi-query block kernel
     (ops.pallas.paged_attention_block): pool history is read ONCE for the
-    whole block with per-row causal limits. FEI_TPU_BLOCK_ATTN=0 falls
-    back to T unrolled single-query kernel calls; T=1 (plain decode)
-    always takes the single-query kernel already validated under Mosaic.
-    Returns (logits [B, T, V] fp32, cache with lengths += T). The CALLER
-    owns rollback: only the accepted prefix's K/V is real — shrink
-    ``lengths`` to mask the rest, exactly like the dense lookahead path.
+    whole block with per-row causal limits.
+    Returns (logits [B, T, V] fp32, cache with lengths += T).
     """
-    from fei_tpu.engine.paged_cache import write_token_kv
     from fei_tpu.ops.pallas import paged_attention
     from fei_tpu.ops.pallas.paged_attention import (
         paged_attention_block,
@@ -591,122 +665,56 @@ def forward_paged_block(
     )
 
     B, T = tokens.shape
-    K, d, Hq = cfg.num_kv_heads, cfg.head_dim_, cfg.num_heads
     positions = cache.lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     max_pos = cache.block_table.shape[1] * cache.page_size
     cos, sin = compute_rope_freqs(cfg.rope_dim_, max_pos, cfg.rope_theta)
-    # kernel-selection policy: see the docstring
-    block_kernel = T > 1 and os.environ.get("FEI_TPU_BLOCK_ATTN", "1") != "0"
-    # any sharding axis (tp heads OR dp batch groups) must lift the pallas
-    # kernel through shard_map — XLA cannot auto-partition a pallas_call
-    sharded = kernel_mesh is not None and (
-        kernel_mesh.shape.get("tp", 1) > 1
-        or kernel_mesh.shape.get("dp", 1) > 1
-    )
+    sharded = _kernel_sharded(kernel_mesh)
     win = cfg.sliding_window or 0
 
     kv_int8 = cache.k_scales is not None
     dtype = model_dtype(params) if kv_int8 else cache.k_pages.dtype
     x = embed_tokens(params, cfg, tokens, dtype)  # [B, T, h]
 
-    def body(x, layer_inputs):
-        if kv_int8:
-            lp, kp, vp, ksc, vsc = layer_inputs
-        else:
-            lp, kp, vp = layer_inputs
-            ksc = vsc = None
-        y = _norm(x, lp["attn_norm"], cfg, b=lp.get("attn_norm_b"))
-        q, k, v = qkv_proj(lp, y, Hq, K, d, kernel_mesh=kernel_mesh)
-        q = _rope(q, cos, sin, positions, cfg.rope_dim_)
-        k = _rope(k, cos, sin, positions, cfg.rope_dim_)
-
+    def body(x, lp, kp, vp, ksc, vsc):
+        y, q, k, v = _block_head(cfg, lp, x, positions, cos, sin, kernel_mesh)
         # write all T positions' K/V (causality is the kernel's per-row
         # mask, so writing ahead of attending is safe)
-        for i in range(T):
-            written = write_token_kv(
-                kp, vp, k[:, i], v[:, i], cache.block_table,
-                cache.lengths + i, k_scales=ksc, v_scales=vsc,
-            )
-            if kv_int8:
-                kp, vp, ksc, vsc = written
-            else:
-                kp, vp = written
+        kp, vp, ksc, vsc = _write_rows(
+            kp, vp, ksc, vsc, k, v, cache.block_table, cache.lengths
+        )
         # the scope sits OUTSIDE the kernels' jitted wrappers: the
         # innermost name on a Pallas call's path is the name its
         # operation gets in a device trace, and that stays the kernel's
         with jax.named_scope("attention"):
-            if block_kernel:
-                if sharded:
-                    attn = paged_attention_block_sharded(
-                        q, kp, vp, cache.block_table, cache.lengths,
-                        kernel_mesh, axis_name="tp", k_scales=ksc, v_scales=vsc,
-                        window=win,
-                    )
-                else:
-                    attn = paged_attention_block(
-                        q, kp, vp, cache.block_table, cache.lengths,
-                        k_scales=ksc, v_scales=vsc, window=win,
-                    )  # [B, T, Hq, D]
+            if T == 1 and sharded:
+                attn = paged_attention_sharded(
+                    q[:, 0], kp, vp, cache.block_table, cache.lengths + 1,
+                    kernel_mesh, axis_name="tp", k_scales=ksc, v_scales=vsc,
+                    window=win,
+                )[:, None]
+            elif T == 1:
+                attn = paged_attention(
+                    q[:, 0], kp, vp, cache.block_table, cache.lengths + 1,
+                    k_scales=ksc, v_scales=vsc, window=win,
+                )[:, None]  # [B, 1, Hq, D]
+            elif sharded:
+                attn = paged_attention_block_sharded(
+                    q, kp, vp, cache.block_table, cache.lengths,
+                    kernel_mesh, axis_name="tp", k_scales=ksc, v_scales=vsc,
+                    window=win,
+                )
             else:
-                attns = []
-                for i in range(T):  # per-position fallback
-                    if sharded:
-                        a = paged_attention_sharded(
-                            q[:, i], kp, vp, cache.block_table,
-                            cache.lengths + i + 1, kernel_mesh, axis_name="tp",
-                            k_scales=ksc, v_scales=vsc, window=win,
-                        )
-                    else:
-                        a = paged_attention(
-                            q[:, i], kp, vp, cache.block_table,
-                            cache.lengths + i + 1, k_scales=ksc, v_scales=vsc,
-                            window=win,
-                        )  # [B, Hq, D]
-                    attns.append(a)
-                attn = jnp.stack(attns, axis=1)  # [B, T, Hq, D]
-        with jax.named_scope("attn_out"):
-            o = mm(attn.reshape(B, T, Hq * d), lp["wo"])
-            if "bo" in lp:
-                o = o + lp["bo"]
-        out = (kp, vp, ksc, vsc) if kv_int8 else (kp, vp)
-        if cfg.parallel_block:  # Phi: x + attn(ln x) + mlp(ln x)
-            mlp_out = (
-                _moe(cfg, y, lp, routed_moe, moe_mesh) if cfg.is_moe
-                else _mlp_dense(cfg, y, lp, kernel_mesh)
-            )
-            return x + o + mlp_out, out
-        x = x + o
+                attn = paged_attention_block(
+                    q, kp, vp, cache.block_table, cache.lengths,
+                    k_scales=ksc, v_scales=vsc, window=win,
+                )  # [B, T, Hq, D]
+        x = _block_tail(cfg, lp, x, y, attn, routed_moe, moe_mesh, kernel_mesh)
+        return x, (kp, vp, ksc, vsc)
 
-        y = _norm(x, lp["mlp_norm"], cfg, b=lp.get("mlp_norm_b"))
-        if cfg.is_moe:
-            mlp_out = _moe(cfg, y, lp, routed_moe, moe_mesh)
-        else:
-            mlp_out = _mlp_dense(cfg, y, lp, kernel_mesh)
-        return x + mlp_out, out
-
-    if kv_int8:
-        xs = (
-            params["layers"], cache.k_pages, cache.v_pages,
-            cache.k_scales, cache.v_scales,
-        )
-        with jax.named_scope("pool_carry"):
-            x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(body, x, xs)
-    else:
-        xs = (params["layers"], cache.k_pages, cache.v_pages)
-        # the layer scan slices each layer's pool out of the stack and
-        # writes it back: those operations are the scan's own, and this
-        # is the only name they can be given
-        with jax.named_scope("pool_carry"):
-            x, (new_k, new_v) = jax.lax.scan(body, x, xs)
-        new_ks = new_vs = None
-
+    x, new_cache = _scan_pool(body, x, params, cache)
     x = _norm(x, params["final_norm"], cfg, b=params.get("final_norm_b"))
     out = _logits(x, params, cfg, kernel_mesh=kernel_mesh) if lm_head else x
-    new_cache = cache._replace(
-        k_pages=new_k, v_pages=new_v, lengths=cache.lengths + T,
-        k_scales=new_ks, v_scales=new_vs,
-    )
-    return out, new_cache
+    return out, new_cache._replace(lengths=cache.lengths + T)
 
 
 def forward_paged_merged(
@@ -723,9 +731,8 @@ def forward_paged_merged(
 ) -> tuple[jnp.ndarray, jnp.ndarray, object]:
     """One ragged dispatch serves a prefill chunk AND a decode step.
 
-    The legacy scheduler iteration issues two programs — the chunk body
-    (``forward_paged_block`` through a one-slot view) and the decode step
-    (``forward_paged``) — streaming the weights twice. Here the two run
+    Run apart, the chunk (``forward_chunk``) and the decode step
+    (``forward_paged``) stream the weights twice. Here the two run
     through ONE layer scan: per layer the chunk's [1, C] tokens and the
     decode batch's [B, 1] tokens each keep their own legacy-shaped
     projections/norms/MLP matmuls (bitwise the ops the solo programs run),
@@ -749,7 +756,6 @@ def forward_paged_merged(
     chunk-side lengths are host-tracked (``st["pos"]``), as on the solo
     path.
     """
-    from fei_tpu.engine.paged_cache import write_token_kv
     from fei_tpu.ops.pallas.ragged_paged_attention import (
         query_tile,
         ragged_paged_attention,
@@ -767,10 +773,7 @@ def forward_paged_merged(
     dec_positions = cache.lengths[:, None]
     max_pos = cache.block_table.shape[1] * cache.page_size
     cos, sin = compute_rope_freqs(cfg.rope_dim_, max_pos, cfg.rope_theta)
-    sharded = kernel_mesh is not None and (
-        kernel_mesh.shape.get("tp", 1) > 1
-        or kernel_mesh.shape.get("dp", 1) > 1
-    )
+    sharded = _kernel_sharded(kernel_mesh)
     win = cfg.sliding_window or 0
 
     # per-virtual-row metadata: decode rows then chunk groups
@@ -795,41 +798,22 @@ def forward_paged_merged(
     xc = embed_tokens(params, cfg, chunk_toks, dtype)  # [1, C, h]
     xd = embed_tokens(params, cfg, dec_tokens, dtype)  # [B, 1, h]
 
-    def body(carry, layer_inputs):
+    def body(carry, lp, kp, vp, ksc, vsc):
         xc, xd = carry
-        if kv_int8:
-            lp, kp, vp, ksc, vsc = layer_inputs
-        else:
-            lp, kp, vp = layer_inputs
-            ksc = vsc = None
-        yc = _norm(xc, lp["attn_norm"], cfg, b=lp.get("attn_norm_b"))
-        qc, kc, vc = qkv_proj(lp, yc, Hq, K, d, kernel_mesh=kernel_mesh)
-        qc = _rope(qc, cos, sin, chunk_positions, cfg.rope_dim_)
-        kc = _rope(kc, cos, sin, chunk_positions, cfg.rope_dim_)
-        yd = _norm(xd, lp["attn_norm"], cfg, b=lp.get("attn_norm_b"))
-        qd, kd, vd = qkv_proj(lp, yd, Hq, K, d, kernel_mesh=kernel_mesh)
-        qd = _rope(qd, cos, sin, dec_positions, cfg.rope_dim_)
-        kd = _rope(kd, cos, sin, dec_positions, cfg.rope_dim_)
-
+        yc, qc, kc, vc = _block_head(
+            cfg, lp, xc, chunk_positions, cos, sin, kernel_mesh
+        )
+        yd, qd, kd, vd = _block_head(
+            cfg, lp, xd, dec_positions, cos, sin, kernel_mesh
+        )
         # chunk writes first, then the decode row writes — page-disjoint,
         # so the order is free (mirrors the solo programs' chunk-first)
-        for i in range(C):
-            written = write_token_kv(
-                kp, vp, kc[:, i], vc[:, i], chunk_row, chunk_pos + i,
-                k_scales=ksc, v_scales=vsc,
-            )
-            if kv_int8:
-                kp, vp, ksc, vsc = written
-            else:
-                kp, vp = written
-        written = write_token_kv(
-            kp, vp, kd[:, 0], vd[:, 0], cache.block_table, cache.lengths,
-            k_scales=ksc, v_scales=vsc,
+        kp, vp, ksc, vsc = _write_rows(
+            kp, vp, ksc, vsc, kc, vc, chunk_row, chunk_pos
         )
-        if kv_int8:
-            kp, vp, ksc, vsc = written
-        else:
-            kp, vp = written
+        kp, vp, ksc, vsc = _write_rows(
+            kp, vp, ksc, vsc, kd, vd, cache.block_table, cache.lengths
+        )
 
         # ONE ragged invocation for both sides: decode rows padded to the
         # R-row tile (pad rows compute garbage never read), chunk padded
@@ -853,54 +837,19 @@ def forward_paged_merged(
         dec_attn = av[:B, :1]  # [B, 1, Hq, d]
         chunk_attn = av[B:].reshape(1, Cp, Hq, d)[:, :C]
 
-        out = (kp, vp, ksc, vsc) if kv_int8 else (kp, vp)
-
-        def tail(x, y, attn, T, nB):
-            with jax.named_scope("attn_out"):
-                o = mm(attn.reshape(nB, T, Hq * d), lp["wo"])
-                if "bo" in lp:
-                    o = o + lp["bo"]
-            if cfg.parallel_block:  # Phi: x + attn(ln x) + mlp(ln x)
-                mlp_out = (
-                    _moe(cfg, y, lp, routed_moe, moe_mesh) if cfg.is_moe
-                    else _mlp_dense(cfg, y, lp, kernel_mesh)
-                )
-                return x + o + mlp_out
-            x = x + o
-            y2 = _norm(x, lp["mlp_norm"], cfg, b=lp.get("mlp_norm_b"))
-            if cfg.is_moe:
-                mlp_out = _moe(cfg, y2, lp, routed_moe, moe_mesh)
-            else:
-                mlp_out = _mlp_dense(cfg, y2, lp, kernel_mesh)
-            return x + mlp_out
-
-        xc = tail(xc, yc, chunk_attn, C, 1)
-        xd = tail(xd, yd, dec_attn, 1, B)
-        return (xc, xd), out
-
-    if kv_int8:
-        xs = (
-            params["layers"], cache.k_pages, cache.v_pages,
-            cache.k_scales, cache.v_scales,
+        xc = _block_tail(
+            cfg, lp, xc, yc, chunk_attn, routed_moe, moe_mesh, kernel_mesh
         )
-        with jax.named_scope("pool_carry"):
-            (xc, xd), (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-                body, (xc, xd), xs
-            )
-    else:
-        xs = (params["layers"], cache.k_pages, cache.v_pages)
-        with jax.named_scope("pool_carry"):
-            (xc, xd), (new_k, new_v) = jax.lax.scan(body, (xc, xd), xs)
-        new_ks = new_vs = None
+        xd = _block_tail(
+            cfg, lp, xd, yd, dec_attn, routed_moe, moe_mesh, kernel_mesh
+        )
+        return (xc, xd), (kp, vp, ksc, vsc)
 
+    (xc, xd), new_cache = _scan_pool(body, (xc, xd), params, cache)
     xc = _norm(xc, params["final_norm"], cfg, b=params.get("final_norm_b"))
     xd = _norm(xd, params["final_norm"], cfg, b=params.get("final_norm_b"))
     dec_logits = _logits(xd, params, cfg, kernel_mesh=kernel_mesh)
-    new_cache = cache._replace(
-        k_pages=new_k, v_pages=new_v, lengths=cache.lengths + 1,
-        k_scales=new_ks, v_scales=new_vs,
-    )
-    return xc, dec_logits, new_cache
+    return xc, dec_logits, new_cache._replace(lengths=cache.lengths + 1)
 
 
 def forward_train(

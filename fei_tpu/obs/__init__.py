@@ -1,7 +1,7 @@
 """Observability subsystem: metrics, histograms, request traces, the
-engine flight recorder, the roofline cost model, and Prometheus
-exposition. fei_tpu/utils/metrics.py re-exports the METRICS singleton
-from here so pre-existing call sites are unchanged."""
+engine flight recorder, and Prometheus exposition.
+fei_tpu/utils/metrics.py re-exports the METRICS singleton from here so
+pre-existing call sites are unchanged."""
 
 from fei_tpu.obs.flight import FLIGHT, CompileObserver, FlightRecorder
 from fei_tpu.obs.metrics import (

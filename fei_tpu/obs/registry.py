@@ -68,11 +68,6 @@ METRIC_REGISTRY: dict[str, tuple[str, str]] = {
     "scheduler.decode_slot_steps": ("counter",
                                     "Per-slot decode steps (steps x active "
                                     "slots)."),
-    "scheduler.spec_steps": ("counter", "Speculative decode steps."),
-    "scheduler.spec_accepted": ("counter",
-                                "Speculative tokens accepted."),
-    "scheduler.spec_disabled": ("counter",
-                                "Speculation disabled for a sequence."),
     "scheduler.host_mask_uploads": ("counter",
                                     "Host-side grammar mask uploads."),
     "scheduler.multi_steps": ("counter", "Multi-step decode dispatches."),
@@ -425,7 +420,6 @@ METRIC_REGISTRY: dict[str, tuple[str, str]] = {
                 "program signature)."),
     "decode_chunk": ("span", "One fused free-phase decode chunk (the "
                              "blocking host sync; dispatch is pipelined)."),
-    "spec_step": ("span", "One speculative decode step."),
     "grammar_fused_chunk": ("span", "One fused grammar-constrained chunk."),
     "kv_spill": ("span", "One HBM -> host tier spill (gather + enqueue)."),
     "kv_fetch": ("span", "One host tier -> HBM streamed resume (fetch + "

@@ -5,8 +5,8 @@ fei/core/assistant.py:524-530); these are the greenfield TPU-native hot ops:
 
 - flash_attention: blockwise causal attention for prefill — O(T) memory,
   online softmax, MXU-shaped [block_q, block_k] score tiles.
-- paged_attention: paged-KV decode attention over a block table (legacy
-  fixed-query-block programs, kept behind FEI_TPU_ATTENTION=paged).
+- paged_attention: paged-KV attention over a block table at a fixed query
+  block: the decode step (one query a sequence) and the solo prefill chunk.
 - ragged_paged_attention: mixed prefill+decode rows — per-row
   (limit, q_len) metadata — in ONE invocation over the paged pool.
 

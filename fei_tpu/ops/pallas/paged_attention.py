@@ -96,7 +96,7 @@ def _decode_kernel(
     """Online-softmax paged attention of one (seq, kv-head) program.
 
     ``qt`` is the query-block length: qt consecutive query positions share
-    one kernel invocation (speculative verification / block decode), each
+    one kernel invocation (a prefill chunk), each
     row r attending kv positions < length + r//g — the per-row causal
     limit. qt=1 with length = kv_len+1 is plain single-token decode; the
     pool history is read ONCE for the whole block either way.
@@ -381,8 +381,7 @@ def paged_attention_block(
     v_scales: jnp.ndarray | None = None,
     window: int = 0,
 ) -> jnp.ndarray:
-    """Multi-query paged attention for speculative verification / block
-    decode. The T positions' K/V must already be written into the pool
+    """Multi-query paged attention for a prefill chunk. The T positions' K/V must already be written into the pool
     (positions lengths..lengths+T-1); per-row causal masking keeps query t
     from seeing positions beyond lengths+t. Pool history is read ONCE for
     the whole block — vs T reads for T single-token calls. Returns

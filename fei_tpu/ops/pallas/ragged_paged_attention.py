@@ -2,7 +2,7 @@
 
 The legacy kernels (ops/pallas/paged_attention.py) compile one program per
 query-block length: ``paged_attention`` (qt=1, decode) and
-``paged_attention_block`` (qt=T, chunked prefill / speculative verify). A
+``paged_attention_block`` (qt=T, chunked prefill). A
 serving iteration that interleaves one prefill chunk with one decode scan
 therefore issues two programs — and byte-identical resume has to reason
 about the ~1-bf16-ulp residual between their fusions (docs/ENGINE.md

@@ -1,9 +1,7 @@
 """Loopback OpenAI-compatible ``/chat/completions`` stub server.
 
-One implementation shared by the client-path benchmark (bench.py remote
-suite) and the RemoteProvider tests, so the canned protocol cannot drift
-between what the bench measures and what the tests pin. Also handy for
-driving the agent stack against a fake remote endpoint in demos.
+What the RemoteProvider tests talk to. Also handy for driving the agent
+stack against a fake remote endpoint in demos.
 """
 
 from __future__ import annotations

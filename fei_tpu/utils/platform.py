@@ -1,15 +1,15 @@
 """Process start-up on whatever device JAX came up on.
 
-Two things every entry point (CLI, server, bench.py, __graft_entry__.py,
-tests/conftest.py) needs before its first compile, kept in one place:
+Two things every entry point (CLI, server, benchmarks/run.py,
+__graft_entry__.py, tests/conftest.py) needs before its first compile, kept in one place:
 
 - where compiled programs persist. A cold 7B serving process spends
   minutes compiling; the cache directory is part of the cache key, so it
   must not move between runs. ``JAX_COMPILATION_CACHE_DIR`` (which JAX
   reads by itself) places it from outside; otherwise it is
   ``<checkout>/.jax_cache``, resolved from this package's own location.
-- which device that is, as JAX reports it — /health and every bench line
-  carry it so a process that silently came up on the CPU of a chip
+- which device that is, as JAX reports it — /health and every benchmark
+  result carry it so a process that silently came up on the CPU of a chip
   machine cannot pass for a chip run.
 """
 

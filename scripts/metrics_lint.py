@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Static check: every METRICS call site uses a registered metric name.
 
-Greps fei_tpu/ and bench.py for ``METRICS.incr/gauge/observe/span/timing``
+Greps fei_tpu/ for ``METRICS.incr/gauge/observe/span/timing``
 calls with a literal (or f-string) first argument and fails if the name is
 not declared in fei_tpu/obs/registry.py. F-string ``{...}`` segments
 normalize to ``*`` and match the registry's wildcard families (e.g.
@@ -38,7 +38,7 @@ _FSTRING_FIELD = re.compile(r"\{[^{}]*\}")
 def scan_tree() -> list[tuple[Path, int, str, str]]:
     """(file, line, method, normalized name) for every call site."""
     sites = []
-    files = sorted((REPO / "fei_tpu").rglob("*.py")) + [REPO / "bench.py"]
+    files = sorted((REPO / "fei_tpu").rglob("*.py"))
     for path in files:
         text = path.read_text(encoding="utf-8")
         for m in _CALL.finditer(text):

@@ -208,25 +208,6 @@ class TestJaxLocalProvider:
         assert isinstance(resp.content, str)
         assert resp.usage["completion_tokens"] == 8
 
-    def test_speculation_toggle_is_output_invariant(self, monkeypatch):
-        """The provider's greedy path uses prompt-lookup speculation by
-        default; disabling it must not change a single token."""
-        import jax.numpy as jnp
-
-        from fei_tpu.agent.providers import JaxLocalProvider
-        from fei_tpu.engine import InferenceEngine
-
-        engine = InferenceEngine.from_config(
-            "tiny", dtype=jnp.float32, max_seq_len=512, tokenizer="byte"
-        )
-        provider = JaxLocalProvider(engine=engine, gen_overrides={"ignore_eos": True})
-        msgs = [{"role": "user", "content": "echo echo echo echo"}]
-        outs = {}
-        for flag in ("1", "0"):
-            monkeypatch.setenv("FEI_TPU_SPECULATE", flag)
-            outs[flag] = provider.complete(msgs, max_tokens=12).content
-        assert outs["1"] == outs["0"]
-
     def test_stream_detok_byte_identical(self, monkeypatch):
         """The stream loop detokenizes incrementally (bounded pending
         window + cached context decode) instead of re-decoding the whole
@@ -241,7 +222,6 @@ class TestJaxLocalProvider:
         )
         from fei_tpu.engine import InferenceEngine
 
-        monkeypatch.setenv("FEI_TPU_SPECULATE", "0")
         engine = InferenceEngine.from_config(
             "tiny", dtype=jnp.float32, max_seq_len=512, tokenizer="byte"
         )

@@ -114,31 +114,6 @@ def test_step_programs_carry_every_scope(programs, program, expected):
     assert not missing, f"{program} lost scopes {sorted(missing)}"
 
 
-def test_kv_read_marks_the_explicit_prefix_gather():
-    """The paged kernels read pages through the block table themselves;
-    the one explicit gather of pages that feeds a kernel is the dense
-    staging admission's prefix gather."""
-    from fei_tpu.models.llama import KVCache
-
-    engine = InferenceEngine.from_config(
-        "tiny", paged=True, batch_size=2, max_seq_len=256
-    )
-    try:
-        engine._compiles.wrap = lambda family, key, fn: fn
-        sched = engine.scheduler
-        sched._ensure_pool()
-        bucket = 2 * engine.page_size
-        dense = KVCache.create(engine.cfg, 1, bucket, dtype=engine.dtype)
-        fn = sched._gather_fn(1, bucket)
-        words = _scopes_in(
-            fn, [sched._pool, jnp.zeros((1,), jnp.int32), dense,
-                 jnp.int32(8)], {},
-        )
-        assert "kv_read" in words
-    finally:
-        engine.close()
-
-
 @pytest.mark.parametrize("program", ["multi", "ragged"])
 def test_step_program_trace_names(programs, program):
     """A jitted program is ``jit_<function name>`` on the trace's XLA

@@ -169,85 +169,6 @@ def test_hf_safetensors_checkpoint_loads(tmp_path):
     assert logits.shape == (1, 3, V)
 
 
-class TestPromptLookupSpeculation:
-    """generate_lookahead: greedy prompt-lookup speculation must be
-    token-identical to plain greedy decode (accepted tokens are the
-    model's own argmax by construction)."""
-
-    def _engine(self):
-        return InferenceEngine.from_config(
-            "tiny", dtype=jnp.float32, tokenizer="byte",
-            max_seq_len=256, num_layers=2,
-        )
-
-    def test_matches_greedy_on_repetitive_prompt(self):
-        eng = self._engine()
-        prompt = eng.tokenizer.encode(
-            "def foo(a, b): return a + b\ndef foo(a, b): return a + b\n",
-            add_bos=True,
-        )
-        gen = GenerationConfig(max_new_tokens=24, temperature=0.0, ignore_eos=True)
-        want = eng.generate(prompt, gen).token_ids
-        assert eng.generate_lookahead(prompt, gen).token_ids == want
-
-    def test_spec_path_exercised_and_exact(self, monkeypatch):
-        """Force drafts every step (even bogus ones): the verify/accept
-        machinery must still emit exactly the greedy stream — wrong draft
-        tokens are rejected by the model's own argmax."""
-        from fei_tpu.utils.metrics import METRICS
-
-        eng = self._engine()
-        prompt = eng.tokenizer.encode("spec test", add_bos=True)
-        gen = GenerationConfig(max_new_tokens=20, temperature=0.0, ignore_eos=True)
-        want = eng.generate(prompt, gen).token_ids
-
-        drafts = iter(range(1000))
-
-        def fake_draft(ids, ngram, draft_len):
-            # arbitrary, mostly-wrong proposals of varying lengths
-            k = (next(drafts) % draft_len) + 1
-            return [(ids[-1] + i) % 256 for i in range(k)]
-
-        monkeypatch.setattr(
-            type(eng), "_find_draft", staticmethod(fake_draft)
-        )
-        res = eng.generate_lookahead(prompt, gen)
-        assert res.token_ids == want
-        snap = METRICS.snapshot()
-        assert snap["spans"].get("spec_step", {}).get("count", 0) >= 1
-
-    def test_find_draft(self):
-        find = InferenceEngine._find_draft
-        ids = [1, 2, 3, 9, 9, 1, 2, 3]
-        assert find(ids, 3, 4) == [9, 9, 1, 2]  # follows the earlier match
-        assert find([5, 6, 7], 3, 4) is None  # tail == whole sequence
-        assert find([1, 2], 3, 4) is None  # too short
-
-    def test_matches_greedy_on_nonrepetitive_prompt(self):
-        eng = self._engine()
-        prompt = eng.tokenizer.encode("zq9!k", add_bos=True)
-        gen = GenerationConfig(max_new_tokens=16, temperature=0.0, ignore_eos=True)
-        want = eng.generate(prompt, gen).token_ids
-        assert eng.generate_lookahead(prompt, gen).token_ids == want
-
-    def test_sampled_falls_back(self):
-        eng = self._engine()
-        prompt = eng.tokenizer.encode("hello", add_bos=True)
-        gen = GenerationConfig(max_new_tokens=8, temperature=0.8, seed=3,
-                               ignore_eos=True)
-        assert (
-            eng.generate_lookahead(prompt, gen).token_ids
-            == eng.generate(prompt, gen).token_ids
-        )
-
-    def test_respects_stops(self):
-        eng = self._engine()
-        prompt = eng.tokenizer.encode("ab " * 20, add_bos=True)
-        gen = GenerationConfig(max_new_tokens=32, temperature=0.0)
-        want = eng.generate(prompt, gen).token_ids
-        assert eng.generate_lookahead(prompt, gen).token_ids == want
-
-
 def test_min_p_filters_and_paths_agree():
     """min_p drops tokens below min_p * max-prob; the static sampler, the
     dynamic (scheduler) sampler, and the dense fused scan must agree."""

@@ -4,9 +4,7 @@ restart sequence (docs/FLEET.md).
 
 Everything here runs against fake replicas — scripted answers, no
 engines, no sockets — so each policy decision is a fast deterministic
-pin. The end-to-end proof over real engines is scripts/fleet_smoke.py
-(rehearse/on-chip ``fleet_smoke`` + chaos stages) and the overload
-bench (``bench_fleet``).
+pin. The end-to-end proof over real engines is scripts/fleet_smoke.py.
 """
 
 from __future__ import annotations

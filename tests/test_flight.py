@@ -1,5 +1,5 @@
 """Flight recorder & performance attribution (fei_tpu/obs/flight.py,
-fei_tpu/obs/costmodel.py, docs/OBSERVABILITY.md "Flight recorder").
+docs/OBSERVABILITY.md "Flight recorder").
 
 The claims under test:
 - the ring is BOUNDED: under arbitrary event churn it never exceeds its
@@ -20,9 +20,7 @@ The claims under test:
   warmed engine re-running an identical workload shows ZERO new
   compiles and zero recompiles, while deliberately dropping a jit cache
   reads as a recompile (the silent-20s-shard_map-recompile tripwire);
-- the analytical cost model matches hand-computed arithmetic from the
-  model config (weights-minus-embed stream, K/V row bytes); nothing
-  publishes a per-dispatch roofline or collective gauge any more (a
+- nothing publishes a per-dispatch roofline or collective gauge (a
   kernel's roofline share is a benchmark metric, read from a trace);
 - a KV-pressure preempt → resume round trip leaves rid-tagged
   ``preempt`` / ``resume`` / ``admit`` instants on the timeline,
@@ -39,7 +37,6 @@ import pytest
 
 from fei_tpu.engine.engine import GenerationConfig, InferenceEngine
 from fei_tpu.obs import FLIGHT, CompileObserver, FlightRecorder
-from fei_tpu.obs import costmodel
 from fei_tpu.utils.metrics import METRICS
 
 PROMPT = list(range(11, 29))
@@ -347,6 +344,7 @@ class TestSchedulerFlight:
         # run leaves no such gauge or histogram, the registry declares
         # none, and the call sites are gone
         from fei_tpu.engine import sched_decode
+        from fei_tpu.kv import tier
         from fei_tpu.obs.registry import METRIC_REGISTRY
 
         snap = METRICS.snapshot()
@@ -355,8 +353,8 @@ class TestSchedulerFlight:
                         if k.startswith(("roofline.", "collective."))]
         assert not [k for k in METRIC_REGISTRY
                     if k.startswith(("roofline.", "collective."))]
-        assert not hasattr(costmodel, "account_dispatch")
-        assert not hasattr(costmodel, "account_ragged_dispatch")
+        assert not hasattr(tier, "account_dispatch")
+        assert not hasattr(tier, "account_ragged_dispatch")
         assert not hasattr(sched_decode.DecodeMixin, "_record_collective_time")
 
     def test_timeline_endpoint_end_to_end(self, flown):
@@ -387,63 +385,3 @@ class TestSchedulerFlight:
         assert status == 404
 
 
-# ---------------------------------------------------------------------------
-# analytical cost model vs hand-computed config arithmetic
-
-
-class TestCostModel:
-    def test_kv_row_bytes(self, engine):
-        cfg = engine.cfg
-        # 2 (K and V) × layers × kv_heads × head_dim × fp32
-        expected = 2 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim_ * 4
-        assert costmodel.kv_row_bytes(engine) == expected == 512
-
-    def test_decode_stream_bytes_vs_hand_computed(self, engine):
-        cfg = engine.cfg
-        sb = costmodel.decode_stream_bytes(engine, mean_ctx=32)
-        # hand-computed from the config card: every parameter streams
-        # except the (untied) embedding table, which is a one-row gather
-        hand_weights = (cfg.num_params() - cfg.vocab_size
-                        * cfg.hidden_size) * 4
-        assert sb["weights"] == pytest.approx(hand_weights, rel=0.05)
-        assert sb["kv_read"] == 512 * 32
-        assert sb["kv_write"] == 512
-        assert sb["total"] == sb["weights"] + sb["kv_read"] + sb["kv_write"]
-
-    def test_dispatch_bytes(self, engine):
-        sb = costmodel.decode_stream_bytes(engine, 0)
-        got = costmodel.dispatch_bytes(
-            engine, n_steps=4, total_ctx=100, slots=2
-        )
-        assert got == 4 * (sb["weights"] + 512 * 102)
-        # n_steps floor: a degenerate dispatch still streams once
-        assert costmodel.dispatch_bytes(engine, 0, 0, 1) > 0
-
-    def test_decode_flops_vs_active_params(self, engine):
-        got = costmodel.decode_flops_per_token(engine)
-        assert got == pytest.approx(
-            2 * engine.cfg.num_active_params(), rel=0.10
-        )
-
-    def test_roofline_fraction(self):
-        assert costmodel.roofline_fraction(int(50e9), 1.0, 100.0) == (
-            pytest.approx(0.5)
-        )
-        assert costmodel.roofline_fraction(
-            int(50e9), 1.0, 100.0, n_chips=2
-        ) == pytest.approx(0.25)
-        assert costmodel.roofline_fraction(int(50e9), 0.0, 100.0) == 0.0
-
-    def test_peak_table_is_keyed_by_device_kind(self):
-        assert costmodel.device_peaks() is None  # the CPU is not a row
-        assert costmodel.DEVICE_PEAKS["TPU v5 lite"] == {
-            "hbm_gbps": 819.0, "bf16_tflops": 197.0,
-        }
-
-    def test_chips_for_tag(self):
-        assert costmodel.chips_for_tag(None) == 1
-        assert costmodel.chips_for_tag("ms1") == 1
-        assert costmodel.chips_for_tag("off") == 1
-        assert costmodel.chips_for_tag("tp2") == 2
-        assert costmodel.chips_for_tag("tp2dp2") == 4
-        assert costmodel.chips_for_tag("??junk??") == 1

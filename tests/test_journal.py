@@ -155,8 +155,9 @@ class TestRecover:
         assert sessions[0]["resume_key"] == [50, 51]
 
     def test_greedy_tokens_carry_null_keys(self, tmp_path):
-        """Greedy speculation never advances the PRNG chain, so its tok
-        records carry key=None — the last non-null key must win."""
+        """A tok record may carry key=None (a token delivered without
+        advancing the PRNG chain, as journals written under greedy
+        speculation hold) — the last non-null key must win."""
         d = str(tmp_path)
         recs = [
             {"t": "admit", "rid": "r1", "prompt_ids": [1], "gen": {}},

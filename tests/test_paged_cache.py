@@ -16,8 +16,6 @@ from fei_tpu.engine.paged_cache import (
 from fei_tpu.ops.pallas import paged_attention
 from fei_tpu.utils.errors import EngineError
 
-pytestmark = pytest.mark.slow  # fast lane: -m 'not slow' (docs/TESTING.md)
-
 
 class TestPageAllocator:
     def test_alloc_free_cycle(self):

@@ -1,8 +1,8 @@
-"""Paged-NATIVE chunked prefill: admission writes K/V straight into pool
-pages and attends via the multi-query block kernel through a one-slot pool
-view — no dense staging cache, no completion scatter, no prefix gather.
-Must be token-identical to the dense-staging path it replaces
-(FEI_TPU_PAGED_PREFILL=0), including prefix-cache reuse and int8 pools.
+"""Chunked admission: a long prompt's K/V is written straight into pool
+pages chunk by chunk, attending via the multi-query block kernel through a
+one-slot pool view. Must be token-identical to the dense engine (no pages,
+no chunks, no kernels), including prefix-cache reuse; an int8 pool to the
+same pool filled by the dense one-shot admission.
 """
 
 from __future__ import annotations
@@ -14,53 +14,48 @@ import pytest
 
 from fei_tpu.engine.engine import GenerationConfig, InferenceEngine
 
-pytestmark = pytest.mark.slow  # fast lane: -m 'not slow' (docs/TESTING.md)
-
 PROMPT = [(7 * i + 11) % 200 + 10 for i in range(560)]  # 2 chunks + partial
 GEN = GenerationConfig(max_new_tokens=12, ignore_eos=True)
 
 
-def _engine(monkeypatch, native: bool, **kw):
-    monkeypatch.setenv("FEI_TPU_PAGED_PREFILL", "1" if native else "0")
-    # fp32: the native path's block-kernel accumulation order differs from
-    # the staging path's dense forward at bf16 rounding level, and a
-    # 700-token random tiny model has near-tie argmaxes that flip on
-    # ~1e-2 logit noise. fp32 keeps the comparison about CORRECTNESS
-    # (state machine, page writes, masks), not accumulation order.
+def _engine(**kw):
+    # fp32: the block kernel's accumulation order differs from the dense
+    # forward's at bf16 rounding level, and a 700-token random tiny model
+    # has near-tie argmaxes that flip on ~1e-2 logit noise. fp32 keeps the
+    # comparison about CORRECTNESS (state machine, page writes, masks),
+    # not accumulation order.
     kw.setdefault("dtype", jnp.float32)
     return InferenceEngine.from_config(
         "tiny", paged=True, batch_size=2, max_seq_len=2048, **kw
     )
 
 
-class TestPagedNativePrefill:
-    def test_long_prompt_matches_staging_path(self, monkeypatch):
-        legacy = _engine(monkeypatch, native=False)
-        want = list(legacy.scheduler.stream(PROMPT, GEN))
+@pytest.fixture(scope="module")
+def dense():
+    """Greedy tokens of the dense engine: the reference."""
+    eng = InferenceEngine.from_config(
+        "tiny", paged=False, dtype=jnp.float32, max_seq_len=2048
+    )
+    return lambda prompt, gen: eng.generate(prompt, gen).token_ids
 
-        native = _engine(monkeypatch, native=True)
-        got = list(native.scheduler.stream(PROMPT, GEN))
-        assert got == want
-        # the staging machinery must never have compiled
-        assert native.scheduler._chunk_jit == {}
-        assert native.scheduler._gather_jit == {}
-        assert native.scheduler._pchunk_jit  # and the native path did
 
-    def test_interleaves_with_live_decode(self, monkeypatch):
+class TestChunkedAdmission:
+    def test_long_prompt_matches_dense_engine(self, dense):
+        eng = _engine()
+        assert list(eng.scheduler.stream(PROMPT, GEN)) == dense(PROMPT, GEN)
+        assert eng.scheduler._pchunk_jit  # the prompt was chunked
+
+    def test_interleaves_with_live_decode(self, dense):
         gen_live = GenerationConfig(max_new_tokens=48, ignore_eos=True)
         live_prompt = list(range(40, 72))
-        legacy = _engine(monkeypatch, native=False)
-        want_live = list(legacy.scheduler.stream(live_prompt, gen_live))
-        want_long = list(legacy.scheduler.stream(PROMPT, GEN))
-
-        native = _engine(monkeypatch, native=True)
+        eng = _engine()
         results: dict = {}
         started = threading.Event()
 
         def live():
             out = []
             for i, tok in enumerate(
-                native.scheduler.stream(live_prompt, gen_live)
+                eng.scheduler.stream(live_prompt, gen_live)
             ):
                 out.append(tok)
                 if i == 4:
@@ -69,52 +64,46 @@ class TestPagedNativePrefill:
 
         def long_admit():
             started.wait(timeout=60)
-            results["long"] = list(native.scheduler.stream(PROMPT, GEN))
+            results["long"] = list(eng.scheduler.stream(PROMPT, GEN))
 
         ts = [threading.Thread(target=live), threading.Thread(target=long_admit)]
         [t.start() for t in ts]
         [t.join(timeout=600) for t in ts]
-        # chunks of the native admission interleave with the live stream
-        # and neither corrupts the other
-        assert results["live"] == want_live
-        assert results["long"] == want_long
+        # chunks of the admission interleave with the live stream and
+        # neither corrupts the other
+        assert results["live"] == dense(live_prompt, gen_live)
+        assert results["long"] == dense(PROMPT, GEN)
 
-    def test_prefix_cache_hit_reuses_pages_in_place(self, monkeypatch):
-        legacy = _engine(monkeypatch, native=False, prefix_cache=True)
-        l1 = list(legacy.scheduler.stream(PROMPT, GEN))
-        l2 = list(legacy.scheduler.stream(PROMPT, GEN))  # gathered prefix
+    def test_prefix_cache_hit_reuses_pages_in_place(self, dense):
+        eng = _engine(prefix_cache=True)
+        n1 = list(eng.scheduler.stream(PROMPT, GEN))
+        n2 = list(eng.scheduler.stream(PROMPT, GEN))  # in-place prefix
+        assert n1 == n2 == dense(PROMPT, GEN)
 
-        native = _engine(monkeypatch, native=True, prefix_cache=True)
-        n1 = list(native.scheduler.stream(PROMPT, GEN))
-        n2 = list(native.scheduler.stream(PROMPT, GEN))  # in-place prefix
-        assert n1 == l1
-        assert n2 == l2 == n1
-        # prefix reuse happened without the gather machinery
-        assert native.scheduler._gather_jit == {}
+    def test_int8_pool_parity(self):
+        """An int8 pool rounds K/V, so the dense engine is no reference:
+        the same pool filled in one dense prefill (a chunk wider than the
+        prompt) is."""
+        oneshot = _engine(kv_quant="int8")
+        oneshot.scheduler.prefill_chunk = 1024
+        want = list(oneshot.scheduler.stream(PROMPT, GEN))
+        assert not oneshot.scheduler._pchunk_jit
+        eng = _engine(kv_quant="int8")
+        assert list(eng.scheduler.stream(PROMPT, GEN)) == want
 
-    def test_int8_pool_parity(self, monkeypatch):
-        legacy = _engine(monkeypatch, native=False, kv_quant="int8")
-        want = list(legacy.scheduler.stream(PROMPT, GEN))
-        native = _engine(monkeypatch, native=True, kv_quant="int8")
-        got = list(native.scheduler.stream(PROMPT, GEN))
-        assert got == want
-
-    def test_partial_final_chunk_and_page_misalignment(self, monkeypatch):
+    def test_partial_final_chunk_and_page_misalignment(self, dense):
         # n chosen so the final chunk is partial AND n is not page-aligned
         prompt = PROMPT[:397]
-        legacy = _engine(monkeypatch, native=False)
-        want = list(legacy.scheduler.stream(prompt, GEN))
-        native = _engine(monkeypatch, native=True)
-        got = list(native.scheduler.stream(prompt, GEN))
-        assert got == want
+        eng = _engine()
+        assert list(eng.scheduler.stream(prompt, GEN)) == dense(prompt, GEN)
 
     def test_kernel_failure_is_a_typed_device_error(self, monkeypatch):
-        """A compile-stage failure of the native chunk program (the
-        realistic Mosaic-rejection case) fails the request with the typed
+        """A compile-stage failure of the chunk program (the realistic
+        Mosaic-rejection case) fails the request with the typed
         DeviceError: nothing switches the scheduler to another path."""
         from fei_tpu.utils.errors import DeviceError
 
-        native = _engine(monkeypatch, native=True)
+        eng = _engine()
 
         def boom(C, final):
             def fn(*a, **k):
@@ -122,14 +111,11 @@ class TestPagedNativePrefill:
 
             return fn
 
-        monkeypatch.setattr(native.scheduler, "_paged_chunk_fn", boom)
+        monkeypatch.setattr(eng.scheduler, "_paged_chunk_fn", boom)
         with pytest.raises(DeviceError, match="Mosaic said no"):
-            list(native.scheduler.stream(PROMPT, GEN))
-        assert native.scheduler.paged_native_prefill is True
+            list(eng.scheduler.stream(PROMPT, GEN))
 
-    def test_near_capacity_prompt_with_prefix_pads_hit_null_page(
-        self, monkeypatch
-    ):
+    def test_near_capacity_prompt_with_prefix_pads_hit_null_page(self, dense):
         """The clamp hazard: a prefix-hit admission near max_seq_len whose
         final chunk's pad positions run past the table capacity. The pads
         must land in the null page, not clamp onto the last real page and
@@ -138,22 +124,17 @@ class TestPagedNativePrefill:
         # table; prefix from run 1 makes run 2's chunk starts unaligned
         prompt = [(3 * i + 5) % 150 + 30 for i in range(2030)]
         gen = GenerationConfig(max_new_tokens=12, ignore_eos=True)
-        legacy = _engine(monkeypatch, native=False, prefix_cache=True)
-        l1 = list(legacy.scheduler.stream(prompt, gen))
-        l2 = list(legacy.scheduler.stream(prompt, gen))
-
-        native = _engine(monkeypatch, native=True, prefix_cache=True)
-        n1 = list(native.scheduler.stream(prompt, gen))
-        n2 = list(native.scheduler.stream(prompt, gen))  # prefix-hit run
-        assert n1 == l1
-        assert n2 == l2
+        eng = _engine(prefix_cache=True)
+        n1 = list(eng.scheduler.stream(prompt, gen))
+        n2 = list(eng.scheduler.stream(prompt, gen))  # prefix-hit run
+        assert n1 == n2 == dense(prompt, gen)
 
 
 class TestSchedulerLifecycle:
-    def test_idle_park_and_restart(self, monkeypatch):
+    def test_idle_park_and_restart(self):
         import time
 
-        eng = _engine(monkeypatch, native=True)
+        eng = _engine()
         gen = GenerationConfig(max_new_tokens=4, ignore_eos=True)
         a = list(eng.scheduler.stream(list(range(20, 40)), gen))
         sched = eng.scheduler
@@ -170,8 +151,8 @@ class TestSchedulerLifecycle:
         b = list(eng.scheduler.stream(list(range(20, 40)), gen))
         assert b == a
 
-    def test_close_fails_inflight_and_restarts(self, monkeypatch):
-        eng = _engine(monkeypatch, native=True)
+    def test_close_fails_inflight_and_restarts(self):
+        eng = _engine()
         gen = GenerationConfig(max_new_tokens=4, ignore_eos=True)
         list(eng.scheduler.stream(list(range(20, 40)), gen))
         eng.close()

@@ -231,7 +231,7 @@ class TestPagedAttention:
 
 
 class TestPagedBlockAttention:
-    """Multi-query block kernel (speculative verification): per-row causal
+    """Multi-query block kernel (a prefill chunk): per-row causal
     limits over the paged pool, history read once for the whole block."""
 
     def _setup(self, key, B, T, H, K, D, page_size, pps):

@@ -7,9 +7,10 @@ The claims under test (docs/ENGINE.md "Decode dispatch model"):
   kernel computes — decode rows (q_len=1) against ``paged_attention``,
   chunk row-groups against ``paged_attention_block`` — including int8
   scale folding and the sliding-window clamp;
-- engine: FEI_TPU_ATTENTION=ragged is token-identical to the legacy
-  two-program shape, greedy AND seeded, under admission/decode overlap,
-  solo prefill, dense short-prompt admission, and preempt->resume churn;
+- engine: a chunk that rides a decode scan is token-identical to the
+  same chunk run as its own program, greedy AND seeded, under
+  admission/decode overlap, solo prefill, dense short-prompt admission,
+  and preempt->resume churn;
 - accounting: merged chunks record as ``dispatch.step`` extras, NOT as
   ``dispatch.prefill_chunk`` — the chunk-record count dropping under
   overlap is the measured dispatch reduction, and the flight-recorder
@@ -382,21 +383,12 @@ SEED_LONG = GenerationConfig(
 )
 
 
-def _engine(attention: str, **kw):
-    """Tiny paged engine with FEI_TPU_ATTENTION pinned around from_config
-    (the scheduler reads it at construction)."""
-    old = os.environ.get("FEI_TPU_ATTENTION")
-    os.environ["FEI_TPU_ATTENTION"] = attention
-    try:
-        eng = InferenceEngine.from_config(
-            "tiny", paged=True, batch_size=kw.pop("batch_size", 2),
-            max_seq_len=kw.pop("max_seq_len", 2048), **kw,
-        )
-    finally:
-        if old is None:
-            os.environ.pop("FEI_TPU_ATTENTION", None)
-        else:
-            os.environ["FEI_TPU_ATTENTION"] = old
+def _engine(**kw):
+    """Tiny paged engine whose admissions are chunked."""
+    eng = InferenceEngine.from_config(
+        "tiny", paged=True, batch_size=kw.pop("batch_size", 2),
+        max_seq_len=kw.pop("max_seq_len", 2048), **kw,
+    )
     eng.scheduler.prefill_chunk = 16  # force chunked paged admission
     return eng
 
@@ -431,36 +423,37 @@ def _overlap(eng, gen_live, gen_long):
 
 
 @pytest.fixture(scope="module")
-def legacy_refs():
-    """Reference streams on FEI_TPU_ATTENTION=paged, sequential (the
-    legacy engine's tokens are interleaving-independent — pinned by
-    test_paged_native_prefill), plus the solo chunk count for LONG."""
-    eng = _engine("paged")
-    refs = {
-        "live": list(eng.scheduler.stream(LIVE, GEN_LIVE)),
-        "short": list(eng.scheduler.stream(SHORT, GEN_LIVE)),
-        "seed_live": list(eng.scheduler.stream(LIVE, SEED_LIVE)),
-        "seed_long": list(eng.scheduler.stream(LONG, SEED_LONG)),
-    }
-    FLIGHT.reset()
-    refs["long"] = list(eng.scheduler.stream(LONG, GEN_LONG))
-    refs["long_chunks"] = FLIGHT.counts()["dispatch.prefill_chunk"]
-    eng.scheduler.close()
-    assert refs["long_chunks"] == -(-len(LONG) // 16)
-    return refs
-
-
-@pytest.fixture(scope="module")
 def ragged_eng():
-    eng = _engine("ragged")
-    assert eng.scheduler.ragged_attention
+    eng = _engine()
     yield eng
     eng.scheduler.close()
 
 
+@pytest.fixture(scope="module")
+def solo_refs(ragged_eng):
+    """Reference streams, one request at a time: no slot is decoding, so
+    every chunk runs as its own program and nothing merges (tokens are
+    interleaving-independent — pinned by test_paged_native_prefill),
+    plus the solo chunk count for LONG."""
+    sched = ragged_eng.scheduler
+    d0 = _counter("engine.ragged_dispatches")
+    refs = {
+        "live": list(sched.stream(LIVE, GEN_LIVE)),
+        "short": list(sched.stream(SHORT, GEN_LIVE)),
+        "seed_live": list(sched.stream(LIVE, SEED_LIVE)),
+        "seed_long": list(sched.stream(LONG, SEED_LONG)),
+    }
+    FLIGHT.reset()
+    refs["long"] = list(sched.stream(LONG, GEN_LONG))
+    refs["long_chunks"] = FLIGHT.counts()["dispatch.prefill_chunk"]
+    assert refs["long_chunks"] == -(-len(LONG) // 16)
+    assert _counter("engine.ragged_dispatches") == d0
+    return refs
+
+
 class TestMergedDispatch:
     def test_overlap_greedy_identity_and_dispatch_counts(
-        self, legacy_refs, ragged_eng
+        self, solo_refs, ragged_eng
     ):
         FLIGHT.reset()
         c0 = {
@@ -471,8 +464,8 @@ class TestMergedDispatch:
             )
         }
         live, long_, seq = _overlap(ragged_eng, GEN_LIVE, GEN_LONG)
-        assert live == legacy_refs["live"], "live stream diverged"
-        assert long_ == legacy_refs["long"], "admitted stream diverged"
+        assert live == solo_refs["live"], "live stream diverged"
+        assert long_ == solo_refs["long"], "admitted stream diverged"
 
         recs = FLIGHT.records()
         merged = [
@@ -489,10 +482,10 @@ class TestMergedDispatch:
         ]
         # the admission advanced one chunk per loop iteration either way…
         assert (
-            len(long_merged) + len(long_solo) == legacy_refs["long_chunks"]
+            len(long_merged) + len(long_solo) == solo_refs["long_chunks"]
         ), "a chunk was dropped or double-dispatched"
         # …and at least one chunk rode a decode scan instead of its own
-        # program: the dispatch reduction, per-chunk, vs the legacy count
+        # program: the dispatch reduction, per-chunk, vs the solo count
         assert long_merged, "overlap never produced a merged dispatch"
         assert _counter("engine.ragged_dispatches") - c0[
             "engine.ragged_dispatches"
@@ -550,33 +543,33 @@ class TestMergedDispatch:
             )
         assert all("attn_pages" not in t for t in steps if t.get("ragged"))
 
-    def test_overlap_seeded_identity(self, legacy_refs, ragged_eng):
+    def test_overlap_seeded_identity(self, solo_refs, ragged_eng):
         live, long_, _ = _overlap(ragged_eng, SEED_LIVE, SEED_LONG)
-        assert live == legacy_refs["seed_live"], "seeded live diverged"
-        assert long_ == legacy_refs["seed_long"], "seeded admitted diverged"
+        assert live == solo_refs["seed_live"], "seeded live diverged"
+        assert long_ == solo_refs["seed_long"], "seeded admitted diverged"
 
-    def test_prefill_only_flushes_solo(self, legacy_refs, ragged_eng):
-        """No armed decode slot -> chunks never stash; the solo path is
-        the legacy program and tokens match it exactly."""
+    def test_prefill_only_flushes_solo(self, solo_refs, ragged_eng):
+        """No armed decode slot -> chunks never stash, and the stream is
+        the same every time it runs."""
         d0 = _counter("engine.ragged_dispatches")
         got = list(ragged_eng.scheduler.stream(LONG, GEN_LONG))
-        assert got == legacy_refs["long"]
+        assert got == solo_refs["long"]
         assert _counter("engine.ragged_dispatches") == d0
 
-    def test_dense_short_prompt_untouched(self, legacy_refs, ragged_eng):
+    def test_dense_short_prompt_untouched(self, solo_refs, ragged_eng):
         """Decode-only shape: a prompt under the chunk takes the direct
-        dense admission; the ragged flag changes nothing there."""
+        dense admission; a merged dispatch before it changes nothing."""
         got = list(ragged_eng.scheduler.stream(SHORT, GEN_LIVE))
-        assert got == legacy_refs["short"]
+        assert got == solo_refs["short"]
 
-    def test_single_slot_engine_never_merges(self, legacy_refs):
+    def test_single_slot_engine_never_merges(self, solo_refs):
         """batch_size=1: there is never an armed slot to merge with, so
         every chunk dispatches solo and tokens still match."""
-        eng = _engine("ragged", batch_size=1)
+        eng = _engine(batch_size=1)
         try:
             d0 = _counter("engine.ragged_dispatches")
             got = list(eng.scheduler.stream(LONG, GEN_LONG))
-            assert got == legacy_refs["long"]
+            assert got == solo_refs["long"]
             assert _counter("engine.ragged_dispatches") == d0
         finally:
             eng.scheduler.close()
@@ -587,7 +580,7 @@ class TestMergedDispatch:
         DeviceError: the merged path is never disarmed behind the user."""
         from fei_tpu.utils.errors import DeviceError
 
-        eng = _engine("ragged")
+        eng = _engine()
         try:
             def boom(n, C, final, grammared):
                 def fn(*a, **k):
@@ -610,39 +603,25 @@ class TestMergedDispatch:
                 list(eng.scheduler.stream(LONG, GEN_LONG))
             t.join(timeout=120)
             assert not t.is_alive() and seen, "the live stream did not fail"
-            assert eng.scheduler.ragged_attention is True
         finally:
             eng.scheduler.close()
-
-    def test_env_validated(self):
-        from fei_tpu.utils.errors import EngineError
-
-        os.environ["FEI_TPU_ATTENTION"] = "meteor"
-        try:
-            with pytest.raises(EngineError):
-                InferenceEngine.from_config("tiny", paged=True, batch_size=2)
-        finally:
-            os.environ.pop("FEI_TPU_ATTENTION", None)
 
 
 @pytest.mark.slow  # tier-1 carries the fast pins
 class TestRaggedSlow:
     def test_tp2_overlap_identity(self):
         """The mesh composition claim: the merged program all-gathers kv
-        heads inside shard_map exactly like the legacy kernel, so tp2
-        tokens match the legacy tp2 engine under overlap."""
+        heads inside shard_map exactly like the solo kernels, so tp2
+        tokens under overlap match the same engine's sequential streams."""
         if len(jax.devices()) < 2:
             pytest.skip("needs 2 devices")
         old = os.environ.get("FEI_TPU_MESH")
         os.environ["FEI_TPU_MESH"] = "tp2"
         try:
-            legacy = _engine("paged")
-            want_live = list(legacy.scheduler.stream(LIVE, GEN_LIVE))
-            want_long = list(legacy.scheduler.stream(LONG, GEN_LONG))
-            legacy.scheduler.close()
-
-            eng = _engine("ragged")
+            eng = _engine()
             try:
+                want_live = list(eng.scheduler.stream(LIVE, GEN_LIVE))
+                want_long = list(eng.scheduler.stream(LONG, GEN_LONG))
                 live, long_, seq = _overlap(eng, GEN_LIVE, GEN_LONG)
             finally:
                 eng.scheduler.close()
@@ -661,15 +640,13 @@ class TestRaggedSlow:
         prompts = [list(range(11 + i, 29 + i)) for i in range(4)]
         gen = GenerationConfig(max_new_tokens=24, ignore_eos=True)
 
-        roomy = _engine(
-            "ragged", page_size=4, num_pages=64, prefix_cache=True
-        )
+        roomy = _engine(page_size=4, num_pages=64, prefix_cache=True)
         roomy.scheduler.prefill_chunk = 8
         refs = [list(roomy.scheduler.stream(p, gen)) for p in prompts]
         roomy.scheduler.close()
 
         p0 = _counter("scheduler.preemptions")
-        eng = _engine("ragged", page_size=4, num_pages=14, prefix_cache=True)
+        eng = _engine(page_size=4, num_pages=14, prefix_cache=True)
         eng.scheduler.prefill_chunk = 8
         try:
             seqs = [eng.scheduler.submit(p, gen) for p in prompts]

@@ -1,7 +1,6 @@
 """RemoteProvider without litellm: the dependency-free OpenAI-compatible
-urllib client against the shared loopback stub (BASELINE config #1's client
-path — the same stub the bench's remote suite measures, so the protocols
-cannot drift). Reference transport: fei/core/assistant.py:524-530."""
+urllib client against the loopback stub (BASELINE config #1's client
+path). Reference transport: fei/core/assistant.py:524-530."""
 
 from __future__ import annotations
 

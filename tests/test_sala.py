@@ -367,10 +367,6 @@ def test_what_cannot_carry_the_state_refuses_the_model(params, monkeypatch):
     with pytest.raises(EngineError, match="KV tier"):
         _engine(params, monkeypatch)
     monkeypatch.delenv("FEI_TPU_KV_TIER")
-    monkeypatch.setenv("FEI_TPU_SPECULATE", "1")
-    with pytest.raises(EngineError, match="FEI_TPU_SPECULATE"):
-        _engine(params, monkeypatch)
-    monkeypatch.delenv("FEI_TPU_SPECULATE")
     eng = _engine(params, monkeypatch)
     try:
         with pytest.raises(EngineError, match="migration"):

@@ -25,8 +25,6 @@ from fei_tpu.engine.grammar import (
 )
 from fei_tpu.utils.metrics import METRICS
 
-pytestmark = pytest.mark.slow  # fast lane: -m 'not slow' (docs/TESTING.md)
-
 SCHEMA = {
     "type": "object",
     "properties": {"path": {"type": "string"}},
