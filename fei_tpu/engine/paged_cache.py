@@ -49,7 +49,14 @@ class PagedKVCache(NamedTuple):
     int8 with per-slot (per-token, per-head) fp32 scales — KV bytes halve,
     so a pool holds ~2x the conversation tokens (the serving bottleneck for
     the agent task loop). Scales are laid out [L, P, K, 1, ps] so the
-    kernel's scale tile is lane-oriented like its score tile."""
+    kernel's scale tile is lane-oriented like its score tile.
+
+    [L, P, ...] is the outward layout, what everything outside a step
+    program sees. A step program's layer loop views the pools flat as
+    [L*P, ...] (leading dimensions merged: no bytes move), carries them
+    and writes layer l's rows in place at ``l * P + page``
+    (``write_token_kv``'s ``base``; models/llama.py::_scan_pool,
+    models/sala.py::_bufs_of), then views them back."""
 
     k_pages: jnp.ndarray  # [L, P, K, ps, D] (bf16, or int8 when quantized)
     v_pages: jnp.ndarray  # [L, P, K, ps, D]
@@ -486,16 +493,23 @@ def build_block_table(
 
 @jax.named_scope("kv_write")
 def write_token_kv(
-    k_pages: jnp.ndarray,  # [P, K, ps, D] one layer's pool
+    k_pages: jnp.ndarray,  # [N, K, ps, D]: one layer's pool, or all flat
     v_pages: jnp.ndarray,
     k_new: jnp.ndarray,  # [B, K, D] this step's keys
     v_new: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, max_pages]
     lengths: jnp.ndarray,  # [B] position being written
-    k_scales: jnp.ndarray | None = None,  # [P, K, 1, ps] (int8 pools)
+    k_scales: jnp.ndarray | None = None,  # [N, K, 1, ps] (int8 pools)
     v_scales: jnp.ndarray | None = None,
+    base=0,
 ):
     """Scatter one decode token's K/V into each sequence's current page.
+
+    The pool may be one layer's ([P, K, ps, D], ``base`` 0) or every
+    layer's viewed flat ([L*P, K, ps, D]): ``base`` is then the layer's
+    first row, ``l * P``, and the table's page ids count from it. The row
+    goes to ``(base + page, 0, offset, 0)`` where it lies; nothing else of
+    the pool is read or written.
 
     Returns (k_pages, v_pages, k_scales, v_scales), the scales None for a
     bf16 pool. For an int8 pool the new token quantizes per (sequence,
@@ -515,9 +529,10 @@ def write_token_kv(
     for b in range(B):  # B is static and small (decode batch)
         # a position past the table's capacity (pad tokens of a final
         # paged-prefill chunk near max_seq_len) must land in the reserved
-        # null page 0 — the gather would otherwise CLAMP to the last
-        # column, a real page, and overwrite live K/V
-        page = jnp.where(
+        # null page 0 of its own layer (``base + 0``, which nothing
+        # reads) — the gather would otherwise CLAMP to the last column, a
+        # real page, and overwrite live K/V
+        page = base + jnp.where(
             page_slot[b] < width,
             block_table[b, jnp.minimum(page_slot[b], width - 1)],
             0,

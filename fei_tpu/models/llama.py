@@ -546,36 +546,51 @@ def _kernel_sharded(kernel_mesh) -> bool:
     )
 
 
-def _write_rows(kp, vp, ksc, vsc, k, v, block_table, start):
+def _write_rows(kp, vp, ksc, vsc, k, v, block_table, start, base=0):
     """Write the T rows of k/v [B,T,K,D] at positions ``start`` [B] onward
-    into one layer's pages through ``block_table``. ksc/vsc: the pages'
-    scales (an int8 pool) or None. Returns the four, updated."""
+    into the pages of the layer whose first row of the flat pool is
+    ``base``, through ``block_table``. ksc/vsc: the pages' scales (an int8
+    pool) or None. Returns the four, updated."""
     from fei_tpu.engine.paged_cache import write_token_kv
 
     for i in range(k.shape[1]):
         kp, vp, ksc, vsc = write_token_kv(
             kp, vp, k[:, i], v[:, i], block_table, start + i,
-            k_scales=ksc, v_scales=vsc,
+            k_scales=ksc, v_scales=vsc, base=base,
         )
     return kp, vp, ksc, vsc
 
 
 def _scan_pool(body, carry, params: dict, cache):
-    """The layer scan of a paged step: ``body(carry, lp, kp, vp, ksc, vsc)
-    -> (carry, (kp, vp, ksc, vsc))`` runs once a layer on that layer's
-    pages (scales None for a bf16 pool). The pool rides as the scan's xs
-    and comes back as its ys. Returns (carry, cache with the new pages)."""
-    xs = (
-        params["layers"], cache.k_pages, cache.v_pages,
-        cache.k_scales, cache.v_scales,
+    """The layer scan of a paged step: ``body(carry, lp, base, kp, vp, ksc,
+    vsc) -> (carry, (kp, vp, ksc, vsc))`` runs once a layer. The pool
+    rides the scan's carry beside the activations, every layer's pages
+    viewed flat as [L*P, K, ps, D] (scales [L*P, K, 1, ps], None for a
+    bf16 pool): layer l's pages are rows ``base = l * P`` onward, a body
+    writes its rows where they lie and hands its kernels ``base + table``.
+    The carry and not xs/ys: a scan may not write its xs, so a pool handed
+    through as xs is sliced out a layer, rewritten and copied back whole.
+    Returns (carry, cache with the new pages in its outward layout [L, P,
+    K, ps, D])."""
+    L, P = cache.k_pages.shape[:2]
+    pools = tuple(
+        None if a is None else a.reshape(L * P, *a.shape[2:])
+        for a in (cache.k_pages, cache.v_pages, cache.k_scales, cache.v_scales)
     )
-    # the layer scan slices each layer's pool out of the stack and writes
-    # it back: those operations are the scan's own, and this is the only
-    # name they can be given
+
+    def layer(val, x):
+        carry, pools = val
+        lp, l = x
+        return body(carry, lp, l * P, *pools), None
+
     with jax.named_scope("pool_carry"):
-        carry, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-            lambda carry, x: body(carry, *x), carry, xs
+        (carry, pools), _ = jax.lax.scan(
+            layer, (carry, pools),
+            (params["layers"], jnp.arange(L, dtype=jnp.int32)),
         )
+    new_k, new_v, new_ks, new_vs = (
+        None if a is None else a.reshape(L, P, *a.shape[1:]) for a in pools
+    )
     return carry, cache._replace(
         k_pages=new_k, v_pages=new_v, k_scales=new_ks, v_scales=new_vs
     )
@@ -675,37 +690,40 @@ def _forward_paged_block(
     dtype = model_dtype(params) if kv_int8 else cache.k_pages.dtype
     x = embed_tokens(params, cfg, tokens, dtype)  # [B, T, h]
 
-    def body(x, lp, kp, vp, ksc, vsc):
+    def body(x, lp, base, kp, vp, ksc, vsc):
         y, q, k, v = _block_head(cfg, lp, x, positions, cos, sin, kernel_mesh)
         # write all T positions' K/V (causality is the kernel's per-row
         # mask, so writing ahead of attending is safe)
         kp, vp, ksc, vsc = _write_rows(
-            kp, vp, ksc, vsc, k, v, cache.block_table, cache.lengths
+            kp, vp, ksc, vsc, k, v, cache.block_table, cache.lengths, base
         )
+        # the kernels index the pool's leading axis by the table's entries
+        # and nothing else: this layer's pages are the table's, from base
+        bt = base + cache.block_table
         # the scope sits OUTSIDE the kernels' jitted wrappers: the
         # innermost name on a Pallas call's path is the name its
         # operation gets in a device trace, and that stays the kernel's
         with jax.named_scope("attention"):
             if T == 1 and sharded:
                 attn = paged_attention_sharded(
-                    q[:, 0], kp, vp, cache.block_table, cache.lengths + 1,
+                    q[:, 0], kp, vp, bt, cache.lengths + 1,
                     kernel_mesh, axis_name="tp", k_scales=ksc, v_scales=vsc,
                     window=win,
                 )[:, None]
             elif T == 1:
                 attn = paged_attention(
-                    q[:, 0], kp, vp, cache.block_table, cache.lengths + 1,
+                    q[:, 0], kp, vp, bt, cache.lengths + 1,
                     k_scales=ksc, v_scales=vsc, window=win,
                 )[:, None]  # [B, 1, Hq, D]
             elif sharded:
                 attn = paged_attention_block_sharded(
-                    q, kp, vp, cache.block_table, cache.lengths,
+                    q, kp, vp, bt, cache.lengths,
                     kernel_mesh, axis_name="tp", k_scales=ksc, v_scales=vsc,
                     window=win,
                 )
             else:
                 attn = paged_attention_block(
-                    q, kp, vp, cache.block_table, cache.lengths,
+                    q, kp, vp, bt, cache.lengths,
                     k_scales=ksc, v_scales=vsc, window=win,
                 )  # [B, T, Hq, D]
         x = _block_tail(cfg, lp, x, y, attn, routed_moe, moe_mesh, kernel_mesh)
@@ -798,7 +816,7 @@ def forward_paged_merged(
     xc = embed_tokens(params, cfg, chunk_toks, dtype)  # [1, C, h]
     xd = embed_tokens(params, cfg, dec_tokens, dtype)  # [B, 1, h]
 
-    def body(carry, lp, kp, vp, ksc, vsc):
+    def body(carry, lp, base, kp, vp, ksc, vsc):
         xc, xd = carry
         yc, qc, kc, vc = _block_head(
             cfg, lp, xc, chunk_positions, cos, sin, kernel_mesh
@@ -809,10 +827,10 @@ def forward_paged_merged(
         # chunk writes first, then the decode row writes — page-disjoint,
         # so the order is free (mirrors the solo programs' chunk-first)
         kp, vp, ksc, vsc = _write_rows(
-            kp, vp, ksc, vsc, kc, vc, chunk_row, chunk_pos
+            kp, vp, ksc, vsc, kc, vc, chunk_row, chunk_pos, base
         )
         kp, vp, ksc, vsc = _write_rows(
-            kp, vp, ksc, vsc, kd, vd, cache.block_table, cache.lengths
+            kp, vp, ksc, vsc, kd, vd, cache.block_table, cache.lengths, base
         )
 
         # ONE ragged invocation for both sides: decode rows padded to the
@@ -826,12 +844,12 @@ def forward_paged_merged(
         with jax.named_scope("attention"):
             if sharded:
                 av = ragged_paged_attention_sharded(
-                    qv, kp, vp, btv, limits, q_lens, modes, kernel_mesh,
+                    qv, kp, vp, base + btv, limits, q_lens, modes, kernel_mesh,
                     axis_name="tp", k_scales=ksc, v_scales=vsc, window=win,
                 )
             else:
                 av = ragged_paged_attention(
-                    qv, kp, vp, btv, limits, q_lens, modes,
+                    qv, kp, vp, base + btv, limits, q_lens, modes,
                     k_scales=ksc, v_scales=vsc, window=win,
                 )
         dec_attn = av[:B, :1]  # [B, 1, Hq, d]

@@ -12,6 +12,9 @@ given this file loads the TPU's library (keep such tests in this file).
 
 from __future__ import annotations
 
+import re
+import types
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -164,3 +167,129 @@ def test_selected_page_kernel_compiles_for_v5e_at_g16(one_chip):
     assert "sparse_paged_attention" in compiled.as_text()
     # a program a (slot, kv head) row of the pool's one-head view
     assert _pallas_grid(call, *args) == (B * K, 1)
+
+
+# -- the step programs' pool traffic ------------------------------------------
+#
+# The Llama family's layer scan carries the page pool flat and writes a
+# layer's rows where they lie (models/llama.py::_scan_pool). What that buys
+# is a property of the compiled step programs, so it is asserted on them:
+# compiled for the described v5e at the sessions cell's shapes, abstract
+# arguments only (no weight is built, nothing runs).
+
+SLOTS, POSITIONS, PAGE, CHUNK = 4, 8192, 64, 256
+_POOL_OPS = "copy|copy-start|dynamic-slice|dynamic-update-slice"
+# %name = <result shape, a tuple for copy-start> opcode(%operand, ...
+_DEF = re.compile(r"%?([\w.-]+) = (.*?) ([\w-]+)\((.*)")
+_DIMS = re.compile(r"\w+\[([\d,]*)\]")
+
+
+def _step_program(which: str, kv_quant, chip, monkeypatch):
+    """``multi(n_steps=8)`` or ``ragged(n_steps=8, C=256, final=True)`` of
+    mistral-7b with int8 weights as the scheduler builds them, compiled
+    for ``chip``. Returns (compiled, the pool's dimensions L, P, K, ps, D)."""
+    from fei_tpu.engine.paged_cache import PagedKVCache
+    from fei_tpu.engine.sched_decode import DecodeMixin
+    from fei_tpu.models.configs import get_model_config
+    from fei_tpu.models.llama import init_params
+
+    cfg = get_model_config("mistral-7b")
+    # the kernels pick interpret mode by the default backend, which here
+    # is the CPU: the program is compiled for the chip, so say so
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+            tree,
+        )
+
+    params = on_chip(jax.eval_shape(
+        lambda k: init_params(cfg, k, quantize="int8"), jax.random.PRNGKey(0)
+    ))
+    width = POSITIONS // PAGE
+    pool = on_chip(jax.eval_shape(lambda: PagedKVCache.create(
+        cfg, SLOTS * width + 1, SLOTS, width, page_size=PAGE,
+        kv_quant=kv_quant,
+    )))
+
+    def S(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    sched = types.SimpleNamespace(
+        engine=types.SimpleNamespace(
+            cfg=cfg, mesh=None,
+            _compiles=types.SimpleNamespace(wrap=lambda fam, key, fn: fn),
+        ),
+        _step_jit={}, _hybrid=False,
+    )
+    sampling = [
+        S((SLOTS, 1), jnp.int32), S((SLOTS, 2), jnp.uint32),
+        S((SLOTS,), jnp.float32), S((SLOTS,), jnp.int32),
+        S((SLOTS,), jnp.float32), S((SLOTS,), jnp.float32),
+    ]
+    if which == "multi":
+        fn = DecodeMixin._multi_fn(sched, 8, False)
+        args = [params, pool, *sampling]
+    else:
+        fn = DecodeMixin._ragged_fn(sched, 8, CHUNK, True, False)
+        args = [
+            params, pool, S((1, CHUNK), jnp.int32), S((1, width), jnp.int32),
+            S((1,), jnp.int32), S((), jnp.int32), *sampling,
+        ]
+    return fn.lower(*args).compile(), pool.k_pages.shape
+
+
+def pool_traffic(hlo: str, dims) -> list[str]:
+    """The instructions of an optimized HLO text that copy, slice or write
+    back something of the shape of the pool ([L*P, ...], [L, P, ...]) or
+    of one layer's pool ([P, ...]), pages or scales. Not among them: a row
+    write's update in place (its update operand is one row a kv head), and
+    the relayout of an int8 pool's scales at the program's edge (copies in
+    the entry computation, once a dispatch and as on the xs/ys scan: the
+    steps want [.., K, 1, ps] in another layout than arguments arrive in)."""
+    L, P, K, ps, D = dims
+    leads = ((L * P,), (L, P), (P,))
+    pages = {",".join(map(str, lead + (K, ps, D))) for lead in leads}
+    scales = {",".join(map(str, lead + (K, 1, ps))) for lead in leads}
+    rows = {f"1,{K},1,{D}", f"1,{K},1,1"}
+    defs, entry = [], False
+    for line in hlo.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            entry = line.startswith("ENTRY")
+        m = _DEF.search(line)
+        if m:
+            defs.append((entry, *m.groups()))
+    dims_of = {name: _DIMS.findall(shape)[:1] for _, name, shape, _, _ in defs}
+    found = []
+    for entry, name, shape, op, args in defs:
+        moved = set(_DIMS.findall(shape))
+        if not re.fullmatch(_POOL_OPS, op) or not moved & (pages | scales):
+            continue
+        if op == "dynamic-update-slice":
+            operands = re.findall(r"%([\w.-]+)", args)
+            update = operands[1] if len(operands) > 1 else None
+            if set(dims_of.get(update, [])) & rows:
+                continue
+        if op.startswith("copy") and entry and not moved & pages:
+            continue
+        found.append(f"{name} = {shape} {op}({args}"[:200])
+    return found
+
+
+@pytest.mark.parametrize("which,kv_quant", [
+    ("multi", None), ("ragged", None), ("multi", "int8"),
+], ids=["multi-bf16", "ragged-bf16", "multi-int8"])
+def test_step_programs_move_no_pool(one_chip, monkeypatch, which, kv_quant):
+    """No whole pool, no layer's pool is copied, sliced out or written
+    back, and no temporary has a pool's size (the xs/ys scan's second
+    stack read 5.12 / 4.46 / 6.19 GB of temporaries here)."""
+    compiled, dims = _step_program(which, kv_quant, one_chip, monkeypatch)
+    found = pool_traffic(compiled.as_text(), dims)
+    assert not found, "\n".join(found)
+    # bf16 pages: 0.68 GB (multi: wq and wk relaid once a dispatch) and
+    # 0.02 GB (ragged). int8 pages: 1.89 GB, of which 1.08 are the two
+    # scale pools in the layout the steps want ([.., K, 1, ps] with K on
+    # the lanes: 16 times their bytes) and 0.81 three relaid weights
+    limit = 2 << 30 if kv_quant else 1 << 30
+    assert compiled.memory_analysis().temp_size_in_bytes < limit
