@@ -154,6 +154,11 @@ class InferenceEngine:
                 f"{model_cfg.name} (layers of several kinds) is served from "
                 "pages and state only: paged=True"
             )
+        if model_cfg.is_latent and not paged:
+            raise EngineError(
+                f"{model_cfg.name} (latent attention) is served from its "
+                "paged latent pool only: paged=True"
+            )
         self._pool = None  # lazy PagedKVCache page pool
         self._allocator = None
         # the scheduler object is created eagerly (it is cheap — no device
@@ -233,7 +238,7 @@ class InferenceEngine:
         if quantize == "int4" and has_axis(mesh, "tp"):
             int4_exclude = frozenset({"wo", "w_down"})
         tok = load_tokenizer(tokenizer)
-        if checkpoint_dir and cfg.layer_kinds:
+        if checkpoint_dir and (cfg.layer_kinds or cfg.is_latent):
             raise ValueError(f"{cfg.name}: no checkpoint name map for its tree yet")
         if checkpoint_dir:
             from fei_tpu.engine.weights import load_checkpoint

@@ -25,6 +25,13 @@ d] float32 is their recurrent state, one row a slot (indexed like
 ``lengths``) and a last row for the admission in flight, which becomes the
 slot's when the slot is armed. Both are None for every other model.
 
+A third kind: a model with latent attention (models/deepseek.py) keeps one
+row a token and layer with no head axis and no K/V pair, ``latent`` [L, P,
+ps, W]: the compressed vector and the rotated key part, padded to whole
+lane tiles (``ModelConfig.latent_row``). ``k_pages`` and ``v_pages`` are
+then None. Tables, lengths, the allocator and the prefix cache deal in
+pages and are the same for all three.
+
 The allocator is deliberately host-side Python (free-list): allocation
 happens once per prefill and at page boundaries during decode, never inside
 a jitted program.
@@ -66,9 +73,16 @@ class PagedKVCache(NamedTuple):
     v_scales: jnp.ndarray | None = None
     kc_pages: jnp.ndarray | None = None  # [L, P, K, rows, D] fp32 (sparse)
     state: jnp.ndarray | None = None  # [Ll, B + 1, H, d, d] fp32 (linear)
+    latent: jnp.ndarray | None = None  # [L, P, ps, W] (latent attention)
+    # what the expert layers of the steps since the program began routed
+    # (ops/moe.moe_held's stats, summed over layers and steps): a step
+    # program zeroes it, the layers add to it, the scheduler reads it
+    route_stats: jnp.ndarray | None = None  # int32 [4]
 
     @property
     def page_size(self) -> int:
+        if self.latent is not None:
+            return self.latent.shape[2]
         return self.k_pages.shape[3]
 
     @property
@@ -88,6 +102,18 @@ class PagedKVCache(NamedTuple):
     ) -> "PagedKVCache":
         if kv_quant not in (None, "int8"):
             raise EngineError(f"unsupported kv_quant mode: {kv_quant!r}")
+        if cfg.is_latent:
+            if kv_quant:
+                raise EngineError(f"{cfg.name}: latent rows stay unquantized")
+            return cls(
+                k_pages=None, v_pages=None,
+                block_table=jnp.zeros((batch, max_pages_per_seq), dtype=jnp.int32),
+                lengths=jnp.zeros((batch,), dtype=jnp.int32),
+                latent=jnp.zeros(
+                    (cfg.num_layers, num_pages, page_size, cfg.latent_row),
+                    dtype=dtype),
+                route_stats=jnp.zeros((4,), dtype=jnp.int32),
+            )
         L, K, D = cfg.kv_layers, cfg.num_kv_heads, cfg.head_dim_
         shape = (L, num_pages, K, page_size, D)
         kc = state = None
@@ -551,6 +577,55 @@ def write_token_kv(
                 v_scales, vs_upd, (page, 0, 0, offset[b])
             )
     return k_pages, v_pages, k_scales, v_scales
+
+
+def page_at(row: jnp.ndarray, slot) -> jnp.ndarray:
+    """Page id at table slot ``slot`` of ``row`` ([..., width]); a slot
+    outside the table is the null page 0 (``write_token_kv``'s rule)."""
+    width = row.shape[-1]
+    inside = (slot >= 0) & (slot < width)
+    got = jnp.take_along_axis(
+        row, jnp.clip(slot, 0, width - 1)[..., None], axis=-1
+    )[..., 0]
+    return jnp.where(inside, got, 0)
+
+
+@jax.named_scope("kv_write")
+def write_latent_rows(
+    pool: jnp.ndarray,  # [N, ps, W]: every layer's latent rows, flat
+    rows: jnp.ndarray,  # [B, W] this step's rows
+    block_table: jnp.ndarray,  # [B, max_pages]
+    positions: jnp.ndarray,  # [B] position being written
+    base=0,
+) -> jnp.ndarray:
+    """One decode token's latent row a sequence, written where it lies:
+    ``(base + page, offset, 0)``; a position past the table goes to the
+    layer's null page."""
+    ps = pool.shape[1]
+    page = base + page_at(block_table, positions // ps)
+    off = positions % ps
+    for b in range(rows.shape[0]):
+        pool = jax.lax.dynamic_update_slice(
+            pool, rows[b][None, None].astype(pool.dtype), (page[b], off[b], 0)
+        )
+    return pool
+
+
+@jax.named_scope("kv_write")
+def write_latent_pages(
+    pool: jnp.ndarray,  # [N, ps, W]
+    rows: jnp.ndarray,  # [C, W] a chunk's rows, C whole pages
+    row: jnp.ndarray,  # [max_pages] the admitting slot's table row
+    start,  # int32, page-aligned: the chunk's first position
+    base=0,
+) -> jnp.ndarray:
+    """An admission chunk's latent rows, a page a write."""
+    ps = pool.shape[1]
+    pages = rows.reshape(-1, ps, rows.shape[-1]).astype(pool.dtype)
+    for i in range(pages.shape[0]):
+        at = base + page_at(row, start // ps + i)
+        pool = jax.lax.dynamic_update_slice(pool, pages[i][None], (at, 0, 0))
+    return pool
 
 
 def paged_attention_reference(

@@ -231,7 +231,8 @@ class AdmissionMixin:
                 # byte-identical continuation
                 if (
                     prefix or n_tok > self.prefill_chunk or seq.generated
-                    or self._hybrid  # pages and state only: no dense prefill
+                    # pages (and state) only: no dense prefill
+                    or self._hybrid or self._latent
                 ) and not sp_long:
                     self._start_chunked(seq, slot, prefix)
                     return  # one chunked admission at a time
@@ -677,14 +678,17 @@ class AdmissionMixin:
             mesh = self.engine.mesh
             fam = family(cfg)
             _logits = fam._logits
+            latent = self._latent
 
             def chunk(params, pool, toks, row, pos, last_idx, *snap_at):
                 # a family with recurrent layers is told which of the
                 # chunk's tokens are real and where to snapshot its state,
-                # and the snapshot it takes is one more result
+                # and the snapshot it takes is one more result; a family
+                # with expert layers is told which are real (padding is
+                # routed to no expert)
                 hidden, out_pool, *snap = fam.forward_chunk(
                     params, cfg, toks, pool, row, pos,
-                    *((last_idx, *snap_at) if snap_at else ()),
+                    *((last_idx, *snap_at) if snap_at or latent else ()),
                     kernel_mesh=mesh,
                 )
                 if not final:

@@ -26,6 +26,27 @@ from fei_tpu.parallel.mesh import mesh_tag
 from fei_tpu.utils.metrics import METRICS
 
 
+def _route_begin(pool):
+    """A step program starts its count of what the expert layers routed
+    (``PagedKVCache.route_stats``; None where the model has none)."""
+    if pool.route_stats is None:
+        return pool
+    return pool._replace(route_stats=jnp.zeros_like(pool.route_stats))
+
+
+def _route_ride(toks, pool):
+    """The routing count rides the sampled tokens [B, n] out as further
+    columns, so that the one fetch the loop makes anyway brings it: no
+    wait of its own (``_dispatch_steps`` takes the columns off again)."""
+    if pool.route_stats is None:
+        return toks
+    extra = jnp.broadcast_to(
+        pool.route_stats.astype(toks.dtype)[None],
+        (toks.shape[0], pool.route_stats.shape[0]),
+    )
+    return jnp.concatenate([toks, extra], axis=1)
+
+
 def _make_sampler(grammared: bool, masked: bool):
     """The ONE on-device sampling tail every scheduler decode step runs:
     grammar DFA mask, optional host mask, per-slot key split, dynamic
@@ -384,17 +405,29 @@ class DecodeMixin:
         METRICS.timing("dispatch_issue", t_issue - t0)
         METRICS.timing("dispatch_sync", t1 - t_issue)
         extra = {}
+        if self._latent:
+            # what the expert layers routed, over the dispatch's layers
+            # and steps, idle slots and a chunk's padding left out: it
+            # came out beside the tokens (``_route_ride``)
+            out, routed = out[:, :n], out[0, n:]
+            extra.update(
+                held_rows=int(routed[0]), expert_rows_max=int(routed[1]),
+                experts_touched=int(routed[2]),
+            )
+            METRICS.incr("moe.assignments", int(routed[3]))
+            METRICS.incr("moe.assignments_held", int(routed[0]))
         if merged:
             # NO separate "dispatch.prefill_chunk" record for a merged
             # chunk — that count dropping under overlap IS the measured
             # dispatch reduction (pinned in tests/test_ragged_attention)
             cfg = eng.cfg
             tp = eng.mesh.shape.get("tp", 1) if eng.mesh is not None else 1
-            extra = {
+            extra.update({
                 "ragged": True, "chunk_tokens": pc["hi"] - pc["lo"],
                 "chunk_rid": pc["st"]["seq"].rid, "chunk_lo": pc["lo"],
-            }
-            if not self._hybrid:  # its merged step calls no ragged kernel
+            })
+            # a hybrid's or a latent pool's merged step calls no ragged kernel
+            if not (self._hybrid or self._latent):
                 extra["attn_steps"] = math.prod(ragged_grid_of(
                     self.B, pc["toks"].shape[1], cfg.num_kv_heads // tp,
                     cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_,
@@ -503,6 +536,7 @@ class DecodeMixin:
                       minps, gstates=None, gremain=None, table=None,
                       mind=None, mask=None):
                 sampler = _make_sampler(grammared, masked)
+                pool = _route_begin(pool)
 
                 def body(carry, _):
                     if grammared:
@@ -538,7 +572,8 @@ class DecodeMixin:
                 # the per-token reference chain after delivering i+1 tokens,
                 # so the host can re-enter mid-scan (free-phase trigger
                 # rollback) with bit-identical seeded sampling
-                return jnp.swapaxes(toks, 0, 1), step_keys, carry[0], carry[2]
+                return (_route_ride(jnp.swapaxes(toks, 0, 1), carry[0]),
+                        step_keys, carry[0], carry[2])
 
             self._step_jit[key] = self.engine._compiles.wrap(
                 "sched.multi", key, jax.jit(multi, donate_argnums=(1,))
@@ -564,11 +599,13 @@ class DecodeMixin:
             _logits, forward_paged = fam._logits, fam.forward_paged
             forward_paged_merged = fam.forward_paged_merged
             hybrid = self._hybrid
+            latent = self._latent
 
             def ragged(params, pool, ctoks, crow, cpos, clast, tokens,
                        keys, temps, topks, topps, minps, gstates=None,
                        gremain=None, table=None, mind=None, csnap=None):
                 sampler = _make_sampler(grammared, False)
+                pool = _route_begin(pool)
                 if hybrid:
                     # the chunk's real tokens and where it snapshots the
                     # recurrent state go in; the snapshot comes out last
@@ -577,9 +614,11 @@ class DecodeMixin:
                         clast, csnap, kernel_mesh=mesh,
                     )
                 else:
+                    # a family with expert layers is told which of the
+                    # chunk's tokens are real: padding goes to no expert
                     chunk_hidden, logits, pool = forward_paged_merged(
                         params, cfg, ctoks, crow, cpos, tokens, pool,
-                        kernel_mesh=mesh,
+                        *((clast,) if latent else ()), kernel_mesh=mesh,
                     )
                 logits = logits[:, -1, :]
                 nxt, new_keys, gstates, gremain = sampler(
@@ -627,7 +666,8 @@ class DecodeMixin:
                     step_keys = jnp.concatenate([step_keys, keys_r], axis=0)
                 else:
                     keys_out = new_keys
-                out = (jnp.swapaxes(toks, 0, 1), step_keys, pool, keys_out)
+                out = (_route_ride(jnp.swapaxes(toks, 0, 1), pool), step_keys,
+                       pool, keys_out)
                 if hybrid:
                     out = out + (snap,)
                 if not final:

@@ -340,19 +340,31 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         # moves pages without that state refuses the model here, at build.
         self._hybrid = bool(engine.cfg.layer_kinds)
         if self._hybrid:
-            self._refuse_for_hybrid()
+            self._refuse_what_moves_pages(
+                "layers of several kinds",
+                "spills and streams pages without the linear layers' state")
+        # a model with latent attention (models/deepseek.py): its cache row
+        # has no head axis and no K/V pair. Pages, tables and the prefix
+        # cache are as ever; what spells K and V pages a head refuses it.
+        self._latent = engine.cfg.is_latent
+        if self._latent:
+            self._refuse_what_moves_pages(
+                "a latent pool",
+                "spills and streams K and V pages a head (kv/pagesio.py)")
         self._state_jit: dict = {}  # adopt_state / load_state, jitted
 
-    def _refuse_for_hybrid(self) -> None:
+    def _refuse_what_moves_pages(self, kind: str, tier_why: str) -> None:
+        """A model served from pages (and state) only: one line at build
+        for what would move its pages another way."""
         eng = self.engine
         why = None
         if self._kv_tier is not None:
-            why = "the KV tier (FEI_TPU_KV_TIER) spills and streams pages without the linear layers' state"
+            why = f"the KV tier (FEI_TPU_KV_TIER) {tier_why}"
         elif self.prefill_chunk % eng.page_size:
             why = (f"an admission chunk ({self.prefill_chunk}) must be whole "
                    f"pages of {eng.page_size}")
         if why:
-            raise EngineError(f"{eng.cfg.name} (layers of several kinds): {why}")
+            raise EngineError(f"{eng.cfg.name} ({kind}): {why}")
 
     # -- public API ---------------------------------------------------------
 
@@ -852,6 +864,11 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
             raise EngineError(
                 f"{self.engine.cfg.name}: migration moves pages without "
                 "the linear layers' state"
+            )
+        if self._latent:
+            raise EngineError(
+                f"{self.engine.cfg.name} (a latent pool): migration moves K "
+                "and V pages a head (kv/migrate.py over kv/pagesio.py)"
             )
 
     def export_prefix(self, prompt_ids) -> bytes | None:

@@ -12,7 +12,15 @@ tiny preset for hermetic CPU tests beside its published sizes:
 - MiniCPM-SALA (``minicpm-sala``, ``tiny-sala``): layers of two kinds in
   one model, block-sparse attention over pages and linear attention with a
   per-sequence state, q/k norms, output gates, muP scalings:
-  ``models/sala.py``, a stack of weights a kind.
+  ``models/sala.py``, a stack of weights a kind;
+- Moonlight-16B-A3B (``moonlight-16b-a3b``, ``tiny-moonlight``; the
+  ``deepseek_v3`` block): latent attention, whose cache row is one
+  compressed vector and one rotated key part a token with no head axis,
+  decoded by absorbed weights; a leading dense layer, then layers of many
+  small experts chosen by sigmoid scores plus a selection bias, beside
+  shared experts, of which a chip may hold a share:
+  ``models/deepseek.py``, a stack for the dense layers and one for the
+  expert layers.
 
 ``models.family(cfg)`` gives the module whose step functions serve a
 configuration.
@@ -91,6 +99,33 @@ class ModelConfig:
     sparse_topk: int = 0
     sparse_init_blocks: int = 0
     sparse_window: int = 0
+    # Latent attention (MLA, models/deepseek.py; kv_lora_rank > 0): keys and
+    # values are up-projections of one compressed vector of ``kv_lora_rank``
+    # a token, beside one rotated key part of ``qk_rope_head_dim`` that all
+    # heads share; a head's query and key are ``qk_nope_head_dim`` unrotated
+    # dims and the rotated part, its value ``v_head_dim``. The cache row is
+    # the compressed vector and the rotated part: no head axis.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    kv_norm_eps: float = 1e-6  # the compressed vector's own RMS norm
+    # Expert layers of that family: the first ``first_dense_layers`` layers
+    # keep a dense MLP of ``intermediate_size``; every later one routes
+    # over ``num_experts`` experts of ``moe_intermediate_size`` (sigmoid
+    # scores, the ``num_experts_per_tok`` largest of score + selection
+    # bias, weights the scores alone, normalised if ``norm_topk_prob``,
+    # times ``routed_scaling_factor``) beside ``num_shared_experts`` shared
+    # experts run as one MLP. ``expert_share`` = (i, n): this program holds
+    # the i-th of n equal runs of the experts (expert parallelism of degree
+    # n) and computes their part of a layer's result; the router keeps all
+    # ``num_experts`` outputs.
+    first_dense_layers: int = 0
+    moe_intermediate_size: int = 0
+    num_shared_experts: int = 0
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    expert_share: tuple = (0, 1)
     # tokenizer/bos/eos defaults (overridden by a real tokenizer when loaded)
     bos_token_id: int = 1
     eos_token_id: int = 2
@@ -107,6 +142,30 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_latent(self) -> bool:
+        """Latent attention: the cache row has no head axis."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_row(self) -> int:
+        """Width of a latent cache row as the pool stores it: the
+        compressed vector and the rotated key part, padded with zeros to
+        whole 128-lane tiles (the device lays the minor dimension out in
+        such tiles anyway, and a kernel's own copy moves whole ones)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def experts_held(self) -> tuple:
+        """(first expert held, how many): ``expert_share``'s run."""
+        i, n = self.expert_share
+        if self.num_experts % n or not 0 <= i < n:
+            raise ValueError(
+                f"{self.name}: share {i} of {n} does not divide "
+                f"{self.num_experts} experts")
+        held = self.num_experts // n
+        return i * held, held
 
     @property
     def kv_layers(self) -> int:
@@ -129,6 +188,16 @@ class ModelConfig:
             attn += self.num_heads * d + 2 * self.num_kv_heads * d
         if self.o_bias:
             attn += h
+        if self.is_latent:
+            # what this program holds: its share of the routed experts
+            r, dn, dr = self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim
+            H, dv, im = self.num_heads, self.v_head_dim, self.moe_intermediate_size
+            attn = h * H * (dn + dr) + h * (r + dr) + r * H * (dn + dv) + H * dv * h
+            expert = 3 * h * im
+            moe = (self.experts_held[1] + self.num_shared_experts) * expert \
+                + h * self.num_experts
+            Ld = self.first_dense_layers
+            return L * (attn + 2 * h) + Ld * 3 * h * i + (L - Ld) * moe + 2 * v * h + h
         if self.is_moe:
             mlp = self.num_experts * 3 * h * i + h * self.num_experts
         else:
@@ -314,6 +383,33 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         attn_gate=True, scale_emb=12.0, scale_depth=1.4, dim_model_base=16,
         sparse_block=8, sparse_kernel=4, sparse_stride=2, sparse_topk=4,
         sparse_init_blocks=1, sparse_window=16,
+    ),
+    # Moonlight-16B-A3B (moonshotai, 2025-02; config.json of
+    # moonshotai/Moonlight-16B-A3B, model_type deepseek_v3): latent
+    # attention with no query down-projection, one dense layer, then 26
+    # layers of 64 routed experts (6 a token, sigmoid scores) beside 2
+    # shared experts. All 64 experts of every layer are 16 GB of int8: the
+    # preset is one chip's share of two (experts 0-31 of each layer, all of
+    # the rest), expert parallelism of degree 2
+    "moonlight-16b-a3b": ModelConfig(
+        name="moonlight-16b-a3b", vocab_size=163840, hidden_size=2048,
+        intermediate_size=11264, num_layers=27, num_heads=16, num_kv_heads=16,
+        head_dim=192, rope_theta=50000.0, rms_norm_eps=1e-5, max_seq_len=8192,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, first_dense_layers=1, moe_intermediate_size=1408,
+        num_experts=64, num_experts_per_tok=6, num_shared_experts=2,
+        norm_topk_prob=True, routed_scaling_factor=2.446,
+        expert_share=(0, 2), bos_token_id=163584, eos_token_id=163585,
+    ),
+    # the same family at test size, every expert held
+    "tiny-moonlight": ModelConfig(
+        name="tiny-moonlight", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=3, num_heads=4, num_kv_heads=4,
+        head_dim=24, rope_theta=10000.0, rms_norm_eps=1e-5, max_seq_len=512,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, first_dense_layers=1, moe_intermediate_size=32,
+        num_experts=8, num_experts_per_tok=3, num_shared_experts=2,
+        norm_topk_prob=True, routed_scaling_factor=2.5,
     ),
     "qwen2-0.5b": ModelConfig(
         name="qwen2-0.5b", vocab_size=151936, hidden_size=896,

@@ -39,6 +39,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from fei_tpu.engine.paged_cache import page_at as _page_at
 from fei_tpu.models.configs import ModelConfig
 from fei_tpu.models.llama import _mlp_dense, _norm
 from fei_tpu.ops import linear_attention as la
@@ -141,17 +142,6 @@ def _cache_of(cache, bufs: _Bufs, lengths):
         kc_pages=bufs.kc.reshape(cache.kc_pages.shape),
         state=bufs.state, lengths=lengths,
     )
-
-
-def _page_at(row, slot):
-    """Page id at table slot ``slot`` of ``row`` ([..., width]); a slot
-    outside the table is the null page 0 (``write_token_kv``'s rule)."""
-    width = row.shape[-1]
-    inside = (slot >= 0) & (slot < width)
-    got = jnp.take_along_axis(
-        row, jnp.clip(slot, 0, width - 1)[..., None], axis=-1
-    )[..., 0]
-    return jnp.where(inside, got, 0)
 
 
 def _gated_out(lp, y, attn):

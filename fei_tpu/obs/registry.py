@@ -127,6 +127,13 @@ METRIC_REGISTRY: dict[str, tuple[str, str]] = {
     "state.resumed_tokens": ("counter",
                              "Prompt tokens not recomputed because an "
                              "admission resumed from a snapshot."),
+    "moe.assignments": ("counter",
+                        "(row, expert) assignments the step programs' expert "
+                        "layers made: live rows x experts a token x expert "
+                        "layers (idle slots and padding are routed nowhere)."),
+    "moe.assignments_held": ("counter",
+                             "Of moe.assignments, those to experts this chip "
+                             "holds: the rows its grouped products ran."),
     "sparse.pages_selected": ("counter",
                               "Pages a block-sparse layer's decode steps "
                               "read (one layer, one kv head)."),

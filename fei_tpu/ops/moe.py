@@ -13,7 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from fei_tpu.ops.quant import scale_expert_out, scale_rows, wcast
+from fei_tpu.ops.quant import QTensor, scale_expert_out, scale_rows, wcast
 
 
 def moe_mlp(
@@ -105,3 +105,130 @@ def moe_mlp_routed(
     wf = jnp.take(topk_weights.reshape(-1), order).astype(x.dtype)
     out = jnp.zeros((N, H), dtype=x.dtype).at[token_of].add(outs * wf[:, None])
     return out.reshape(B, T, H)
+
+
+def sigmoid_gate(
+    x: jnp.ndarray,  # [N, H]
+    router_w: jnp.ndarray,  # [H, E]
+    bias: jnp.ndarray,  # [E] selection bias
+    k: int,
+    norm: bool = True,
+    scale: float = 1.0,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The gate of the ``deepseek_v3`` expert layer (one group): scores
+    ``s = sigmoid(x W)`` in float32, the ``k`` experts of the largest
+    ``s + bias`` chosen, their weights the scores alone (the bias selects
+    and never weighs), normalised to sum 1 if ``norm``, times ``scale``.
+    Returns (chosen [N, k] int32, weights [N, k] float32)."""
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ))
+    # the k largest by k rounds of arg-max, each taking its pick out (the
+    # first of equals, as ``lax.top_k`` orders them): ``top_k`` of 64 is a
+    # whole sort a row on the TPU, 0.1 ms a layer at 32 rows (PERF.md, PR 36)
+    sel = s + bias.astype(jnp.float32)
+    lane = jnp.arange(sel.shape[-1], dtype=jnp.int32)[None, :]
+    picks = []
+    for _ in range(k):
+        pick = jnp.argmax(sel, axis=-1).astype(jnp.int32)
+        picks.append(pick)
+        sel = jnp.where(lane == pick[:, None], -jnp.inf, sel)
+    idx = jnp.stack(picks, axis=-1)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if norm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx, w * scale
+
+
+def _grouped(xs, w, sizes, expert_of, layer):
+    """Rows sorted by expert times their expert's matrix, one grouped
+    product (``ops/pallas/grouped_matmul.py``). ``w``: the held experts'
+    matrices [Eh, K, N], or with ``layer`` every layer's [L, Eh, K, N],
+    which the kernel reads where they lie. int8 experts go in as they are
+    (no bf16 copy of the experts is ever made) and the scales are applied
+    to the result rows."""
+    from fei_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    quant = isinstance(w, QTensor)
+    out = grouped_matmul(xs, w.q if quant else w, sizes,
+                         0 if layer is None else layer)
+    if quant and layer is not None:  # the layer's scales: [Eh, 1, N]
+        w = w._replace(s=jax.lax.dynamic_index_in_dim(
+            w.s, layer, axis=0, keepdims=False))
+    return scale_rows(out, w, expert_of)
+
+
+def moe_held(
+    x: jnp.ndarray,  # [N, H]
+    idx: jnp.ndarray,  # [N, k] chosen experts, of all the router's
+    weights: jnp.ndarray,  # [N, k] float32
+    w_gate,  # [Eh, H, I] the experts held here (plain or QTensor)
+    w_up,
+    w_down,  # [Eh, I, H]
+    first: int = 0,
+    layer=None,
+    live=None,  # [N] bool: the rows that are somebody's token
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The part of an expert layer's result that the experts held here
+    give: experts ``first .. first + Eh`` of those the router chooses
+    among. The (token, expert) assignments to held experts are put in
+    expert order and run as ONE grouped product a matrix over their rows
+    alone; an assignment to an expert that is not held belongs to no run,
+    so nothing is computed for it, and what that expert would add is left
+    out (another chip's part). Every shape is static: a decode step's
+    rows and an admission chunk's go through the same code. With
+    ``layer`` the three weights are every layer's, stacked on a leading
+    axis, and ``layer`` says which. A row that ``live`` leaves out (an idle
+    slot's, a chunk's padding) is routed to no expert: it costs no
+    expert's bytes, counts in no number below, and comes back as zeros.
+
+    The order comes from counting, not sorting: an assignment's place is
+    its expert's first row plus how many earlier assignments chose the
+    same expert (a running sum over a one-hot [N*k, Eh + 1]); a sort of
+    the same 192 keys cost a quarter of a millisecond a layer on the v5e
+    (PERF.md, PR 36).
+
+    Returns (out [N, H], stats int32 [4]: assignments held, rows of the
+    busiest expert, experts with any row, assignments made)."""
+    from fei_tpu.ops.pallas.grouped_matmul import TILE_ROWS
+
+    N, k = idx.shape
+    Eh = w_gate.shape[-3]
+    M = N * k
+    Mp = -(-M // TILE_ROWS) * TILE_ROWS
+    with jax.named_scope("moe_route"):
+        local = idx - first
+        held = (local >= 0) & (local < Eh)
+        made = jnp.int32(M)
+        if live is not None:
+            held = held & live[:, None]
+            made = jnp.sum(live.astype(jnp.int32)) * k
+        flat = jnp.where(held, local, Eh).reshape(-1)  # not held: behind all
+        hot = (flat[:, None] == jnp.arange(Eh + 1)[None, :]).astype(jnp.int32)
+        counts = jnp.sum(hot, axis=0)
+        starts = jnp.cumsum(counts) - counts
+        rank = jnp.cumsum(hot, axis=0) - hot  # earlier rows of the same expert
+        pos = jnp.sum(hot * (starts[None, :] + rank), axis=1)  # row -> its place
+        src = jnp.zeros((Mp,), jnp.int32).at[pos].set(
+            jnp.arange(M, dtype=jnp.int32), unique_indices=True)  # and back
+        expert_of = jnp.minimum(jnp.take(flat, src), Eh - 1)
+        sizes = counts[:Eh]
+        n_held = jnp.sum(sizes)
+        xs = jnp.take(x, src // k, axis=0)  # [Mp, H]
+    with jax.named_scope("moe_experts"):
+        gate = _grouped(xs, w_gate, sizes, expert_of, layer)
+        up = _grouped(xs, w_up, sizes, expert_of, layer)
+        act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
+        outs = _grouped(act, w_down, sizes, expert_of, layer)
+    with jax.named_scope("moe_combine"):
+        # rows behind the last run are no expert's: whatever they hold is
+        # dropped, and the rest go back to their tokens' order to be summed
+        live = (jnp.arange(Mp) < n_held)[:, None]
+        outs = jnp.where(live, outs, jnp.zeros_like(outs))
+        back = jnp.take(outs, pos, axis=0).reshape(N, k, -1)
+        out = jnp.einsum(
+            "nkh,nk->nh", back.astype(jnp.float32), weights
+        ).astype(x.dtype)
+    stats = jnp.stack([n_held, jnp.max(sizes), jnp.sum(sizes > 0), made])
+    return out, stats.astype(jnp.int32)

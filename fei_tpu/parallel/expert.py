@@ -16,6 +16,13 @@ Two formulations, both static-shape SPMD over the ``ep`` mesh axis:
 
 Composes with dp (batch) and tp (the I dimension inside each expert) from
 sharding.py.
+
+3. ``moe_share`` — a chip that is told which experts it holds
+   (``ModelConfig.expert_share``: one of n equal runs) and computes their
+   part of the layer's result (``ops.moe.moe_held``). On one chip that is
+   the whole of it: no dispatch, no exchange, and nothing stands in for
+   the other chips; their parts are left out. Across an ``ep`` axis the
+   shares would be summed after an exchange, which is not run yet.
 """
 
 from __future__ import annotations
@@ -274,3 +281,20 @@ def expert_flops_share(
     routed_rows = num_experts * C  # E_local experts × n·C rows each
     dense_rows = num_tokens * (num_experts // ep)
     return routed_rows, dense_rows
+
+
+def moe_share(x, idx, weights, w_gate, w_up, w_down, first: int, mesh=None,
+              layer=None, live=None):
+    """This chip's share of an expert layer whose gate has chosen ``idx``
+    with ``weights`` ([N, k] each): the experts ``first`` onward that
+    ``w_gate``/``w_up``/``w_down`` hold (with ``layer``, every layer's,
+    stacked), over the rows that ``live`` names. One chip needs no
+    exchange; ``mesh`` is the one the step program runs under."""
+    from fei_tpu.ops.moe import moe_held
+
+    if mesh is not None and mesh.shape.get("ep", 1) > 1:
+        raise NotImplementedError(
+            "shares of an expert layer across an ep axis, with their "
+            "exchange, are not run yet: one chip holds one share"
+        )
+    return moe_held(x, idx, weights, w_gate, w_up, w_down, first, layer, live)

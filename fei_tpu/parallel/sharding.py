@@ -296,6 +296,11 @@ def shard_engine(engine, mesh: Mesh, weights: str = "sharded") -> None:
             f"{engine.cfg.name}: no sharding rules for a tree with a stack "
             "of weights a kind of layer, nor for the linear layers' state"
         )
+    if engine.cfg.is_latent:
+        raise ValueError(
+            f"{engine.cfg.name}: no sharding rules for a latent pool (no kv "
+            "heads to divide) nor for a share of the experts a chip"
+        )
     if weights not in ("sharded", "replicated"):
         raise ValueError(
             f"unknown weights profile {weights!r} "

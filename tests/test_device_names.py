@@ -33,6 +33,13 @@ SALA_SCOPES = (LAYER_SCOPES - {"attention"}) | {
     "sparse_select", "sparse_attention", "linear_attn", "state_carry"}
 
 
+# latent attention and expert layers (models/deepseek.py): the cache row's
+# making, the absorbed products, and the expert layer's four parts
+MOONLIGHT_SCOPES = (LAYER_SCOPES - {"rope"}) | {
+    "rope", "latent_kv", "latent_absorb", "moe_route", "moe_experts",
+    "moe_shared", "moe_combine"}
+
+
 def _programs(model: str, **kw):
     engine = InferenceEngine.from_config(
         model, paged=True, batch_size=2, max_seq_len=256, **kw
@@ -79,6 +86,24 @@ def sala_programs():
     engine, fns = _programs("tiny-sala", page_size=8)
     yield fns
     engine.close()
+
+
+@pytest.fixture(scope="module")
+def moonlight_programs():
+    engine, fns = _programs("tiny-moonlight", page_size=8)
+    yield fns
+    engine.close()
+
+
+@pytest.mark.parametrize("program,expected", [
+    ("multi", MOONLIGHT_SCOPES | {"lm_head", "sample", "grammar_mask"}),
+    ("ragged", MOONLIGHT_SCOPES | {"lm_head", "sample", "grammar_mask"}),
+    ("chunk", MOONLIGHT_SCOPES | {"lm_head"}),
+])
+def test_latent_step_programs_carry_every_scope(moonlight_programs, program, expected):
+    fn, args, kw = moonlight_programs[program]
+    missing = expected - _scopes_in(fn, args, kw)
+    assert not missing, f"{program} lost scopes {sorted(missing)}"
 
 
 @pytest.mark.parametrize("program,expected", [
@@ -159,8 +184,15 @@ def _kernel_calls():
         ragged_paged_attention,
     )
 
+    from fei_tpu.ops.pallas.latent_paged_attention import (
+        latent_paged_attention,
+        latent_paged_attention_block,
+    )
+
     bt = jnp.zeros((2, 2), jnp.int32)
     ln = jnp.ones((2,), jnp.int32)
+    latent = jnp.zeros((5, 4, 128), jnp.float32)  # [N, ps, W]: no head axis
+    ql = jnp.zeros((2, 4, 128), jnp.float32)
     q1 = jnp.zeros((2, 4, 8), jnp.float32)
     qT = jnp.zeros((2, 3, 4, 8), jnp.float32)
     dense = jnp.zeros((2, 8, 2, 8), jnp.float32)
@@ -185,6 +217,18 @@ def _kernel_calls():
             lambda: _pallas_names(
                 ragged_paged_attention, qT, _pool(), _pool(), bt, ln, ln),
         ),
+        "latent_paged_attention": (
+            "latent_paged_attention",
+            lambda: _pallas_names(
+                lambda q, p: latent_paged_attention(
+                    q, p, bt, ln, dv=128, scale=1.0), ql, latent),
+        ),
+        "latent_paged_attention_block": (
+            "latent_paged_attention_block",
+            lambda: _pallas_names(
+                lambda q, p: latent_paged_attention_block(
+                    q, p, bt[0], jnp.int32(0), dv=128, scale=1.0), ql, latent),
+        ),
         "flash_attention": (
             "flash_attention",
             lambda: _pallas_names(
@@ -194,9 +238,23 @@ def _kernel_calls():
     }
 
 
+def test_grouped_product_names_itself_as_names_json_lists():
+    from fei_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    got = _pallas_names(
+        grouped_matmul, jnp.zeros((64, 8), jnp.float32),
+        jnp.zeros((2, 3, 8, 128), jnp.float32), jnp.ones((3,), jnp.int32),
+        jnp.int32(1))
+    assert got == ["moe_grouped_matmul"]
+    assert got[0] in NAMES["kernels"]["moe_experts"]
+    assert "moe_experts" not in NAMES["attention"]
+
+
 @pytest.mark.parametrize("call", ["paged_attention", "paged_attention_block",
                                   "sparse_paged_attention",
-                                  "ragged_paged_attention", "flash_attention"])
+                                  "ragged_paged_attention", "flash_attention",
+                                  "latent_paged_attention",
+                                  "latent_paged_attention_block"])
 def test_kernels_name_themselves_as_names_json_lists(call):
     kernel, names = _kernel_calls()[call]
     got = names()

@@ -169,6 +169,141 @@ def test_selected_page_kernel_compiles_for_v5e_at_g16(one_chip):
     assert _pallas_grid(call, *args) == (B * K, 1)
 
 
+# rows of qt positions x H heads against a latent pool of `pages` pages of
+# ps rows of W lanes, values the leading dv: Moonlight's cell (32 slots x
+# 64 page slots x 27 layers), its decode rows and its 256-token chunk
+LATENT = {
+    "moonlight_decode_32_slots": (32, 1, 16, 640, 512, 64, 64, 27 * 2049),
+    "moonlight_chunk_256": (1, 256, 16, 640, 512, 64, 64, 27 * 2049),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(LATENT))
+def test_latent_kernel_compiles_for_v5e(one_chip, shape):
+    """The kernel over a cache row with no head axis at the served shapes,
+    under its own names: a program a sequence for decode, a program a tile
+    of 64 positions (1024 query rows) for a chunk, whole pages copied by
+    the program itself."""
+    from fei_tpu.ops.pallas.latent_paged_attention import (
+        latent_paged_attention,
+        latent_paged_attention_block,
+    )
+
+    B, T, H, W, dv, ps, slots, pages = LATENT[shape]
+
+    def S(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pool = S((pages, ps, W), jnp.bfloat16)
+    if T == 1:
+        args = [S((B, H, W), jnp.bfloat16), pool, S((B, slots), jnp.int32),
+                S((B,), jnp.int32)]
+
+        def call(q, p, bt, ln):
+            return latent_paged_attention(
+                q, p, bt, ln, dv=dv, scale=192 ** -0.5, interpret=False)
+        name, grid = "latent_paged_attention.", (B,)
+    else:
+        args = [S((T, H, W), jnp.bfloat16), pool, S((slots,), jnp.int32),
+                S((), jnp.int32)]
+
+        def call(q, p, row, start):
+            return latent_paged_attention_block(
+                q, p, row, start, dv=dv, scale=192 ** -0.5, interpret=False)
+        name, grid = "latent_paged_attention_block.", (T * H // 1024,)
+    compiled = jax.jit(call).lower(*args).compile()
+    assert name in compiled.as_text()
+    assert _pallas_grid(call, *args) == grid
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("rows,K,N", [
+    (192, 2048, 1408), (192, 1408, 2048), (1728, 2048, 1408), (1728, 1408, 2048)])
+def test_grouped_product_compiles_for_v5e(one_chip, rows, K, N):
+    """The experts' grouped product at Moonlight's shapes: a decode step's
+    32 x 6 assignments and a merged dispatch's (32 + 256) x 6, gate/up and
+    down, over the int8 stack of all 26 expert layers read in place."""
+    from fei_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    def S(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    args = [S((rows, K), jnp.bfloat16), S((26, 32, K, N), jnp.int8),
+            S((32,), jnp.int32), S((), jnp.int32)]
+
+    def call(xs, w, sizes, layer):
+        return grouped_matmul(xs, w, sizes, layer, interpret=False)
+
+    compiled = jax.jit(call).lower(*args).compile()
+    assert "moe_grouped_matmul." in compiled.as_text()
+    assert _pallas_grid(call, *args) == (rows // 64 + 32,)
+    # nothing of the stack's size, nor of a layer's experts, is made
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+@pytest.mark.parametrize("which", ["multi", "ragged"])
+def test_moonlight_decode_scan_fits_the_chip_and_moves_no_pool(
+        one_chip, monkeypatch, which):
+    """``multi(8)`` and ``ragged(8, 256, final)`` of moonlight-16b-a3b
+    (int8, 32 of 64 experts a layer) at the cell's shapes, 32 slots of 4096
+    positions: the program fits a 16 GB chip beside its arguments (9.1 GB
+    of weights, a 4.5 GB pool), the pool is written in place (nothing of
+    its size is copied or made), and no bfloat16 copy of a layer's experts
+    is made. In the cell every dispatch is the merged one."""
+    from fei_tpu.engine.paged_cache import PagedKVCache
+    from fei_tpu.engine.sched_decode import DecodeMixin
+    from fei_tpu.models.configs import get_model_config
+    from fei_tpu.models.deepseek import init_params
+
+    cfg = get_model_config("moonlight-16b-a3b")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, width = 32, 4096 // 64
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree)
+
+    def S(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: init_params(cfg, k, quantize="int8"), jax.random.PRNGKey(0)))
+    pool = on_chip(jax.eval_shape(lambda: PagedKVCache.create(
+        cfg, slots * width + 1, slots, width, page_size=64)))
+    sched = types.SimpleNamespace(
+        engine=types.SimpleNamespace(
+            cfg=cfg, mesh=None,
+            _compiles=types.SimpleNamespace(wrap=lambda fam, key, fn: fn)),
+        _step_jit={}, _hybrid=False, _latent=True)
+    sampling = [
+        S((slots, 1), jnp.int32), S((slots, 2), jnp.uint32),
+        S((slots,), jnp.float32), S((slots,), jnp.int32),
+        S((slots,), jnp.float32), S((slots,), jnp.float32)]
+    if which == "multi":
+        fn, chunk = DecodeMixin._multi_fn(sched, 8, False), []
+    else:
+        fn = DecodeMixin._ragged_fn(sched, 8, 256, True, False)
+        chunk = [S((1, 256), jnp.int32), S((1, width), jnp.int32),
+                 S((1,), jnp.int32), S((), jnp.int32)]
+    compiled = fn.lower(params, pool, *chunk, *sampling).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
+    assert mem.temp_size_in_bytes < 1 << 30
+    text = compiled.as_text()
+    L, P, ps, W = pool.latent.shape
+    whole = {f"bf16[{L * P},{ps},{W}]", f"bf16[{L},{P},{ps},{W}]",
+             f"bf16[{P},{ps},{W}]"}
+    moved = [m.group(0)[:160] for m in re.finditer(
+        r"= (\S+?)\{[^}]*\} (copy|copy-start|dynamic-slice|convert)\(.*", text)
+        if m.group(1) in whole]
+    assert not moved, "\n".join(moved)
+    # no copy of a layer's experts, in any type: the grouped product reads
+    # them in the stack
+    assert not re.search(r"= \w+\[(1,)?32,(2048,1408|1408,2048)\]", text)
+    assert "latent_paged_attention." in text and "moe_grouped_matmul." in text
+
+
 # -- the step programs' pool traffic ------------------------------------------
 #
 # The Llama family's layer scan carries the page pool flat and writes a
@@ -221,7 +356,7 @@ def _step_program(which: str, kv_quant, chip, monkeypatch):
             cfg=cfg, mesh=None,
             _compiles=types.SimpleNamespace(wrap=lambda fam, key, fn: fn),
         ),
-        _step_jit={}, _hybrid=False,
+        _step_jit={}, _hybrid=False, _latent=False,
     )
     sampling = [
         S((SLOTS, 1), jnp.int32), S((SLOTS, 2), jnp.uint32),
