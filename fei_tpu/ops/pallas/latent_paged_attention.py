@@ -17,9 +17,19 @@ positions its first query sees, both scalar-prefetched. The pool is handed
 over whole, where it lies in HBM, and the program copies the pages the row
 really has, ``n`` at a time (``ragged_paged_attention.pages_per_step``)
 into one half of a ``[2, n, page_size, W]`` buffer while the other half is
-computed on; online softmax carries (m, l, acc) across pages in VMEM. The
-slots of the last group past the last live page repeat that page under
-wholly masked positions, exact no-ops as there. A dead row (limit 0)
+computed on.
+
+The unit of work is the fetched group, not the page (PR 38): the half-buffer
+is one operand of ``n * page_size`` positions, and a group costs one score
+product ``[rows, W] x [W, n * page_size]``, one mask / max / exp / sum, one
+update of the online softmax's (m, l, acc) in VMEM and one value product
+``[rows, n * page_size] x [n * page_size, dv]`` (``[16, 640] x [640, 512]``
+and ``[16, 512] x [512, 512]`` for Moonlight's decode rows; 1024 rows for a
+chunk's tile). A decode program's 16 rows pass an operand tile in 16 cycles
+and the tile takes 128 to load, so what a group costs is the tiles it loads:
+a page a product loaded 9 half-used tiles a page, a group a product loads
+4.5 full ones. The slots of the last group past the last live page repeat
+that page under wholly masked positions, exact no-ops. A dead row (limit 0)
 walks nothing and comes out as zeros.
 
 Two callers, two kernel names in a device trace: ``latent_paged_attention``
@@ -100,14 +110,16 @@ def _latent_kernel(
             pool_hbm.at[pl.ds(0, n)], buf.at[half], sem.at[half]
         ).wait()
 
-    def online(pi, half, j):
+    def online(gi, half):
         q = q_ref[0]  # [rows, W]
-        row = buf[half, j]  # [page_size, W]: keys, and values in front
+        # the n fetched pages as one operand: keys, and values in front
+        grp = buf[half].reshape(n * page_size, -1)
         s = jax.lax.dot_general(
-            q, row, dimension_numbers=(((1,), (1,)), ((), ())),
+            q, grp, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # [rows, page_size]
-        pos = pi * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        ) * scale  # [rows, n * page_size]
+        pos = gi * (n * page_size) + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
         row_t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // heads
         s = jnp.where(pos < limit + row_t, s, NEG_INF)
         m_prev = m_ref[:]
@@ -116,7 +128,7 @@ def _latent_kernel(
         correction = jnp.exp(m_prev - m_new)
         l_ref[:] = correction * l_ref[:] + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[:] = correction * acc_ref[:] + jax.lax.dot_general(
-            p.astype(row.dtype), row[:, :dv],
+            p.astype(grp.dtype), grp[:, :dv],
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -133,8 +145,7 @@ def _latent_kernel(
 
         half = jax.lax.rem(gi, 2)
         arrived(half)
-        for j in range(n):
-            online(gi * n + j, half, j)
+        online(gi, half)
         return carry
 
     jax.lax.fori_loop(0, groups, group, 0)

@@ -327,31 +327,97 @@ def test_grouped_product_in_interpret_mode_matches_ragged_dot(sizes):
         one[:n], np.asarray(grouped_matmul(xs, w, sz, 1), np.float32)[:n])
 
 
+# the latent kernel's unit of work is a fetched group of pages (8 here: 64
+# positions at a page of 8, in a table of 20 pages = 2.5 groups): contexts
+# by where they end, a name a case
+_KPS, _KNP, _KN = 8, 20, 61
+LATENT_LENGTHS = {
+    "inside_a_page": [5, 37, 83],
+    "on_a_pages_last_row": [8, 16, 120],
+    "inside_a_groups_last_page": [60, 62, 125],
+    "on_a_groups_last_row": [64, 128, 64],
+    "one_position_into_a_new_group": [65, 129, 65],
+    "at_the_tables_last_page": [153, 159, 160],
+    "a_dead_row_beside_live_ones": [5, 0, 41],
+    "all_rows_dead": [0, 0, 0],
+    "rows_of_different_group_counts": [3, 64, 65, 130, 160],
+}
+
+
+def _latent_case(dtype, B, H=4, W=128):
+    ks = jax.random.split(jax.random.PRNGKey(0), 2)
+    pool = jax.random.normal(ks[0], (_KN, _KPS, W), jnp.float32).astype(dtype)
+    q = jax.random.normal(ks[1], (B, H, W), jnp.float32).astype(dtype)
+    # rows share pages, as slots behind one prefix do
+    bt = jnp.asarray(np.stack([
+        np.random.RandomState(b).permutation(np.arange(1, _KN))[:_KNP]
+        for b in range(B)]), jnp.int32)
+    return pool, q, bt, (1e-5 if dtype == jnp.float32 else 3e-2)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_latent_kernel_in_interpret_mode_matches_jnp(dtype):
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    Bq, H, W, ps, nP, N = 3, 4, 128, 8, 6, 40
-    pool = jax.random.normal(ks[0], (N, ps, W), jnp.float32).astype(dtype)
-    q = jax.random.normal(ks[1], (Bq, H, W), jnp.float32).astype(dtype)
-    bt = jnp.asarray(np.random.RandomState(0).permutation(
-        np.arange(1, N))[:Bq * nP].reshape(Bq, nP), jnp.int32)
-    ln = jnp.asarray([5, 0, 41], jnp.int32)  # one row dead, one past a group
-    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+@pytest.mark.parametrize("case", sorted(LATENT_LENGTHS))
+def test_latent_kernel_in_interpret_mode_matches_jnp(dtype, case):
+    lengths = LATENT_LENGTHS[case]
+    pool, q, bt, tol = _latent_case(dtype, len(lengths))
+    ln = jnp.asarray(lengths, jnp.int32)
     for dv in (128, 32):
         got = latent_paged_attention(q, pool, bt, ln, dv=dv, scale=0.2)
         want = latent_attention_reference(q, pool, bt, ln, dv=dv, scale=0.2)
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32), atol=tol)
-        assert not np.asarray(got[1], np.float32).any()  # the dead row
-    C, start = 20, 13  # a chunk that is no whole number of tiles of 16
-    qc = jax.random.normal(ks[2], (C, 64, W), jnp.float32).astype(dtype)
+        for b, n in enumerate(lengths):
+            if n == 0:  # a dead row walks nothing and comes out as zeros
+                assert not np.asarray(got[b], np.float32).any()
+
+
+# (chunk positions, heads, the first one's position): tiles are 1024 // heads
+# positions, so 64 heads make tiles of 16 and 4 heads one tile of the chunk
+LATENT_CHUNKS = {
+    "no_whole_number_of_tiles": (20, 64, 13),
+    "from_position_zero": (24, 64, 0),
+    "starts_on_a_group": (16, 64, 64),
+    "ends_on_a_groups_last_row": (32, 64, 32),
+    "ends_at_the_tables_last_row": (40, 64, 120),
+    "one_tile_across_a_group": (40, 4, 50),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", sorted(LATENT_CHUNKS))
+def test_latent_block_kernel_in_interpret_mode_matches_jnp(dtype, case):
+    C, H, start = LATENT_CHUNKS[case]
+    pool, qc, bt, tol = _latent_case(dtype, C, H=H)
     got = latent_paged_attention_block(
-        qc, pool, bt[2], jnp.int32(start), dv=128, scale=0.2)
+        qc, pool, bt[0], jnp.int32(start), dv=128, scale=0.2)
     want = latent_attention_reference(
-        qc, pool, jnp.tile(bt[2][None], (C, 1)), start + 1 + jnp.arange(C),
+        qc, pool, jnp.tile(bt[0][None], (C, 1)), start + 1 + jnp.arange(C),
         dv=128, scale=0.2)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_position_reads_the_same_as_a_decode_row_and_inside_a_chunk(dtype):
+    """Both callers go through one body, and a row's groups past its own
+    last position are exact no-ops, so a position computed inside a chunk's
+    tile is the decode row of the same position. On the parent (a page a
+    product) the two were bitwise alike in interpret mode in both dtypes;
+    a group a product keeps that in float32, and in bfloat16 to the
+    output's last place (the CPU's bfloat16 product sums in an order that
+    depends on how many rows it is given; 1.9e-6 on values of 1e-4)."""
+    C, H, start = 40, 4, 61
+    pool, qc, bt, _ = _latent_case(dtype, C, H=H)
+    blk = latent_paged_attention_block(
+        qc, pool, bt[0], jnp.int32(start), dv=128, scale=0.2)
+    dec = latent_paged_attention(
+        qc, pool, jnp.tile(bt[0][None], (C, 1)),
+        start + 1 + jnp.arange(C, dtype=jnp.int32), dv=128, scale=0.2)
+    blk, dec = np.asarray(blk, np.float32), np.asarray(dec, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_array_equal(blk, dec)
+    else:
+        np.testing.assert_allclose(blk, dec, rtol=2 ** -7, atol=0)
 
 
 def test_int8_weights_serve_the_same_logits_to_rounding():

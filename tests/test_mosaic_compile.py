@@ -169,13 +169,30 @@ def test_selected_page_kernel_compiles_for_v5e_at_g16(one_chip):
     assert _pallas_grid(call, *args) == (B * K, 1)
 
 
-# rows of qt positions x H heads against a latent pool of `pages` pages of
-# ps rows of W lanes, values the leading dv: Moonlight's cell (32 slots x
-# 64 page slots x 27 layers), its decode rows and its 256-token chunk
+# B decode rows and a chunk of T positions, H heads, against a latent pool
+# of `pages` pages of ps rows of W lanes, values the leading dv: Moonlight's
+# cell (32 slots x 64 page slots x 27 layers), its decode rows, its
+# 256-token chunk, and the merged dispatch's pair of them
 LATENT = {
-    "moonlight_decode_32_slots": (32, 1, 16, 640, 512, 64, 64, 27 * 2049),
-    "moonlight_chunk_256": (1, 256, 16, 640, 512, 64, 64, 27 * 2049),
+    "moonlight_decode_32_slots": (32, 0, 16, 640, 512, 64, 64, 27 * 2049),
+    "moonlight_chunk_256": (0, 256, 16, 640, 512, 64, 64, 27 * 2049),
+    "moonlight_merged_32_and_256": (32, 256, 16, 640, 512, 64, 64, 27 * 2049),
 }
+# what a program of each kernel may take of the 16 MiB of fast memory that
+# Mosaic gives a kernel unasked (no vmem_limit_bytes is stated): the decode
+# program's group and its [16, 512] scores read 1.76 MB, the chunk's tile
+# of 1024 rows with its [1024, 512] float32 scores 12.9 MB
+LATENT_SCOPED_VMEM = {"latent_paged_attention": 2 << 20,
+                      "latent_paged_attention_block": 14 << 20}
+
+
+def _scoped_vmem(text):
+    """Fast memory each Mosaic kernel of a compiled program was given, by
+    the kernel's name, from the custom calls' ``used_scoped_memory_configs``."""
+    return {
+        m.group(1): int(m.group(2)) for m in re.finditer(
+            r"%(\w+)\.\d+ = [^\n]*tpu_custom_call[^\n]*"
+            r'used_scoped_memory_configs":\[\{[^\]]*"size":"(\d+)"', text)}
 
 
 @pytest.mark.parametrize("shape", sorted(LATENT))
@@ -183,7 +200,8 @@ def test_latent_kernel_compiles_for_v5e(one_chip, shape):
     """The kernel over a cache row with no head axis at the served shapes,
     under its own names: a program a sequence for decode, a program a tile
     of 64 positions (1024 query rows) for a chunk, whole pages copied by
-    the program itself."""
+    the program itself, a fetched group of 8 pages scored in one product:
+    the chunk's [1024, 512] float32 scores fit beside its accumulator."""
     from fei_tpu.ops.pallas.latent_paged_attention import (
         latent_paged_attention,
         latent_paged_attention_block,
@@ -194,26 +212,27 @@ def test_latent_kernel_compiles_for_v5e(one_chip, shape):
     def S(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    pool = S((pages, ps, W), jnp.bfloat16)
-    if T == 1:
-        args = [S((B, H, W), jnp.bfloat16), pool, S((B, slots), jnp.int32),
-                S((B,), jnp.int32)]
+    kw = dict(dv=dv, scale=192 ** -0.5, interpret=False)
+    args = [S((B + T, H, W), jnp.bfloat16), S((pages, ps, W), jnp.bfloat16),
+            S((B, slots), jnp.int32), S((B,), jnp.int32),
+            S((slots,), jnp.int32), S((), jnp.int32)]
 
-        def call(q, p, bt, ln):
-            return latent_paged_attention(
-                q, p, bt, ln, dv=dv, scale=192 ** -0.5, interpret=False)
-        name, grid = "latent_paged_attention.", (B,)
-    else:
-        args = [S((T, H, W), jnp.bfloat16), pool, S((slots,), jnp.int32),
-                S((), jnp.int32)]
+    def call(q, p, bt, ln, row, start):
+        # in models/deepseek.py::_read_both's order: the chunk's rows first
+        oc = latent_paged_attention_block(
+            q[B:], p, row, start, **kw) if T else None
+        od = latent_paged_attention(q[:B], p, bt, ln, **kw) if B else None
+        return jnp.concatenate([o for o in (od, oc) if o is not None])
 
-        def call(q, p, row, start):
-            return latent_paged_attention_block(
-                q, p, row, start, dv=dv, scale=192 ** -0.5, interpret=False)
-        name, grid = "latent_paged_attention_block.", (T * H // 1024,)
     compiled = jax.jit(call).lower(*args).compile()
-    assert name in compiled.as_text()
-    assert _pallas_grid(call, *args) == grid
+    vmem = _scoped_vmem(compiled.as_text())
+    want = (["latent_paged_attention"] if B else []) + (
+        ["latent_paged_attention_block"] if T else [])
+    assert sorted(vmem) == want
+    for name in want:
+        assert 0 < vmem[name] < LATENT_SCOPED_VMEM[name], (name, vmem)
+    if not (B and T):
+        assert _pallas_grid(call, *args) == ((B,) if B else (T * H // 1024,))
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
