@@ -149,9 +149,9 @@ class InferenceEngine:
                 "shared pages of the paged pool)"
             )
         self.prefix_cache = prefix_cache
-        if model_cfg.layer_kinds and not paged:
+        if model_cfg.has_state and not paged:
             raise EngineError(
-                f"{model_cfg.name} (layers of several kinds) is served from "
+                f"{model_cfg.name} (a recurrent state) is served from "
                 "pages and state only: paged=True"
             )
         if model_cfg.is_latent and not paged:
@@ -238,7 +238,7 @@ class InferenceEngine:
         if quantize == "int4" and has_axis(mesh, "tp"):
             int4_exclude = frozenset({"wo", "w_down"})
         tok = load_tokenizer(tokenizer)
-        if checkpoint_dir and (cfg.layer_kinds or cfg.is_latent):
+        if checkpoint_dir and (cfg.has_state or cfg.is_latent):
             raise ValueError(f"{cfg.name}: no checkpoint name map for its tree yet")
         if checkpoint_dir:
             from fei_tpu.engine.weights import load_checkpoint

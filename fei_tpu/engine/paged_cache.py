@@ -15,15 +15,22 @@ D=head dim):
   block_table:     [B, max_pages]     int32 page ids, row-ragged
   lengths:         [B]                int32 valid token count
 
-Two kinds of cache in one manager (models/sala.py: a model whose layers
-are of several kinds). Pages hold the keys and values of the attention
-layers only, and beside each page ``kc_pages`` [L, P, K, rows, D] float32:
-the compressed keys a block-sparse layer selects its pages from, indexed
-by page like the pages themselves, so a shared prefix page shares them
-too. The linear-attention layers keep no pages: ``state`` [Ll, B + 1, H, d,
-d] float32 is their recurrent state, one row a slot (indexed like
-``lengths``) and a last row for the admission in flight, which becomes the
-slot's when the slot is armed. Both are None for every other model.
+Two kinds of cache in one manager. Pages hold the keys and values of the
+layers that attend (``cfg.kv_layers``); a model whose layers are
+block-sparse (models/sala.py) keeps beside each page ``kc_pages`` [L, P,
+K, rows, D] float32, the compressed keys a layer selects its pages from,
+indexed by page like the pages themselves, so a shared prefix page shares
+them too. Layers with a recurrence (``cfg.state_layers``) keep ``state``:
+a block of one or several arrays, each ``[Ll, B + 1, ...]`` in its own
+shape and dtype, one row a slot (indexed like ``lengths``) and a last row
+for the admission in flight, which becomes the slot's when the slot is
+armed. Linear attention keeps one array ``[Ll, B + 1, H, d, d]`` float32
+and no pages in those layers; a state-space mixer beside attention
+(models/falcon_h1.py) keeps a ``MixerState`` in every layer, which has
+pages too. A snapshot is the same block without the slot axis. Whatever
+handles the block (``adopt_state``, ``load_state``, a replay, the prefix
+cache's snapshots, their bytes) maps over its arrays and asks no shape.
+Both are None for a model without such layers.
 
 A third kind: a model with latent attention (models/deepseek.py) keeps one
 row a token and layer with no head axis and no K/V pair, ``latent`` [L, P,
@@ -51,6 +58,26 @@ from fei_tpu.utils.errors import EngineError
 from fei_tpu.utils.metrics import METRICS
 
 
+class MixerState(NamedTuple):
+    """The state block of a state-space mixer (``ops/ssd.py``)."""
+
+    ssm: jnp.ndarray  # [L, B + 1, heads, d_head, d_state] float32
+    conv: jnp.ndarray  # [L, B + 1, taps - 1, channels]: the last inputs
+
+
+def state_row_bytes(state) -> int:
+    """Bytes of one row (a slot, or a snapshot) of a state block, over
+    all its layers and arrays; 0 for no state."""
+    return sum(a.size // a.shape[1] * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(state))
+
+
+def empty_snapshot(state):
+    """A snapshot of nothing: the state block without the slot axis, zero."""
+    return jax.tree_util.tree_map(
+        lambda a: jnp.zeros((a.shape[0], *a.shape[2:]), a.dtype), state)
+
+
 class PagedKVCache(NamedTuple):
     """Page pool + block tables. With ``kv_quant="int8"`` the pools store
     int8 with per-slot (per-token, per-head) fp32 scales — KV bytes halve,
@@ -72,7 +99,7 @@ class PagedKVCache(NamedTuple):
     k_scales: jnp.ndarray | None = None  # [L, P, K, 1, ps] fp32 (int8 mode)
     v_scales: jnp.ndarray | None = None
     kc_pages: jnp.ndarray | None = None  # [L, P, K, rows, D] fp32 (sparse)
-    state: jnp.ndarray | None = None  # [Ll, B + 1, H, d, d] fp32 (linear)
+    state: object = None  # arrays [Ll, B + 1, ...]: the recurrent layers'
     latent: jnp.ndarray | None = None  # [L, P, ps, W] (latent attention)
     # what the expert layers of the steps since the program began routed
     # (ops/moe.moe_held's stats, summed over layers and steps): a step
@@ -117,7 +144,7 @@ class PagedKVCache(NamedTuple):
         L, K, D = cfg.kv_layers, cfg.num_kv_heads, cfg.head_dim_
         shape = (L, num_pages, K, page_size, D)
         kc = state = None
-        if cfg.layer_kinds:
+        if cfg.sparse_block:
             if kv_quant or page_size != cfg.sparse_block:
                 raise EngineError(
                     f"{cfg.name}: a page is the model's block of "
@@ -127,9 +154,21 @@ class PagedKVCache(NamedTuple):
                 (L, num_pages, K, page_size // cfg.sparse_stride, D),
                 dtype=jnp.float32,
             )
+        if cfg.has_state and kv_quant:
+            raise EngineError(
+                f"{cfg.name}: pages beside a recurrent state stay unquantized")
+        rows = (cfg.state_layers, batch + 1)
+        if cfg.mamba_d_ssm:
+            state = MixerState(
+                ssm=jnp.zeros((*rows, cfg.mamba_n_heads, cfg.mamba_d_head,
+                               cfg.mamba_d_state), dtype=jnp.float32),
+                conv=jnp.zeros((*rows, cfg.mamba_d_conv - 1,
+                                cfg.mamba_conv_dim), dtype=dtype),
+            )
+        elif cfg.has_state:
             state = jnp.zeros(
-                (cfg.state_layers, batch + 1, cfg.lin_heads,
-                 cfg.lin_head_dim, cfg.lin_head_dim), dtype=jnp.float32,
+                (*rows, cfg.lin_heads, cfg.lin_head_dim, cfg.lin_head_dim),
+                dtype=jnp.float32,
             )
         pool_dtype = jnp.int8 if kv_quant == "int8" else dtype
         # two distinct arrays: a shared buffer would be donated twice when
@@ -162,24 +201,26 @@ def replace_lengths(pool: "PagedKVCache", lengths) -> "PagedKVCache":
 
 
 def adopt_state(pool: "PagedKVCache", slot) -> "PagedKVCache":
-    """The admission in flight becomes ``slot``'s: the recurrent state's
-    last row is copied to the slot's row (beside arming its table row)."""
-    st = pool.state
-    row = jax.lax.dynamic_slice_in_dim(st, st.shape[1] - 1, 1, axis=1)
-    return pool._replace(
-        state=jax.lax.dynamic_update_slice_in_dim(st, row, slot, axis=1)
-    )
+    """The admission in flight becomes ``slot``'s: the last row of each
+    of the state's arrays is copied over the slot's row whole (beside
+    arming its table row), so nothing of the slot's previous stream
+    stays."""
+    def adopt(st):
+        row = jax.lax.dynamic_slice_in_dim(st, st.shape[1] - 1, 1, axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(st, row, slot, axis=1)
+
+    return pool._replace(state=jax.tree_util.tree_map(adopt, pool.state))
 
 
-def load_state(pool: "PagedKVCache", snap: jnp.ndarray) -> "PagedKVCache":
-    """An admission resumes from a snapshot ([Ll, H, d, d]): it becomes
-    the state of the admission in flight (the last row)."""
-    st = pool.state
-    return pool._replace(
-        state=jax.lax.dynamic_update_slice_in_dim(
-            st, snap[:, None].astype(st.dtype), st.shape[1] - 1, axis=1
-        )
-    )
+def load_state(pool: "PagedKVCache", snap) -> "PagedKVCache":
+    """An admission resumes from a snapshot (the state's block without the
+    slot axis): it becomes the state of the admission in flight (the last
+    row)."""
+    def load(st, sn):
+        return jax.lax.dynamic_update_slice_in_dim(
+            st, sn[:, None].astype(st.dtype), st.shape[1] - 1, axis=1)
+
+    return pool._replace(state=jax.tree_util.tree_map(load, pool.state, snap))
 
 
 def quant_kv_rows(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:

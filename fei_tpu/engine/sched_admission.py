@@ -232,7 +232,7 @@ class AdmissionMixin:
                 if (
                     prefix or n_tok > self.prefill_chunk or seq.generated
                     # pages (and state) only: no dense prefill
-                    or self._hybrid or self._latent
+                    or self._stateful or self._latent
                 ) and not sp_long:
                     self._start_chunked(seq, slot, prefix)
                     return  # one chunked admission at a time
@@ -434,7 +434,7 @@ class AdmissionMixin:
             "row": self._slot_row(slot),
             "pos": m * self.engine.page_size, "prefix": m,
         }
-        if self._hybrid:
+        if self._stateful:
             self._resume_state(self._admitting, seq, m)
         self._admit_chunk()
 
@@ -519,7 +519,7 @@ class AdmissionMixin:
                             jnp.asarray(st["row"]), jnp.int32(st["slot"]),
                             jnp.asarray(lo, dtype=jnp.int32),
                             # a recurrent state must not take in the padding
-                            *((jnp.int32(hi - lo),) if self._hybrid else ()),
+                            *((jnp.int32(hi - lo),) if self._stateful else ()),
                         )
                     # no host sync: the replayed pool stays on device
                     t_issue = time.perf_counter()
@@ -577,7 +577,7 @@ class AdmissionMixin:
         eng = self.engine
         C = toks.shape[1]
         extra, snap_pages = (), 0
-        if self._hybrid:
+        if self._stateful:
             off, snap_pages = self._snap_offset(st, lo, C)
             extra = (jnp.int32(off),)
         t0 = time.perf_counter()
@@ -590,10 +590,10 @@ class AdmissionMixin:
                 # the index of the prompt's last token in the chunk; with a
                 # recurrent state also how many of the chunk's tokens are
                 # real, which on a resume's prompt walk ends at ``hi``
-                jnp.int32((hi if self._hybrid else n) - 1 - lo), *extra,
+                jnp.int32((hi if self._stateful else n) - 1 - lo), *extra,
             )
             t_issue = time.perf_counter()
-            if self._hybrid:
+            if self._stateful:
                 *out, snap = out
                 out = out if final else out[0]
                 if snap_pages:
@@ -748,16 +748,18 @@ class AdmissionMixin:
                         params, cfg, tokens, carry, kernel_mesh=mesh
                     )
                     if n_real:  # padding behind the suffix leaves the state
-                        new = new._replace(state=jnp.where(
-                            i < n_real[0], new.state, carry.state))
+                        new = new._replace(state=jax.tree_util.tree_map(
+                            lambda a, b: jnp.where(i < n_real[0], a, b),
+                            new.state, carry.state))
                     return new, None
 
                 view, _ = jax.lax.scan(
                     body, view, (toks, jnp.arange(toks.shape[0])))
                 if pool.state is not None:
-                    built = jax.lax.dynamic_index_in_dim(
-                        view.state, slot, axis=1, keepdims=False
-                    )
+                    built = jax.tree_util.tree_map(
+                        lambda a: jax.lax.dynamic_index_in_dim(
+                            a, slot, axis=1, keepdims=False),
+                        view.state)
                     view = load_state(view._replace(state=pool.state), built)
                 return view._replace(block_table=bt0, lengths=ln0)
 
@@ -815,7 +817,7 @@ class AdmissionMixin:
             self._pool, jnp.asarray(row), jnp.int32(slot),
             jnp.asarray(n, dtype=jnp.int32),
         )
-        if self._hybrid:
+        if self._stateful:
             # the finished admission's state (the state's last row) becomes
             # the slot's, beside its armed table row
             self._move_state("adopt_state", jnp.int32(slot))

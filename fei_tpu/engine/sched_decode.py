@@ -200,7 +200,7 @@ class DecodeMixin:
         for _, s in active:
             if s.mask_fn is not None:
                 return False
-            if self._hybrid and s.grammar is not None and s.gstate < 0:
+            if self._stateful and s.grammar is not None and s.gstate < 0:
                 # the fused free phase rolls a slot back mid-scan, which a
                 # recurrent state cannot follow: such slots step one token
                 return False
@@ -376,17 +376,17 @@ class DecodeMixin:
                 jnp.asarray(pc["toks"]),
                 jnp.asarray(pc["st"]["row"][None]),
                 jnp.asarray([pc["lo"]], dtype=jnp.int32),
-                jnp.int32((pc["hi"] if self._hybrid else pc["ntok"])
+                jnp.int32((pc["hi"] if self._stateful else pc["ntok"])
                           - 1 - pc["lo"]),
             ] + args[2:]
-            if self._hybrid:
+            if self._stateful:
                 off, pc["snap_pages"] = self._snap_offset(
                     pc["st"], pc["lo"], pc["toks"].shape[1])
                 kw["csnap"] = jnp.int32(off)
             with METRICS.span("decode_step", jax_trace=True):
                 res = self._device_call("ragged merged dispatch", step,
                                         *rargs, **kw)
-                if self._hybrid:
+                if self._stateful:
                     *res, pc["snap"] = res
                 if pc["final"]:
                     (chunk_logits, nxt, self._step_keys, self._pool,
@@ -426,8 +426,9 @@ class DecodeMixin:
                 "ragged": True, "chunk_tokens": pc["hi"] - pc["lo"],
                 "chunk_rid": pc["st"]["seq"].rid, "chunk_lo": pc["lo"],
             })
-            # a hybrid's or a latent pool's merged step calls no ragged kernel
-            if not (self._hybrid or self._latent):
+            # block-sparse layers' or a latent pool's merged step calls no
+            # ragged kernel
+            if not (self._sparse or self._latent):
                 extra["attn_steps"] = math.prod(ragged_grid_of(
                     self.B, pc["toks"].shape[1], cfg.num_kv_heads // tp,
                     cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_,
@@ -436,12 +437,16 @@ class DecodeMixin:
                 ))
             METRICS.incr("engine.ragged_dispatches")
             METRICS.gauge("engine.kernel_loop_depth", n * eng.cfg.num_layers)
-        elif not self._hybrid:  # its pages are the record's sel_pages
+        elif not self._sparse:  # its pages are the record's sel_pages
             extra["attn_pages"] = sum(
                 pages_walked(c, eng.page_size, eng.cfg.sliding_window or 0)
                 for c in ctx
             )
-        if self._hybrid:
+        if self._stateful:
+            # live rows whose state the dispatch's steps read and wrote
+            extra["state_rows"] = len(active) * n
+            METRICS.gauge("state.live_bytes", len(active) * self._state_row)
+        if self._sparse:
             # pages one sparse layer reads a kv head at the first step,
             # and pages its contexts hold, over the active slots: a query
             # with topk blocks or fewer behind it reads them all
@@ -598,7 +603,7 @@ class DecodeMixin:
             fam = family(cfg)
             _logits, forward_paged = fam._logits, fam.forward_paged
             forward_paged_merged = fam.forward_paged_merged
-            hybrid = self._hybrid
+            stateful = self._stateful
             latent = self._latent
 
             def ragged(params, pool, ctoks, crow, cpos, clast, tokens,
@@ -606,7 +611,7 @@ class DecodeMixin:
                        gremain=None, table=None, mind=None, csnap=None):
                 sampler = _make_sampler(grammared, False)
                 pool = _route_begin(pool)
-                if hybrid:
+                if stateful:
                     # the chunk's real tokens and where it snapshots the
                     # recurrent state go in; the snapshot comes out last
                     chunk_hidden, logits, pool, snap = forward_paged_merged(
@@ -668,7 +673,7 @@ class DecodeMixin:
                     keys_out = new_keys
                 out = (_route_ride(jnp.swapaxes(toks, 0, 1), pool), step_keys,
                        pool, keys_out)
-                if hybrid:
+                if stateful:
                     out = out + (snap,)
                 if not final:
                     return out

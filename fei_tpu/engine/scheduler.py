@@ -334,15 +334,20 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         # the loop thread between dispatches — the donated pool is
         # single-owner state and must never race a dispatch
         self._ctl: deque = deque()
-        # a model whose layers are of several kinds (models/sala.py): its
-        # linear layers' state rides beside the pages (PagedKVCache.state)
-        # through admission, decode, preemption and the prefix cache. What
-        # moves pages without that state refuses the model here, at build.
-        self._hybrid = bool(engine.cfg.layer_kinds)
-        if self._hybrid:
+        # a model with a recurrent state (``cfg.has_state``: some or all of
+        # its layers keep a fixed-size state a sequence, beside pages or
+        # instead of them): the state block rides beside the pages
+        # (PagedKVCache.state) through admission, decode, preemption and
+        # the prefix cache. What moves pages without that state refuses the
+        # model here, at build.
+        self._stateful = engine.cfg.has_state
+        if self._stateful:
             self._refuse_what_moves_pages(
-                "layers of several kinds",
-                "spills and streams pages without the linear layers' state")
+                "a recurrent state",
+                "spills and streams pages without the layers' state")
+        # block-sparse attention layers read a selection of a context's
+        # pages: their dispatch records carry the selection's counts
+        self._sparse = engine.cfg.sparse_block > 0
         # a model with latent attention (models/deepseek.py): its cache row
         # has no head axis and no K/V pair. Pages, tables and the prefix
         # cache are as ever; what spells K and V pages a head refuses it.
@@ -352,6 +357,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
                 "a latent pool",
                 "spills and streams K and V pages a head (kv/pagesio.py)")
         self._state_jit: dict = {}  # adopt_state / load_state, jitted
+        self._state_row = 0  # bytes of one slot's row of the state block
 
     def _refuse_what_moves_pages(self, kind: str, tier_why: str) -> None:
         """A model served from pages (and state) only: one line at build
@@ -860,10 +866,10 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
                 done.set()
 
     def _refuse_migration(self) -> None:
-        if self._hybrid:
+        if self._stateful:
             raise EngineError(
                 f"{self.engine.cfg.name}: migration moves pages without "
-                "the linear layers' state"
+                "the layers' recurrent state"
             )
         if self._latent:
             raise EngineError(
@@ -1823,21 +1829,47 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
                 self._pool = self.engine._ensure_pool()
                 self.engine._pool = None  # scheduler owns the arrays now
                 self._keys = jnp.zeros((self.B, 2), dtype=jnp.uint32)
-                if self.engine.prefix_cache and self._prefix is None:
-                    from fei_tpu.engine.paged_cache import PrefixCache
+                from fei_tpu.engine.paged_cache import (
+                    PrefixCache,
+                    empty_snapshot,
+                    state_row_bytes,
+                )
 
-                    # snapshots of the recurrent state: two a slot (a
-                    # live conversation's newest and what it grew from)
-                    st = self._pool.state
-                    one = 0 if st is None else st[:, 0].size * st.dtype.itemsize
+                self._state_row = state_row_bytes(self._pool.state)
+                if self.engine.prefix_cache and self._prefix is None:
                     # an entry a page boundary: as many as the pool has pages
                     # (a context of tens of thousands of tokens registers
                     # hundreds of boundaries)
                     self._prefix = PrefixCache(
                         self.engine._allocator,
                         max_entries=max(512, self.engine._allocator.num_pages),
-                        state_bytes=one, state_budget=2 * self.B * one,
+                        state_bytes=self._state_row,
+                        state_budget=self._snapshot_budget(),
                     )
+                    if self._stateful:
+                        # the first resume from a snapshot loads it under
+                        # load: its program is made here, on an empty one
+                        self._move_state(
+                            "load_state", empty_snapshot(self._pool.state))
+
+    def _snapshot_budget(self) -> int:
+        """Bytes the prefix cache may keep in snapshots of the recurrent
+        state. A model with state wants two a slot (a live conversation's
+        newest and what it grew from); it gets no more than a quarter of
+        what the device has left once weights, pages and the state block
+        are there, the rest being the step programs' to work in. A row of
+        tens of megabytes at tens of slots wants gigabytes, and the device
+        bounds it; a few slots are bounded by their own two each. Where the
+        device reports no memory (the CPU), the slots' bound alone."""
+        want = 2 * self.B * self._state_row
+        if not want:
+            return 0
+        dev = next(iter(self._pool.lengths.devices()))
+        stats = dev.memory_stats() or {}
+        if "bytes_limit" not in stats:
+            return want
+        left = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+        return min(want, max(left, 0) // 4)
 
     @staticmethod
     def _device_call(what: str, fn, *args, **kw):

@@ -22,8 +22,17 @@ tiny preset for hermetic CPU tests beside its published sizes:
   ``models/deepseek.py``, a stack for the dense layers and one for the
   expert layers.
 
-``models.family(cfg)`` gives the module whose step functions serve a
-configuration.
+- Falcon-H1 (``falcon-h1-34b``, ``tiny-falcon-h1``): every layer runs a
+  Mamba-2 mixer and grouped-query attention side by side on one normed
+  input and sums them, then a SwiGLU; so every layer keeps pages AND a
+  recurrent state (the scan state and the convolution's last inputs), and
+  nearly every product carries a muP multiplier: ``models/falcon_h1.py``,
+  one block scanned over one stacked tree.
+
+Which caches a model keeps is read off two properties and nothing else:
+``kv_layers`` (layers with pages) and ``state_layers`` (layers with a
+fixed-size recurrent state a sequence; ``has_state``). ``models.family(cfg)``
+gives the module whose step functions serve a configuration.
 """
 
 from __future__ import annotations
@@ -126,6 +135,37 @@ class ModelConfig:
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
     expert_share: tuple = (0, 1)
+    # State-space mixer (Mamba-2, models/falcon_h1.py; mamba_d_ssm > 0):
+    # ``mamba_n_heads`` heads of ``mamba_d_head`` channels (``mamba_d_ssm``
+    # in all), each with a state of [mamba_d_head, mamba_d_state] float32 a
+    # sequence; B and C of ``mamba_d_state`` are shared by the heads of one
+    # of ``mamba_n_groups`` groups; a causal depthwise convolution of
+    # ``mamba_d_conv`` taps runs over x, B and C before the recurrence; an
+    # admission chunk goes through the chunked form ``mamba_chunk_size``
+    # positions at a time; ``mamba_rms_norm``: the gated output is RMS
+    # normed within each group's channels. Every layer of such a model has
+    # the mixer beside its attention.
+    mamba_d_ssm: int = 0
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 128
+    mamba_rms_norm: bool = True
+    # that family's muP multipliers: on the embedding's rows, the logits,
+    # the attention's input and output, the keys, the mixer's input and
+    # output, the five segments of the mixer's projection (z, x, B, C, dt)
+    # and the MLP's gate and down products
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: tuple = (1.0, 1.0)
     # tokenizer/bos/eos defaults (overridden by a real tokenizer when loaded)
     bos_token_id: int = 1
     eos_token_id: int = 2
@@ -176,8 +216,22 @@ class ModelConfig:
 
     @property
     def state_layers(self) -> int:
-        """Layers whose cache is a fixed-size recurrent state a sequence."""
+        """Layers whose cache is a fixed-size recurrent state a sequence
+        (beside pages, where the layer also attends)."""
+        if self.mamba_d_ssm:
+            return self.num_layers
         return sum(k == "lightning-attn" for k in self.layer_kinds)
+
+    @property
+    def has_state(self) -> bool:
+        """Some layer keeps a recurrent state: the model is served from
+        pages and state only, and what moves pages alone refuses it."""
+        return self.state_layers > 0
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the mixer's convolution runs over: x, B and C."""
+        return self.mamba_d_ssm + 2 * self.mamba_n_groups * self.mamba_d_state
 
     def num_params(self) -> int:
         """Approximate parameter count (for memory planning)."""
@@ -188,6 +242,9 @@ class ModelConfig:
             attn += self.num_heads * d + 2 * self.num_kv_heads * d
         if self.o_bias:
             attn += h
+        if self.mamba_d_ssm:  # the mixer's two projections, beside attention
+            attn += h * (self.mamba_d_ssm + self.mamba_conv_dim
+                         + self.mamba_n_heads) + self.mamba_d_ssm * h
         if self.is_latent:
             # what this program holds: its share of the routed experts
             r, dn, dr = self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim
@@ -410,6 +467,41 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         v_head_dim=16, first_dense_layers=1, moe_intermediate_size=32,
         num_experts=8, num_experts_per_tok=3, num_shared_experts=2,
         norm_topk_prob=True, routed_scaling_factor=2.5,
+    ),
+    # Falcon-H1-34B-Instruct (tiiuae, 2025-05; config.json of
+    # tiiuae/Falcon-H1-34B-Instruct, model_type falcon_h1): 72 layers, each
+    # a Mamba-2 mixer (32 heads x 128, state 256, 2 groups) beside 20
+    # query heads over 4 kv heads on one normed input, then a SwiGLU
+    "falcon-h1-34b": ModelConfig(
+        name="falcon-h1-34b", vocab_size=261120, hidden_size=5120,
+        intermediate_size=21504, num_layers=72, num_heads=20, num_kv_heads=4,
+        head_dim=128, rope_theta=1e11, rms_norm_eps=1e-5, max_seq_len=262144,
+        mamba_d_ssm=4096, mamba_n_heads=32, mamba_d_head=128,
+        mamba_d_state=256, mamba_n_groups=2, mamba_d_conv=4,
+        mamba_chunk_size=128, mamba_rms_norm=True,
+        embedding_multiplier=5.656854249492381, lm_head_multiplier=0.0078125,
+        attention_in_multiplier=1.0, attention_out_multiplier=0.0375,
+        key_multiplier=0.011048543456039804, ssm_in_multiplier=0.25,
+        ssm_out_multiplier=0.08838834764831845,
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+    ),
+    # the same family at test size: 10 query heads over 2 kv heads (5 rows
+    # a kv head, as published), 2 groups, a state twice the head, and every
+    # multiplier a value of its own
+    "tiny-falcon-h1": ModelConfig(
+        name="tiny-falcon-h1", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=3, num_heads=10, num_kv_heads=2,
+        head_dim=16, rope_theta=10000.0, rms_norm_eps=1e-5, max_seq_len=512,
+        mamba_d_ssm=64, mamba_n_heads=4, mamba_d_head=16, mamba_d_state=32,
+        mamba_n_groups=2, mamba_d_conv=4, mamba_chunk_size=8,
+        mamba_rms_norm=True,
+        embedding_multiplier=1.7, lm_head_multiplier=0.6,
+        attention_in_multiplier=0.9, attention_out_multiplier=0.45,
+        key_multiplier=0.35, ssm_in_multiplier=0.8, ssm_out_multiplier=0.55,
+        ssm_multipliers=(0.75, 1.3, 0.65, 1.2, 0.85),
+        mlp_multipliers=(0.7, 0.5),
     ),
     "qwen2-0.5b": ModelConfig(
         name="qwen2-0.5b", vocab_size=151936, hidden_size=896,

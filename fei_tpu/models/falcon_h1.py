@@ -1,0 +1,388 @@
+"""Falcon-H1 family: every layer keeps pages AND a recurrent state.
+
+One block, the same for every layer: a norm, then a Mamba-2 mixer
+(``ops/ssd.py``) and grouped-query attention side by side on that one
+normed input, summed into the residual; then a norm and a SwiGLU. Nearly
+every product carries a muP multiplier (``ModelConfig``'s fourteen).
+
+- Attention: keys and values in the paged pool, through the paged kernels
+  every other family's pages go through (``ops/pallas``); rotation on the
+  whole head; the keys times ``key_multiplier``.
+- Mixer: one projection to z, x, B, C and dt (each segment times its own
+  multiplier), a causal depthwise convolution over x, B and C, the
+  selective recurrence with a decay a token and head, a gate by silu(z),
+  an RMS norm within each group's channels, the output projection. Its
+  cache is ``PagedKVCache.state``, a ``MixerState``: the scan state
+  ``[L, B + 1, heads, d_head, d_state]`` float32 and the convolution's
+  last inputs ``[L, B + 1, taps - 1, channels]``, a row a slot and a last
+  row for the admission in flight.
+
+The layers run as ONE scan over the stacked weights (``llama._scan_pool``):
+the pool rides the carry viewed flat, the state block beside it, both
+written where they lie. The step functions are those the paged scheduler
+calls for ``models/sala.py``: ``forward_paged`` (one decode token a slot),
+``forward_chunk`` (one admission chunk of one slot) and
+``forward_paged_merged`` (both in one program, the weights streamed once,
+one ragged attention call for the two sides).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from fei_tpu.engine.paged_cache import MixerState, empty_snapshot
+from fei_tpu.models import llama
+from fei_tpu.models.configs import ModelConfig
+from fei_tpu.models.llama import (
+    _mlp_dense,
+    _norm,
+    _rope,
+    _scan_pool,
+    _write_rows,
+    merged_queries,
+    merged_rows,
+    model_dtype,
+    qkv_proj,
+)
+from fei_tpu.models.sala import _chunk_points
+from fei_tpu.ops import ssd
+from fei_tpu.ops.quant import mm, quantize as _quantize
+from fei_tpu.ops.rope import compute_rope_freqs
+
+_F32 = jnp.float32
+_LINEARS = ("wq", "wk", "wv", "wo", "ssm_in", "ssm_out",
+            "w_gate", "w_up", "w_down")
+
+
+def _layer_shapes(cfg: ModelConfig) -> dict:
+    h, I = cfg.hidden_size, cfg.intermediate_size
+    H, K, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    ds, nh, W = cfg.mamba_d_ssm, cfg.mamba_n_heads, cfg.mamba_conv_dim
+    return {
+        "attn_norm": (h,), "wq": (h, H * d), "wk": (h, K * d),
+        "wv": (h, K * d), "wo": (H * d, h),
+        "ssm_in": (h, ds + W + nh), "conv_w": (cfg.mamba_d_conv, W),
+        "conv_b": (W,), "dt_bias": (nh,), "A_log": (nh,), "ssm_D": (nh,),
+        "ssm_norm": (ds,), "ssm_out": (ds, h),
+        "mlp_norm": (h,), "w_gate": (h, I), "w_up": (h, I), "w_down": (I, h),
+    }
+
+
+def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
+                quantize: str | None = None,
+                int4_exclude: frozenset = frozenset()) -> dict:
+    """Random-init tree, one jitted program (``llama.init_params``'s
+    contract): ``layers`` (stacked), ``embed``, ``final_norm``,
+    ``lm_head``. ``quantize="int8"``: the big linears weight-only int8.
+    The decays start as Mamba-2 publishes them: ``A`` in 1..16, a step
+    ``dt`` of 0.001-0.1, a skip of 1."""
+    if quantize not in (None, "int8"):
+        raise ValueError(f"{cfg.name}: weights are bf16 or weight-only int8")
+    quant = quantize == "int8"
+    L = cfg.num_layers
+
+    def build(key):
+        def rnd(k, shape, fan_in, q):
+            w = jax.random.normal(k, shape, _F32) * fan_in ** -0.5
+            w = w.astype(dtype)
+            return _quantize(w) if q and quant else w
+
+        layers = {}
+        for name, shape in _layer_shapes(cfg).items():
+            key, sub = jax.random.split(key)
+            if name == "A_log":
+                layers[name] = jnp.log(jax.random.uniform(
+                    sub, (L, *shape), _F32, 1.0, 16.0)).astype(dtype)
+            elif name == "dt_bias":
+                dt = jnp.exp(jax.random.uniform(
+                    sub, (L, *shape), _F32, jnp.log(1e-3), jnp.log(1e-1)))
+                layers[name] = jnp.log(jnp.expm1(dt)).astype(dtype)
+            elif name == "conv_b":
+                layers[name] = jnp.zeros((L, *shape), dtype)
+            elif len(shape) == 1:
+                layers[name] = jnp.ones((L, *shape), dtype)
+            else:
+                layers[name] = rnd(sub, (L, *shape), shape[0],
+                                   name in _LINEARS)
+        key, k1, k2 = jax.random.split(key, 3)
+        h, V = cfg.hidden_size, cfg.vocab_size
+        return {
+            "layers": layers, "embed": rnd(k1, (V, h), h, False),
+            "final_norm": jnp.ones((h,), dtype),
+            "lm_head": rnd(k2, (h, V), h, True),
+        }
+
+    return jax.jit(build)(key)
+
+
+def embed_tokens(params, cfg, tokens, dtype):
+    x = llama.embed_tokens(params, cfg, tokens, dtype)
+    return x * jnp.asarray(cfg.embedding_multiplier, dtype)
+
+
+def _logits(x, params, cfg, kernel_mesh=None):
+    """LM head over final-normed hidden states, times its multiplier."""
+    return llama._logits(x, params, cfg, kernel_mesh) * cfg.lm_head_multiplier
+
+
+# -- attention --------------------------------------------------------------
+
+
+def _qkv(cfg, lp, y, positions, cos, sin):
+    """q, k, v of the block's normed input ``y`` [n, T, h], rotated; the
+    keys carry their multiplier into the pages."""
+    y = y * jnp.asarray(cfg.attention_in_multiplier, y.dtype)
+    q, k, v = qkv_proj(lp, y, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_)
+    k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
+    q = _rope(q, cos, sin, positions, cfg.rope_dim_)
+    k = _rope(k, cos, sin, positions, cfg.rope_dim_)
+    return q, k, v
+
+
+def _attn_out(cfg, lp, attn):
+    n, T = attn.shape[:2]
+    with jax.named_scope("attn_out"):
+        o = mm(attn.reshape(n, T, -1), lp["wo"])
+        return o.astype(_F32) * cfg.attention_out_multiplier
+
+
+# -- the mixer --------------------------------------------------------------
+
+
+@jax.named_scope("ssm_in")
+def _ssm_in(cfg, lp, y):
+    """y [n, T, h] -> z [n, T, d_ssm] float32, the convolution's input
+    [n, T, W] in y's dtype, dt [n, T, heads] float32: one projection, each
+    of its five segments (z, x, B, C, dt) times its own multiplier."""
+    ds, gn, nh = cfg.mamba_d_ssm, cfg.mamba_n_groups * cfg.mamba_d_state, \
+        cfg.mamba_n_heads
+    p = mm(y * jnp.asarray(cfg.ssm_in_multiplier, y.dtype), lp["ssm_in"])
+    m = jnp.concatenate([
+        jnp.full((width,), mult, _F32) for width, mult
+        in zip((ds, ds, gn, gn, nh), cfg.ssm_multipliers)])
+    p = p.astype(_F32) * m
+    return p[..., :ds], p[..., ds:-nh].astype(y.dtype), p[..., -nh:]
+
+
+def _split_conv(cfg, c):
+    """The convolution's output [..., W] -> x [..., heads, d_head], B and
+    C [..., groups, d_state]."""
+    ds, G, N = cfg.mamba_d_ssm, cfg.mamba_n_groups, cfg.mamba_d_state
+    lead = c.shape[:-1]
+    return (c[..., :ds].reshape(*lead, cfg.mamba_n_heads, cfg.mamba_d_head),
+            c[..., ds:ds + G * N].reshape(*lead, G, N),
+            c[..., ds + G * N:].reshape(*lead, G, N))
+
+
+def _decay(lp, dt):
+    """(dt after its bias and softplus, A = -exp(A_log), the skip D)."""
+    dt = jax.nn.softplus(dt + lp["dt_bias"].astype(_F32))
+    return dt, -jnp.exp(lp["A_log"].astype(_F32)), lp["ssm_D"].astype(_F32)
+
+
+def _ssm_tail(cfg, lp, y, z, dtype):
+    """Gate by silu(z), RMS norm within each group's channels, project
+    out. y: [n, T, heads, d_head] float32; z: [n, T, d_ssm] float32."""
+    n, T = y.shape[:2]
+    with jax.named_scope("ssm_gate"):
+        y = y.reshape(n, T, -1) * jax.nn.silu(z)
+        if cfg.mamba_rms_norm:
+            g = y.reshape(n, T, cfg.mamba_n_groups, -1)
+            g = g * jax.lax.rsqrt(
+                jnp.mean(g * g, axis=-1, keepdims=True) + cfg.rms_norm_eps)
+            y = g.reshape(n, T, -1) * lp["ssm_norm"].astype(_F32)
+    with jax.named_scope("ssm_out"):
+        o = mm(y.astype(dtype), lp["ssm_out"])
+        return o.astype(_F32) * cfg.ssm_out_multiplier
+
+
+def _row(a, l, row, n):
+    """Rows ``row`` .. ``row + n`` of layer ``l`` of one of the state's
+    arrays ``[L, B + 1, ...]``, read where they lie."""
+    at = (l, row) + (0,) * (a.ndim - 2)
+    return jax.lax.dynamic_slice(a, at, (1, n) + a.shape[2:])[0]
+
+
+def _put(a, rows, l, row):
+    """Write ``rows`` [n, ...] back as rows ``row`` on of layer ``l``."""
+    at = (l, row) + (0,) * (a.ndim - 2)
+    return jax.lax.dynamic_update_slice(a, rows[None].astype(a.dtype), at)
+
+
+def _mixer_decode(cfg, lp, y, l, st: MixerState):
+    """One token a slot: y [B, 1, h] against the slots' rows of layer
+    ``l``'s state. Returns (out [B, 1, h] float32, state)."""
+    B = y.shape[0]
+    z, u, dt = _ssm_in(cfg, lp, y)
+    with jax.named_scope("ssm_conv"):
+        c, last = ssd.conv_step(u[:, 0], _row(st.conv, l, 0, B),
+                                lp["conv_w"], lp["conv_b"])
+        conv = _put(st.conv, last, l, 0)
+    x, Bm, Cm = _split_conv(cfg, c)
+    with jax.named_scope("ssm_state"):
+        dt, A, D = _decay(lp, dt[:, 0])
+        o, S = ssd.step(x, dt, A, Bm, Cm, D, _row(st.ssm, l, 0, B))
+        ssm = _put(st.ssm, S, l, 0)
+    return _ssm_tail(cfg, lp, o[:, None], z, y.dtype), MixerState(ssm, conv)
+
+
+def _mixer_chunk(cfg, lp, y, l, st: MixerState, snap: MixerState, lo, points):
+    """``C`` positions of the admission in flight (the state's last row):
+    y [1, C, h] from position ``lo``. ``points``: int32 [2], (real tokens
+    of the chunk, where in it the snapshot is taken). Returns (out [1, C,
+    h] float32, state, snapshot with layer ``l``'s rows)."""
+    B = st.ssm.shape[1] - 1
+    z, u, dt = _ssm_in(cfg, lp, y)
+    fresh = lo == 0  # a sequence starts from nothing, whatever the row held
+    with jax.named_scope("ssm_conv"):
+        prev = _row(st.conv, l, B, 1)[0]
+        c, lasts = ssd.conv_chunk(
+            u[0], jnp.where(fresh, jnp.zeros_like(prev), prev),
+            lp["conv_w"], lp["conv_b"], points)
+    x, Bm, Cm = _split_conv(cfg, c)
+    with jax.named_scope("ssm_state"):
+        dt, A, D = _decay(lp, dt[0])
+        S0 = _row(st.ssm, l, B, 1)[0]
+        o, states = ssd.chunked(x, dt, A, Bm, Cm, D,
+                                jnp.where(fresh, 0.0, S0), points,
+                                cfg.mamba_chunk_size)
+    with jax.named_scope("state_carry"):
+        st = MixerState(_put(st.ssm, states[:1], l, B),
+                        _put(st.conv, lasts[:1], l, B))
+        snap = MixerState(
+            jax.lax.dynamic_update_slice(snap.ssm, states[1:], (l, 0, 0, 0)),
+            jax.lax.dynamic_update_slice(
+                snap.conv, lasts[1:].astype(snap.conv.dtype), (l, 0, 0)))
+    return _ssm_tail(cfg, lp, o[None], z, y.dtype), st, snap
+
+
+# -- the block and the three step functions ---------------------------------
+
+
+def _finish(cfg, lp, x, mix, attn):
+    """The residual takes mixer and attention together, then the MLP."""
+    x = x + (mix + _attn_out(cfg, lp, attn)).astype(x.dtype)
+    return x + _mlp_dense(cfg, _norm(x, lp["mlp_norm"], cfg), lp)
+
+
+def _rope_tables(cfg, cache):
+    max_pos = cache.block_table.shape[1] * cache.page_size
+    return compute_rope_freqs(cfg.rope_dim_, max_pos, cfg.rope_theta)
+
+
+def _final(x, params, cfg):
+    return _norm(x, params["final_norm"], cfg)
+
+
+def forward_paged(params, cfg: ModelConfig, tokens, cache,
+                  routed_moe: bool = False, moe_mesh=None, kernel_mesh=None):
+    """One decode token a slot against pages and state. Returns (logits
+    [B, 1, V], cache with lengths += 1)."""
+    from fei_tpu.ops.pallas import paged_attention
+
+    cos, sin = _rope_tables(cfg, cache)
+    bt, t = cache.block_table, cache.lengths
+    x = embed_tokens(params, cfg, tokens, model_dtype(params))
+
+    def body(carry, lp, base, kp, vp, ksc, vsc):
+        x, st, l = carry
+        y = _norm(x, lp["attn_norm"], cfg)
+        mix, st = _mixer_decode(cfg, lp, y, l, st)
+        q, k, v = _qkv(cfg, lp, y, t[:, None], cos, sin)
+        kp, vp, ksc, vsc = _write_rows(kp, vp, ksc, vsc, k, v, bt, t, base)
+        with jax.named_scope("attention"):
+            attn = paged_attention(q[:, 0], kp, vp, base + bt, t + 1)[:, None]
+        return (_finish(cfg, lp, x, mix, attn), st, l + 1), (kp, vp, ksc, vsc)
+
+    (x, st, _), cache = _scan_pool(
+        body, (x, cache.state, jnp.int32(0)), params, cache)
+    logits = _logits(_final(x, params, cfg), params, cfg)
+    return logits, cache._replace(state=st, lengths=t + 1)
+
+
+def forward_chunk(params, cfg: ModelConfig, toks, cache, row, pos, last_idx,
+                  snap_at, kernel_mesh=None):
+    """One admission chunk of one slot: ``toks`` [1, C] from the
+    page-aligned position ``pos`` [1] through the slot's table row ``row``
+    [1, nP]. ``last_idx``: the prompt's last token's index in the chunk
+    (at or past ``C``: the whole chunk is real); ``snap_at``: where in the
+    chunk the mixers' state is snapshot. Returns (final-normed hidden [1,
+    C, h], cache under its live table and lengths, snapshot: a
+    ``MixerState`` without the slot axis)."""
+    from fei_tpu.ops.pallas.paged_attention import paged_attention_block
+
+    C = toks.shape[1]
+    cos, sin = _rope_tables(cfg, cache)
+    positions = pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
+    points = _chunk_points(C, last_idx, snap_at)
+    x = embed_tokens(params, cfg, toks, model_dtype(params))
+
+    def body(carry, lp, base, kp, vp, ksc, vsc):
+        x, st, snap, l = carry
+        y = _norm(x, lp["attn_norm"], cfg)
+        mix, st, snap = _mixer_chunk(cfg, lp, y, l, st, snap, pos[0], points)
+        q, k, v = _qkv(cfg, lp, y, positions, cos, sin)
+        kp, vp, ksc, vsc = _write_rows(kp, vp, ksc, vsc, k, v, row, pos, base)
+        with jax.named_scope("attention"):
+            attn = paged_attention_block(q, kp, vp, base + row, pos)
+        return ((_finish(cfg, lp, x, mix, attn), st, snap, l + 1),
+                (kp, vp, ksc, vsc))
+
+    (x, st, snap, _), out = _scan_pool(
+        body, (x, cache.state, empty_snapshot(cache.state), jnp.int32(0)),
+        params, cache)
+    return _final(x, params, cfg), out._replace(state=st), snap
+
+
+def forward_paged_merged(params, cfg: ModelConfig, chunk_toks, chunk_row,
+                         chunk_pos, dec_tokens, cache, last_idx, snap_at,
+                         routed_moe: bool = False, moe_mesh=None,
+                         kernel_mesh=None):
+    """A prefill chunk AND a decode step through one pass over the layers:
+    each layer's weights are read once for both, and one ragged attention
+    call serves the decode rows and the chunk's rows
+    (``llama.forward_paged_merged`` has the call's layout). Returns (chunk
+    hidden [1, C, h] final-normed, decode logits [B, 1, V], cache with
+    lengths += 1, snapshot)."""
+    from fei_tpu.ops.pallas.ragged_paged_attention import ragged_paged_attention
+
+    B, C = dec_tokens.shape[0], chunk_toks.shape[1]
+    cos, sin = _rope_tables(cfg, cache)
+    bt, t = cache.block_table, cache.lengths
+    chunk_positions = chunk_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
+    points = _chunk_points(C, last_idx, snap_at)
+    R, nG, btv, limits, q_lens, modes = merged_rows(
+        cfg, cache, chunk_row, chunk_pos, C)
+    Cp = nG * R
+    dtype = model_dtype(params)
+    xc = embed_tokens(params, cfg, chunk_toks, dtype)
+    xd = embed_tokens(params, cfg, dec_tokens, dtype)
+
+    def body(carry, lp, base, kp, vp, ksc, vsc):
+        xc, xd, st, snap, l = carry
+        yc = _norm(xc, lp["attn_norm"], cfg)
+        yd = _norm(xd, lp["attn_norm"], cfg)
+        mixc, st, snap = _mixer_chunk(
+            cfg, lp, yc, l, st, snap, chunk_pos[0], points)
+        mixd, st = _mixer_decode(cfg, lp, yd, l, st)
+        qc, kc, vc = _qkv(cfg, lp, yc, chunk_positions, cos, sin)
+        qd, kd, vd = _qkv(cfg, lp, yd, t[:, None], cos, sin)
+        kp, vp, ksc, vsc = _write_rows(
+            kp, vp, ksc, vsc, kc, vc, chunk_row, chunk_pos, base)
+        kp, vp, ksc, vsc = _write_rows(kp, vp, ksc, vsc, kd, vd, bt, t, base)
+        qv = merged_queries(qd, qc, R, nG)
+        with jax.named_scope("attention"):
+            av = ragged_paged_attention(
+                qv, kp, vp, base + btv, limits, q_lens, modes)
+        xc = _finish(cfg, lp, xc, mixc,
+                     av[B:].reshape(1, Cp, *av.shape[2:])[:, :C])
+        xd = _finish(cfg, lp, xd, mixd, av[:B, :1])
+        return (xc, xd, st, snap, l + 1), (kp, vp, ksc, vsc)
+
+    (xc, xd, st, snap, _), out = _scan_pool(
+        body, (xc, xd, cache.state, empty_snapshot(cache.state), jnp.int32(0)),
+        params, cache)
+    logits = _logits(_final(xd, params, cfg), params, cfg)
+    return (_final(xc, params, cfg), logits,
+            out._replace(state=st, lengths=t + 1), snap)

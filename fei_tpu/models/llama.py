@@ -291,7 +291,8 @@ def _mlp_dense(cfg: ModelConfig, y, lp, kernel_mesh=None):
     """The dense (non-MoE) MLP: gated SwiGLU/GeGLU (w_gate*w_up -> w_down)
     for the Llama families, fc1 -> act -> fc2 with biases for Phi
     (cfg.mlp_gated=False; fc1/fc2 reuse the w_gate/w_down leaves so the
-    column/row sharding and quantization rules apply unchanged)."""
+    column/row sharding and quantization rules apply unchanged). A gated
+    MLP's gate and down products take ``cfg.mlp_multipliers``."""
     if not cfg.mlp_gated:
         a = _mm_k(y, lp["w_gate"], kernel_mesh)
         if "b_gate" in lp:
@@ -301,10 +302,15 @@ def _mlp_dense(cfg: ModelConfig, y, lp, kernel_mesh=None):
         if "b_down" in lp:
             out = out + lp["b_down"]
         return out
-    act = _mlp_act(
-        cfg, _mm_k(y, lp["w_gate"], kernel_mesh).astype(jnp.float32)
-    ).astype(y.dtype)
-    return mm(act * _mm_k(y, lp["w_up"], kernel_mesh), lp["w_down"])
+    gate = _mm_k(y, lp["w_gate"], kernel_mesh).astype(jnp.float32)
+    gate_mult, down_mult = cfg.mlp_multipliers  # muP (Falcon-H1); else 1
+    if gate_mult != 1.0:
+        gate = gate * gate_mult
+    act = _mlp_act(cfg, gate).astype(y.dtype)
+    out = mm(act * _mm_k(y, lp["w_up"], kernel_mesh), lp["w_down"])
+    if down_mult != 1.0:
+        out = (out.astype(jnp.float32) * down_mult).astype(out.dtype)
+    return out
 
 
 def _mlp_act(cfg: ModelConfig, gate):
@@ -735,6 +741,48 @@ def _forward_paged_block(
     return out, new_cache._replace(lengths=cache.lengths + T)
 
 
+def merged_rows(cfg: ModelConfig, cache, chunk_row, chunk_pos, C: int):
+    """The virtual rows of a merged dispatch's one ragged attention call:
+    the ``B`` decode rows, then the chunk of ``C`` positions as ``nG``
+    groups of ``R`` query positions (1 where the chunk fits the kernel's
+    tile, ``query_tile``). Returns (R, nG, the rows' tables [B + nG,
+    max_pages], their causal limits, their query lengths, their modes:
+    mode 1 rows re-run the online update at single-query shapes so the
+    decode side rounds exactly like the standalone qt=1 program)."""
+    from fei_tpu.ops.pallas.ragged_paged_attention import query_tile
+
+    B = cache.lengths.shape[0]
+    R = query_tile(C, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_)
+    nG = -(-C // R)
+    btv = jnp.concatenate(
+        [cache.block_table, jnp.tile(chunk_row, (nG, 1))], axis=0
+    )
+    group_starts = chunk_pos + jnp.arange(nG, dtype=jnp.int32) * R
+    limits = jnp.concatenate([cache.lengths + 1, group_starts + 1])
+    q_lens = jnp.concatenate([
+        jnp.ones((B,), dtype=jnp.int32),
+        jnp.clip(C - jnp.arange(nG, dtype=jnp.int32) * R, 0, R),
+    ])
+    modes = jnp.concatenate([
+        jnp.ones((B,), dtype=jnp.int32),
+        jnp.zeros((nG,), dtype=jnp.int32),
+    ])
+    return R, nG, btv, limits, q_lens, modes
+
+
+def merged_queries(qd, qc, R: int, nG: int):
+    """ONE query block for both sides of a merged dispatch: decode rows
+    qd [B, 1, Hq, d] padded to the R-row tile (pad rows compute garbage
+    never read), the chunk qc [1, C, Hq, d] padded to a whole number of
+    groups. Returns [B + nG, R, Hq, d]."""
+    C, Hq, d = qc.shape[1:]
+    return jnp.concatenate([
+        jnp.pad(qd, ((0, 0), (0, R - 1), (0, 0), (0, 0))),
+        jnp.pad(qc, ((0, 0), (0, nG * R - C), (0, 0), (0, 0)))
+        .reshape(nG, R, Hq, d),
+    ], axis=0)
+
+
 def forward_paged_merged(
     params: dict,
     cfg: ModelConfig,
@@ -775,41 +823,21 @@ def forward_paged_merged(
     path.
     """
     from fei_tpu.ops.pallas.ragged_paged_attention import (
-        query_tile,
         ragged_paged_attention,
         ragged_paged_attention_sharded,
     )
 
     B, _ = dec_tokens.shape
     _, C = chunk_toks.shape
-    K, d, Hq = cfg.num_kv_heads, cfg.head_dim_, cfg.num_heads
-    R = query_tile(C, Hq // K, d)
-    nG = -(-C // R)  # chunk groups of R query positions: 1 where C fits
-    Cp = nG * R
-    Bv = B + nG
     chunk_positions = chunk_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     dec_positions = cache.lengths[:, None]
     max_pos = cache.block_table.shape[1] * cache.page_size
     cos, sin = compute_rope_freqs(cfg.rope_dim_, max_pos, cfg.rope_theta)
     sharded = _kernel_sharded(kernel_mesh)
     win = cfg.sliding_window or 0
-
-    # per-virtual-row metadata: decode rows then chunk groups
-    btv = jnp.concatenate(
-        [cache.block_table, jnp.tile(chunk_row, (nG, 1))], axis=0
-    )
-    group_starts = chunk_pos + jnp.arange(nG, dtype=jnp.int32) * R
-    limits = jnp.concatenate([cache.lengths + 1, group_starts + 1])
-    q_lens = jnp.concatenate([
-        jnp.ones((B,), dtype=jnp.int32),
-        jnp.clip(C - jnp.arange(nG, dtype=jnp.int32) * R, 0, R),
-    ])
-    # mode=1 rows re-run the online update at single-query shapes so the
-    # decode side rounds exactly like the standalone qt=1 program
-    modes = jnp.concatenate([
-        jnp.ones((B,), dtype=jnp.int32),
-        jnp.zeros((nG,), dtype=jnp.int32),
-    ])
+    R, nG, btv, limits, q_lens, modes = merged_rows(
+        cfg, cache, chunk_row, chunk_pos, C)
+    Cp = nG * R
 
     kv_int8 = cache.k_scales is not None
     dtype = model_dtype(params) if kv_int8 else cache.k_pages.dtype
@@ -836,11 +864,7 @@ def forward_paged_merged(
         # ONE ragged invocation for both sides: decode rows padded to the
         # R-row tile (pad rows compute garbage never read), chunk padded
         # to a whole number of groups
-        qv = jnp.concatenate([
-            jnp.pad(qd, ((0, 0), (0, R - 1), (0, 0), (0, 0))),
-            jnp.pad(qc, ((0, 0), (0, Cp - C), (0, 0), (0, 0)))
-            .reshape(nG, R, Hq, d),
-        ], axis=0)  # [Bv, R, Hq, d]
+        qv = merged_queries(qd, qc, R, nG)  # [B + nG, R, Hq, d]
         with jax.named_scope("attention"):
             if sharded:
                 av = ragged_paged_attention_sharded(
@@ -853,7 +877,7 @@ def forward_paged_merged(
                     k_scales=ksc, v_scales=vsc, window=win,
                 )
         dec_attn = av[:B, :1]  # [B, 1, Hq, d]
-        chunk_attn = av[B:].reshape(1, Cp, Hq, d)[:, :C]
+        chunk_attn = av[B:].reshape(1, Cp, *av.shape[2:])[:, :C]
 
         xc = _block_tail(
             cfg, lp, xc, yc, chunk_attn, routed_moe, moe_mesh, kernel_mesh

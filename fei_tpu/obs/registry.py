@@ -124,6 +124,9 @@ METRIC_REGISTRY: dict[str, tuple[str, str]] = {
                                  "Snapshots dropped for the byte budget."),
     "state.snapshot_bytes": ("gauge",
                              "Bytes the registered snapshots hold."),
+    "state.live_bytes": ("gauge",
+                         "Bytes of the recurrent state's rows that belong "
+                         "to decoding slots."),
     "state.resumed_tokens": ("counter",
                              "Prompt tokens not recomputed because an "
                              "admission resumed from a snapshot."),

@@ -291,10 +291,11 @@ def shard_engine(engine, mesh: Mesh, weights: str = "sharded") -> None:
     single-chip, the psums reorder summation); "replicated" pins every
     weight to REPLICATED_RULES so sharded decode stays token-identical to
     the single-chip engine (the FEI_TPU_MESH serving default)."""
-    if engine.cfg.layer_kinds:
+    if engine.cfg.has_state:
         raise ValueError(
-            f"{engine.cfg.name}: no sharding rules for a tree with a stack "
-            "of weights a kind of layer, nor for the linear layers' state"
+            f"{engine.cfg.name}: no sharding rules for the layers' "
+            "recurrent state, nor for a tree with a stack of weights a "
+            "kind of layer"
         )
     if engine.cfg.is_latent:
         raise ValueError(

@@ -39,6 +39,12 @@ MOONLIGHT_SCOPES = (LAYER_SCOPES - {"rope"}) | {
     "rope", "latent_kv", "latent_absorb", "moe_route", "moe_experts",
     "moe_shared", "moe_combine"}
 
+# a state-space mixer beside attention in every layer (models/falcon_h1.py):
+# the mixer's five parts and the state's bookkeeping, beside every scope of
+# the attention block
+FALCON_SCOPES = LAYER_SCOPES | {
+    "ssm_in", "ssm_conv", "ssm_state", "ssm_gate", "ssm_out"}
+
 
 def _programs(model: str, **kw):
     engine = InferenceEngine.from_config(
@@ -60,8 +66,8 @@ def _programs(model: str, **kw):
              jnp.zeros((1,), jnp.int32), jnp.int32(0)]
     head = [engine.params, sched._pool]
     # with a recurrent state a chunk is also told where to snapshot it
-    snap = [jnp.int32(0)] if engine.cfg.layer_kinds else []
-    rsnap = {"csnap": jnp.int32(0)} if engine.cfg.layer_kinds else {}
+    snap = [jnp.int32(0)] if engine.cfg.has_state else []
+    rsnap = {"csnap": jnp.int32(0)} if engine.cfg.has_state else {}
     fns = {
         "multi": (sched._multi_fn(2, True), head + step, gram),
         "ragged": (sched._ragged_fn(2, C, True, True), head + chunk + step,
@@ -102,6 +108,25 @@ def moonlight_programs():
 ])
 def test_latent_step_programs_carry_every_scope(moonlight_programs, program, expected):
     fn, args, kw = moonlight_programs[program]
+    missing = expected - _scopes_in(fn, args, kw)
+    assert not missing, f"{program} lost scopes {sorted(missing)}"
+
+
+@pytest.fixture(scope="module")
+def falcon_programs():
+    engine, fns = _programs("tiny-falcon-h1", page_size=8)
+    yield fns
+    engine.close()
+
+
+@pytest.mark.parametrize("program,expected", [
+    ("multi", FALCON_SCOPES | {"lm_head", "sample", "grammar_mask"}),
+    ("ragged", FALCON_SCOPES | {"lm_head", "sample", "grammar_mask",
+                                "state_carry"}),
+    ("chunk", FALCON_SCOPES | {"lm_head", "state_carry"}),
+])
+def test_mixer_step_programs_carry_every_scope(falcon_programs, program, expected):
+    fn, args, kw = falcon_programs[program]
     missing = expected - _scopes_in(fn, args, kw)
     assert not missing, f"{program} lost scopes {sorted(missing)}"
 

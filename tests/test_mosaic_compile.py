@@ -294,7 +294,7 @@ def test_moonlight_decode_scan_fits_the_chip_and_moves_no_pool(
         engine=types.SimpleNamespace(
             cfg=cfg, mesh=None,
             _compiles=types.SimpleNamespace(wrap=lambda fam, key, fn: fn)),
-        _step_jit={}, _hybrid=False, _latent=True)
+        _step_jit={}, _stateful=False, _latent=True)
     sampling = [
         S((slots, 1), jnp.int32), S((slots, 2), jnp.uint32),
         S((slots,), jnp.float32), S((slots,), jnp.int32),
@@ -321,6 +321,65 @@ def test_moonlight_decode_scan_fits_the_chip_and_moves_no_pool(
     # them in the stack
     assert not re.search(r"= \w+\[(1,)?32,(2048,1408|1408,2048)\]", text)
     assert "latent_paged_attention." in text and "moe_grouped_matmul." in text
+
+
+@pytest.mark.parametrize("which", ["multi", "ragged"])
+def test_mixer_step_programs_fit_the_chip_and_copy_no_state(
+        one_chip, monkeypatch, which):
+    """``multi(8)`` and ``ragged(8, 256, final)`` of falcon-h1-34b (int8,
+    12 of its 72 layers: one stage of six) at the cell's shapes, 32 slots
+    of 2048 positions: both paged kernels compile at 5 query rows a kv
+    head, the program fits a 16 GB chip beside its arguments (9.2 GB of
+    weights, a 1.6 GB pool, 1.7 GB of state), and the state block rides
+    the layer scan and is written in place: no temporary has its size,
+    nor that of one layer's rows (139 MB)."""
+    from fei_tpu.engine.paged_cache import PagedKVCache, state_row_bytes
+    from fei_tpu.engine.sched_decode import DecodeMixin
+    from fei_tpu.models.configs import get_model_config
+    from fei_tpu.models.falcon_h1 import init_params
+
+    cfg = get_model_config("falcon-h1-34b", num_layers=12)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, width = 32, 2048 // 64
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree)
+
+    def S(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: init_params(cfg, k, quantize="int8"), jax.random.PRNGKey(0)))
+    pool = on_chip(jax.eval_shape(lambda: PagedKVCache.create(
+        cfg, slots * width + 1, slots, width, page_size=64)))
+    layer_rows = state_row_bytes(pool.state) * (slots + 1) // cfg.num_layers
+    assert layer_rows == 33 * (4194304 + 30720)
+    sched = types.SimpleNamespace(
+        engine=types.SimpleNamespace(
+            cfg=cfg, mesh=None,
+            _compiles=types.SimpleNamespace(wrap=lambda fam, key, fn: fn)),
+        _step_jit={}, _stateful=True, _latent=False)
+    sampling = [
+        S((slots, 1), jnp.int32), S((slots, 2), jnp.uint32),
+        S((slots,), jnp.float32), S((slots,), jnp.int32),
+        S((slots,), jnp.float32), S((slots,), jnp.float32)]
+    if which == "multi":
+        fn, chunk, kw = DecodeMixin._multi_fn(sched, 8, False), [], {}
+        kernel = "paged_attention."
+    else:
+        fn = DecodeMixin._ragged_fn(sched, 8, 256, True, False)
+        chunk = [S((1, 256), jnp.int32), S((1, width), jnp.int32),
+                 S((1,), jnp.int32), S((), jnp.int32)]
+        kw = {"csnap": S((), jnp.int32)}
+        kernel = "ragged_paged_attention."
+    compiled = fn.lower(params, pool, *chunk, *sampling, **kw).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
+    # read: 40 MB (multi, the logits among them) and 81 MB (ragged)
+    assert mem.temp_size_in_bytes < layer_rows
+    assert kernel in compiled.as_text()
 
 
 # -- the step programs' pool traffic ------------------------------------------
@@ -375,7 +434,7 @@ def _step_program(which: str, kv_quant, chip, monkeypatch):
             cfg=cfg, mesh=None,
             _compiles=types.SimpleNamespace(wrap=lambda fam, key, fn: fn),
         ),
-        _step_jit={}, _hybrid=False, _latent=False,
+        _step_jit={}, _stateful=False, _latent=False,
     )
     sampling = [
         S((SLOTS, 1), jnp.int32), S((SLOTS, 2), jnp.uint32),
