@@ -78,6 +78,13 @@ def empty_snapshot(state):
         lambda a: jnp.zeros((a.shape[0], *a.shape[2:]), a.dtype), state)
 
 
+def armed(cache):
+    """[B] bool: the slots that decode. An idle or admitting slot's table
+    row is zeroed, and page 0 is nobody's, so its row of a step is padding:
+    expert layers route it nowhere, a recurrence leaves its state alone."""
+    return cache.block_table[:, 0] > 0
+
+
 class PagedKVCache(NamedTuple):
     """Page pool + block tables. With ``kv_quant="int8"`` the pools store
     int8 with per-slot (per-token, per-head) fp32 scales — KV bytes halve,

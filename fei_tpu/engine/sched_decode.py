@@ -445,6 +445,9 @@ class DecodeMixin:
         if self._stateful:
             # live rows whose state the dispatch's steps read and wrote
             extra["state_rows"] = len(active) * n
+            if eng.cfg.mamba_n_heads:
+                # a mixer's recurrence leaves the other slots' rows alone
+                METRICS.incr("state.rows_skipped", (self.B - len(active)) * n)
             METRICS.gauge("state.live_bytes", len(active) * self._state_row)
         if self._sparse:
             # pages one sparse layer reads a kv head at the first step,

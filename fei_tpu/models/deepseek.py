@@ -46,7 +46,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from fei_tpu.engine.paged_cache import write_latent_pages, write_latent_rows
+from fei_tpu.engine.paged_cache import (
+    armed,
+    write_latent_pages,
+    write_latent_rows,
+)
 from fei_tpu.models.configs import ModelConfig
 from fei_tpu.models.llama import _logits, _mlp_dense, _norm, _rope
 from fei_tpu.ops.moe import sigmoid_gate
@@ -308,7 +312,7 @@ def _run_layers(params, cfg: ModelConfig, x, positions, cache, read, live,
     """Flat rows ``x`` [N, h] at ``positions`` [N] through every layer:
     the dense stack, then the expert stack, ``cache``'s pool ([L, P, ps,
     W]) carried flat beside them and written in place. ``read``: one of the
-    ``_read_*``; ``live`` [N]: the rows the expert layers route (``_armed``,
+    ``_read_*``; ``live`` [N]: the rows the expert layers route (``armed``,
     ``_real``). Returns (x, pool in its outward layout, routing stats
     summed over the expert layers)."""
     pool = cache.latent
@@ -357,13 +361,6 @@ def _final(x, params, cfg):
 # -- the step functions ------------------------------------------------------
 
 
-def _armed(cache):
-    """[B] bool: the slots that decode. An idle or admitting slot's table
-    row is zeroed, and page 0 is nobody's (``engine/paged_cache.py``), so
-    its row is padding, which the expert layers route nowhere."""
-    return cache.block_table[:, 0] > 0
-
-
 def _real(C: int, last):
     """[C] bool: a chunk's tokens up to ``last``, the index of the
     prompt's last token in it (None: all); the rest is padding."""
@@ -378,7 +375,7 @@ def forward_paged(params, cfg: ModelConfig, tokens, cache, kernel_mesh=None):
     x = embed_tokens(params, cfg, tokens[:, 0], model_dtype(params))
     x, pool, stats = _run_layers(
         params, cfg, x, cache.lengths, cache, _read_decode(cfg, cache),
-        _armed(cache), kernel_mesh)
+        armed(cache), kernel_mesh)
     logits = _logits(_final(x, params, cfg)[:, None], params, cfg)
     return logits, cache._replace(
         latent=pool, lengths=cache.lengths + 1,
@@ -420,7 +417,7 @@ def forward_paged_merged(params, cfg: ModelConfig, chunk_toks, chunk_row,
     x, pool, stats = _run_layers(
         params, cfg, x, positions, cache,
         _read_both(cfg, cache, chunk_row[0], chunk_pos[0]),
-        jnp.concatenate([_armed(cache), _real(C, last)]), kernel_mesh)
+        jnp.concatenate([armed(cache), _real(C, last)]), kernel_mesh)
     x = _final(x, params, cfg)
     logits = _logits(x[:B, None], params, cfg)
     return x[None, B:], logits, cache._replace(

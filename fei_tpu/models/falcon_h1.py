@@ -15,7 +15,9 @@ every product carries a muP multiplier (``ModelConfig``'s fourteen).
   cache is ``PagedKVCache.state``, a ``MixerState``: the scan state
   ``[L, B + 1, heads, d_head, d_state]`` float32 and the convolution's
   last inputs ``[L, B + 1, taps - 1, channels]``, a row a slot and a last
-  row for the admission in flight.
+  row for the admission in flight. A decode step's recurrence is one
+  kernel over the scan state where it lies (``ops/pallas/ssd_step.py``):
+  the slots that decode advance, the others' rows are not touched.
 
 The layers run as ONE scan over the stacked weights (``llama._scan_pool``):
 the pool rides the carry viewed flat, the state block beside it, both
@@ -31,7 +33,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from fei_tpu.engine.paged_cache import MixerState, empty_snapshot
+from fei_tpu.engine.paged_cache import MixerState, armed, empty_snapshot
 from fei_tpu.models import llama
 from fei_tpu.models.configs import ModelConfig
 from fei_tpu.models.llama import (
@@ -47,6 +49,7 @@ from fei_tpu.models.llama import (
 )
 from fei_tpu.models.sala import _chunk_points
 from fei_tpu.ops import ssd
+from fei_tpu.ops.pallas import ssd_step
 from fei_tpu.ops.quant import mm, quantize as _quantize
 from fei_tpu.ops.rope import compute_rope_freqs
 
@@ -210,9 +213,11 @@ def _put(a, rows, l, row):
     return jax.lax.dynamic_update_slice(a, rows[None].astype(a.dtype), at)
 
 
-def _mixer_decode(cfg, lp, y, l, st: MixerState):
+def _mixer_decode(cfg, lp, y, l, st: MixerState, walk: ssd_step.Walk):
     """One token a slot: y [B, 1, h] against the slots' rows of layer
-    ``l``'s state. Returns (out [B, 1, h] float32, state)."""
+    ``l``'s state, the recurrence on the block where it lies: the rows of
+    ``walk`` advance, a slot that does not decode keeps its row untouched.
+    Returns (out [B, 1, h] float32, state)."""
     B = y.shape[0]
     z, u, dt = _ssm_in(cfg, lp, y)
     with jax.named_scope("ssm_conv"):
@@ -222,8 +227,7 @@ def _mixer_decode(cfg, lp, y, l, st: MixerState):
     x, Bm, Cm = _split_conv(cfg, c)
     with jax.named_scope("ssm_state"):
         dt, A, D = _decay(lp, dt[:, 0])
-        o, S = ssd.step(x, dt, A, Bm, Cm, D, _row(st.ssm, l, 0, B))
-        ssm = _put(st.ssm, S, l, 0)
+        o, ssm = ssd_step.step(x, dt, A, Bm, Cm, D, st.ssm, l, walk)
     return _ssm_tail(cfg, lp, o[:, None], z, y.dtype), MixerState(ssm, conv)
 
 
@@ -283,12 +287,13 @@ def forward_paged(params, cfg: ModelConfig, tokens, cache,
 
     cos, sin = _rope_tables(cfg, cache)
     bt, t = cache.block_table, cache.lengths
+    walk = ssd_step.live_walk(armed(cache))
     x = embed_tokens(params, cfg, tokens, model_dtype(params))
 
     def body(carry, lp, base, kp, vp, ksc, vsc):
         x, st, l = carry
         y = _norm(x, lp["attn_norm"], cfg)
-        mix, st = _mixer_decode(cfg, lp, y, l, st)
+        mix, st = _mixer_decode(cfg, lp, y, l, st, walk)
         q, k, v = _qkv(cfg, lp, y, t[:, None], cos, sin)
         kp, vp, ksc, vsc = _write_rows(kp, vp, ksc, vsc, k, v, bt, t, base)
         with jax.named_scope("attention"):
@@ -352,6 +357,7 @@ def forward_paged_merged(params, cfg: ModelConfig, chunk_toks, chunk_row,
     bt, t = cache.block_table, cache.lengths
     chunk_positions = chunk_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
     points = _chunk_points(C, last_idx, snap_at)
+    walk = ssd_step.live_walk(armed(cache))
     R, nG, btv, limits, q_lens, modes = merged_rows(
         cfg, cache, chunk_row, chunk_pos, C)
     Cp = nG * R
@@ -365,7 +371,7 @@ def forward_paged_merged(params, cfg: ModelConfig, chunk_toks, chunk_row,
         yd = _norm(xd, lp["attn_norm"], cfg)
         mixc, st, snap = _mixer_chunk(
             cfg, lp, yc, l, st, snap, chunk_pos[0], points)
-        mixd, st = _mixer_decode(cfg, lp, yd, l, st)
+        mixd, st = _mixer_decode(cfg, lp, yd, l, st, walk)
         qc, kc, vc = _qkv(cfg, lp, yc, chunk_positions, cos, sin)
         qd, kd, vd = _qkv(cfg, lp, yd, t[:, None], cos, sin)
         kp, vp, ksc, vsc = _write_rows(

@@ -130,6 +130,11 @@ METRIC_REGISTRY: dict[str, tuple[str, str]] = {
     "state.resumed_tokens": ("counter",
                              "Prompt tokens not recomputed because an "
                              "admission resumed from a snapshot."),
+    "state.rows_skipped": ("counter",
+                           "Slot rows of the recurrent state a dispatch's "
+                           "steps left where they lay because the slot did "
+                           "not decode: slots x steps less the record's "
+                           "state_rows."),
     "moe.assignments": ("counter",
                         "(row, expert) assignments the step programs' expert "
                         "layers made: live rows x experts a token x expert "

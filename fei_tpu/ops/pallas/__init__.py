@@ -9,6 +9,8 @@ fei/core/assistant.py:524-530); these are the greenfield TPU-native hot ops:
   block: the decode step (one query a sequence) and the solo prefill chunk.
 - ragged_paged_attention: mixed prefill+decode rows — per-row
   (limit, q_len) metadata — in ONE invocation over the paged pool.
+- ssd_step: a Mamba-2 mixer's decode step over its state block in place,
+  a live row read once and written once, a dead row not at all.
 
 Every kernel runs in interpret mode on CPU (the hermetic test mesh) and
 compiled on TPU; the XLA-native fei_tpu.ops.attention is the correctness

@@ -13,7 +13,8 @@ depthwise convolution whose cache is its last ``taps - 1`` inputs. Two
 forms of each, one recurrence:
 
 - ``step`` / ``conv_step``: one token a sequence (decode), the state read
-  and written once;
+  and written once (the serving path runs ``step`` as one kernel over the
+  state block, ``ops/pallas/ssd_step.py``, which this plain form defines);
 - ``chunk`` / ``conv_chunk``: ``T`` positions of one sequence (an admission
   chunk). With ``l_i = sum_{j<=i} dt_j A``, ``Y = ((C B^T) * L)(dt * X) +
   diag(exp(l)) C S_0`` where ``L_ij = exp(l_i - l_j)`` for ``i >= j``: each

@@ -131,6 +131,23 @@ def test_mixer_step_programs_carry_every_scope(falcon_programs, program, expecte
     assert not missing, f"{program} lost scopes {sorted(missing)}"
 
 
+@pytest.mark.parametrize("program", ["multi", "ragged"])
+def test_recurrence_kernel_lies_under_its_scope(falcon_programs, program):
+    """``ssm_state_roofline`` reads the ``ssm_state`` scope's device time
+    against the bytes the kernel moves: the custom call has to lie under
+    that scope (outside it the share would read over 100), once a layer
+    scan (the merged program has two: its first step's and the decode
+    scan's), under a name that is no attention kernel's."""
+    fn, args, kw = falcon_programs[program]
+    found = [str(eqn.source_info.name_stack)
+             for eqn in _pallas_calls(fn, *args, **kw)
+             if eqn.params["name"] == "ssm_state_step"]
+    assert len(found) == {"multi": 1, "ragged": 2}[program], found
+    assert all("ssm_state" in path.split("/") for path in found), found
+    assert all("ssm_state_step" not in names
+               for names in NAMES["kernels"].values())
+
+
 @pytest.mark.parametrize("program,expected", [
     ("multi", SALA_SCOPES | {"lm_head", "sample", "grammar_mask"}),
     ("ragged", SALA_SCOPES | {"lm_head", "sample", "grammar_mask"}),
@@ -176,14 +193,14 @@ def test_chunk_program_trace_name(programs):
     assert programs["chunk"][0].__name__ == "chunk"  # jit_chunk (PERF.md)
 
 
-def _pallas_names(fn, *args, **kw) -> list[str]:
-    """The ``name`` of every pallas_call in the traced function."""
-    found: list[str] = []
+def _pallas_calls(fn, *args, **kw) -> list:
+    """Every pallas_call equation of the traced function."""
+    found: list = []
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                found.append(eqn.params["name"])
+                found.append(eqn)
             for v in eqn.params.values():
                 for sub in (v if isinstance(v, (list, tuple)) else [v]):
                     inner = getattr(sub, "jaxpr", sub)
@@ -192,6 +209,11 @@ def _pallas_names(fn, *args, **kw) -> list[str]:
 
     walk(jax.make_jaxpr(fn)(*args, **kw).jaxpr)
     return found
+
+
+def _pallas_names(fn, *args, **kw) -> list[str]:
+    """The ``name`` of every pallas_call in the traced function."""
+    return [eqn.params["name"] for eqn in _pallas_calls(fn, *args, **kw)]
 
 
 def _pool(P=5, K=2, ps=4, D=8):

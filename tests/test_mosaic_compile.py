@@ -323,6 +323,39 @@ def test_moonlight_decode_scan_fits_the_chip_and_moves_no_pool(
     assert "latent_paged_attention." in text and "moe_grouped_matmul." in text
 
 
+def test_recurrence_step_kernel_compiles_for_v5e(one_chip, monkeypatch):
+    """The recurrence's decode step alone at the cell's shapes: the state
+    block of 12 layers, 33 rows, 32 heads of 128 x 256 float32 (1.7 GB)
+    handed over whole and written in place (no temporary of a row's size),
+    two rows of 4.19 MB in the kernel's fast memory, a program a slot."""
+    from fei_tpu.ops.pallas import ssd_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    L, B, H, P, N, G = 12, 32, 32, 128, 256, 2
+
+    def S(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    args = [S((L, B + 1, H, P, N), jnp.float32), S((), jnp.int32),
+            S((B,), jnp.bool_), S((B, H, P), jnp.float32),
+            S((B, H), jnp.float32), S((H,), jnp.float32),
+            S((B, G, N), jnp.float32), S((B, G, N), jnp.float32),
+            S((H,), jnp.float32)]
+
+    def call(state, l, live, x, dt, A, Bm, Cm, D):
+        return ssd_step.step(x, dt, A, Bm, Cm, D, state, l,
+                             ssd_step.live_walk(live))
+
+    assert ssd_step._kernel_takes(args[0])
+    compiled = jax.jit(call, donate_argnums=(0,)).lower(*args).compile()
+    vmem = _scoped_vmem(compiled.as_text())
+    row = H * P * N * 4
+    assert list(vmem) == ["ssm_state_step"]
+    assert 2 * row <= vmem["ssm_state_step"] <= 2 * row + (8 << 20)
+    assert _pallas_grid(call, *args) == (B,)
+    assert compiled.memory_analysis().temp_size_in_bytes < row
+
+
 @pytest.mark.parametrize("which", ["multi", "ragged"])
 def test_mixer_step_programs_fit_the_chip_and_copy_no_state(
         one_chip, monkeypatch, which):
@@ -379,7 +412,9 @@ def test_mixer_step_programs_fit_the_chip_and_copy_no_state(
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
     # read: 40 MB (multi, the logits among them) and 81 MB (ragged)
     assert mem.temp_size_in_bytes < layer_rows
-    assert kernel in compiled.as_text()
+    text = compiled.as_text()
+    # the paged kernel, and the recurrence's step on the block where it lies
+    assert kernel in text and "ssm_state_step." in text
 
 
 # -- the step programs' pool traffic ------------------------------------------
