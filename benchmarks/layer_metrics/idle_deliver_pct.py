@@ -1,0 +1,10 @@
+"""Model step (``engine/sched_decode.py``): device idle seconds of the
+traced interval that lie under a ``loop.deliver`` span (tokens handed to
+their streams after a dispatch's sync), over that interval; one part of
+``device_idle_pct`` (``_idle.py`` has the rule and prints the table)."""
+
+from ._idle import pct_under
+
+
+def read(ctx):
+    return pct_under(ctx, "loop.deliver")

@@ -388,6 +388,7 @@ class AdmissionMixin:
         FLIGHT.dispatch(
             "dispatch.prefill", t0, t_issue, time.perf_counter(),
             rid=seq.rid, mesh=mesh_tag(eng.mesh), slot=slot, tokens=n,
+            it=self._it,
         )
 
         self._complete_admission(seq, slot, dense, bucket, last_logits)
@@ -527,6 +528,7 @@ class AdmissionMixin:
                         "dispatch.prefill_chunk", t0, t_issue, t_issue,
                         rid=seq.rid, mesh=mesh_tag(eng.mesh),
                         slot=st["slot"], lo=lo, tokens=hi - lo, replay=True,
+                        it=self._it,
                     )
                     METRICS.incr(
                         "scheduler.resume_replayed_tokens", hi - lo
@@ -607,7 +609,7 @@ class AdmissionMixin:
             "dispatch.prefill_chunk", t0, t_issue,
             time.perf_counter(), rid=seq.rid,
             mesh=mesh_tag(eng.mesh), slot=st["slot"],
-            lo=lo, tokens=hi - lo, paged=True,
+            lo=lo, tokens=hi - lo, paged=True, it=self._it,
         )
         st["pos"] = hi
         if not final or hi < n:

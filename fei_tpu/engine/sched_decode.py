@@ -100,7 +100,9 @@ class DecodeMixin:
         # finished mid-iteration) still makes progress NOW — bounded-stall admission is a
         # guarantee, not a fast path. Deliberately not in a finally:
         # after a device error the loop's handler owns the pool.
-        self._flush_pending_chunk()
+        if self._pending_chunk is not None:
+            with self._phase("loop.admit"):
+                self._flush_pending_chunk()
 
     def _step_active_impl(self) -> None:
         eng = self.engine
@@ -140,7 +142,7 @@ class DecodeMixin:
             # the device-native grammar path is measured against
             METRICS.incr("scheduler.host_mask_uploads", len(masks))
         toks = self._dispatch_steps(active, 1, mask=mask)
-        with FLIGHT.span("loop.deliver"):
+        with self._phase("loop.deliver"):
             self._deliver_scan(active, toks, 1)
 
 
@@ -211,16 +213,12 @@ class DecodeMixin:
         if n <= 1:
             return False
         under_admission = bool(self._waiting) or self._admitting is not None
-        FLIGHT.event(
-            "turbo_arm", depth=n, slots=len(active),
-            under_admission=under_admission,
-        )
         toks = self._dispatch_steps(active, n)
         METRICS.incr("scheduler.multi_steps")
         METRICS.incr("scheduler.multi_tokens", n)
         if under_admission:
             METRICS.incr("scheduler.turbo_under_admission")
-        with FLIGHT.span("loop.deliver"):
+        with self._phase("loop.deliver"):
             self._deliver_scan(active, toks, n)
         return True
 
@@ -359,12 +357,11 @@ class DecodeMixin:
         ``attn_pages``, the pages one layer's decode-kernel call fetches
         a kv head at the first step, over the active slots."""
         eng = self.engine
-        with FLIGHT.span("loop.build"):
+        with self._phase("loop.build"):
             args, kw, grammared, pc = self._build_step_args(active, n, mask)
         ctx = [len(s.prompt_ids) + len(s.generated) for _, s in active]
         METRICS.incr("scheduler.decode_steps", n)
         METRICS.incr("scheduler.decode_slot_steps", len(active) * n)
-        METRICS.gauge("scheduler.batch_slots_active", len(active))
         chunk_logits = None
         merged = pc is not None
         t0 = time.perf_counter()
@@ -461,13 +458,13 @@ class DecodeMixin:
         FLIGHT.dispatch(
             "dispatch.step", t0, t_issue, t1,
             rids=[s.rid for _, s in active], mesh=mesh_tag(eng.mesh),
-            n_steps=n, slots=len(active), ctx=ctx, **extra,
+            n_steps=n, slots=len(active), ctx=ctx, it=self._it, **extra,
         )
         for _, s in active:
             s.shield = False  # survived a dispatch: victimizable again
         if merged:
             st = pc["st"]
-            with FLIGHT.span("loop.deliver", chunk=True):
+            with self._phase("loop.deliver", chunk=True):
                 try:
                     self._finish_merged_chunk(pc, chunk_logits)
                 except BaseException as exc:  # noqa: BLE001

@@ -286,6 +286,9 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         self._arm_jit = None
         self._closed = False
         self._admitting: dict | None = None  # in-flight chunked admission
+        # number of the loop's working iteration: every loop.* span and
+        # every dispatch issued from the loop carries it as ``it``
+        self._it = 0
         self._prefix = None  # PrefixCache when engine.prefix_cache
         # active device grammar: ONE table pair serves every constrained
         # request (the agent memoizes one union grammar per tool set); a
@@ -945,10 +948,16 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
             or self._draining or self._closed or any(self._slots)
         )
 
+    def _phase(self, name: str, **tags):
+        """A phase of the loop's working iteration as a flight span,
+        tagged with the iteration's number."""
+        return FLIGHT.span(name, it=self._it, **tags)
+
     def _loop(self) -> None:
         # Every phase of a working iteration is a host span in the flight
         # recorder (loop.reap / loop.ctl / loop.admit here, loop.build /
-        # loop.deliver around the dispatch in sched_decode); an unbroken
+        # loop.deliver around the dispatch in sched_decode), tagged with
+        # the iteration's number; an unbroken
         # stretch with nothing to do is ONE loop.idle span, closed when
         # work arrives or the thread parks — not one record per poll.
         idle = 0
@@ -993,22 +1002,24 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
                             self._thread = None
                             return
                     continue
-                with FLIGHT.span("loop.reap"):
+                self._it += 1
+                with self._phase("loop.reap"):
                     self._reap_cancelled()
-                with FLIGHT.span("loop.ctl"):
+                with self._phase("loop.ctl"):
                     self._run_ctl_pending()
                 if self._draining:
                     if self._admitting is not None:
                         # an ACCEPTED chunked admission finishes its
                         # prefill; nothing new leaves the waiting queue
                         # while draining (_admit_ready checks _draining)
-                        self._admit_ready()
+                        with self._phase("loop.admit"):
+                            self._admit_ready()
                     if self._drain_step():
                         with self._lock:
                             self._thread = None
                             return
                     continue
-                with FLIGHT.span("loop.admit"):
+                with self._phase("loop.admit"):
                     self._admit_ready()
                 if not any(self._slots):
                     # queued work that cannot be admitted yet (every

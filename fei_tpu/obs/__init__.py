@@ -1,5 +1,5 @@
 """Observability subsystem: metrics, histograms, request traces, the
-engine flight recorder, and Prometheus exposition.
+engine flight recorder, the process watch, and Prometheus exposition.
 fei_tpu/utils/metrics.py re-exports the METRICS singleton from here so
 pre-existing call sites are unchanged."""
 
@@ -10,6 +10,7 @@ from fei_tpu.obs.metrics import (
     Histogram,
     Metrics,
 )
+from fei_tpu.obs.proc import WATCH, ProcessWatch
 from fei_tpu.obs.registry import METRIC_REGISTRY, declared, help_for
 from fei_tpu.obs.render import snapshot_lines
 from fei_tpu.obs.trace import TRACES, RequestTrace, TraceBuffer
@@ -23,9 +24,11 @@ __all__ = [
     "FlightRecorder",
     "Histogram",
     "Metrics",
+    "ProcessWatch",
     "RequestTrace",
     "TRACES",
     "TraceBuffer",
+    "WATCH",
     "declared",
     "help_for",
     "snapshot_lines",

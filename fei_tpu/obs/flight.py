@@ -18,8 +18,13 @@ Three record shapes share the ring:
 - **host spans** — ``with FLIGHT.span(name, **tags):`` records a named
   stretch of host work with its begin and end (the scheduler loop's
   phases between dispatches: ``loop.reap``, ``loop.ctl``, ``loop.admit``,
-  ``loop.build``, ``loop.deliver``, ``loop.idle``). A span is two
-  ``perf_counter`` calls and one append; it is also the one place that
+  ``loop.build``, ``loop.deliver``, ``loop.idle``; the process watch's
+  ``proc.gc`` and ``proc.stall``, obs/proc.py). A span is two
+  ``perf_counter`` and two ``thread_time`` calls and one append: its
+  ``cpu_s`` tag is the CPU time of the thread that ran it, so ``dur_s -
+  cpu_s`` is the time that thread was not on a CPU (waiting for the
+  interpreter, or blocked); where the kernel accounts CPU time by the
+  tick, only sums over many spans are fair. It is also the one place that
   opens a ``jax.profiler.TraceAnnotation``, so a profiler capture
   (``POST /debug/profile``) shows the host phases beside the device
   operations in the profiler's own trace, with no clock mapping.
@@ -113,16 +118,20 @@ class FlightRecorder:
 
     @contextlib.contextmanager
     def span(self, name: str, **tags):
-        """Record the enclosed host work as one span (begin, end). Also
+        """Record the enclosed host work as one span (begin, end), with
+        the calling thread's CPU time over it as ``cpu_s``. Also
         opens a ``jax.profiler.TraceAnnotation`` of the same name, which
         costs nothing measurable while no profiler capture is running
         and puts the span into the capture's host plane while one is."""
         with _jax_annotation(name):
+            c0 = time.thread_time()
             t0 = time.perf_counter()
             try:
                 yield
             finally:
-                self.record_span(name, t0, time.perf_counter(), **tags)
+                t1 = time.perf_counter()
+                tags["cpu_s"] = round(time.thread_time() - c0, 6)
+                self.record_span(name, t0, t1, **tags)
 
     def record_span(self, name: str, t0: float, t1: float, **tags) -> None:
         """Append a host span whose ends the caller timed itself — for a
@@ -200,7 +209,9 @@ class FlightRecorder:
         timestamps in µs). Each dispatch expands into two complete ("X")
         events — ``<name>.issue`` and ``<name>.sync`` — so the issue/sync
         split is visible as adjacent slices on the timeline; instants
-        export as ph="i". Args carry the rid/mesh/slot tags verbatim."""
+        export as ph="i". Dispatches and instants are row 1, host spans
+        row 2, the process watch's ``proc.*`` spans row 3. Args carry the
+        rid/mesh/slot tags verbatim."""
         with self._lock:
             ring = list(self._ring)
         events = []
@@ -217,7 +228,8 @@ class FlightRecorder:
                 events.append({
                     "name": name, "ph": "X", "ts": round(t0 * 1e6, 3),
                     "dur": round(max(0.0, t1 - t0) * 1e6, 3),
-                    "pid": 1, "tid": 2, "args": tags,
+                    "pid": 1, "tid": 3 if name.startswith("proc.") else 2,
+                    "args": tags,
                 })
             else:
                 _, name, t0, t_issue, t1, tags = rec

@@ -357,6 +357,26 @@ METRIC_REGISTRY: dict[str, tuple[str, str]] = {
                           "signature: steady-state recompiles; each one "
                           "is a dropped cache or a shape leak, not "
                           "warmup."),
+    "proc.gc_seconds": ("counter",
+                        "Seconds the process spent inside garbage "
+                        "collections while `fei serve` ran (every "
+                        "generation; obs/proc.py)."),
+    "proc.gc_collections": ("counter",
+                            "Garbage collections of any generation "
+                            "while `fei serve` ran."),
+    "proc.gc_full_collections": ("counter",
+                                 "Collections of generation 2 (the whole "
+                                 "heap: each also a `proc.gc` flight "
+                                 "span)."),
+    "proc.stall_seconds": ("counter",
+                           "Seconds by which the process watch's 10 ms "
+                           "heartbeat woke late, over the wakes that "
+                           "were more than 50 ms late (each a "
+                           "`proc.stall` flight span)."),
+    "proc.stalls": ("counter",
+                    "Heartbeat wakes more than 50 ms late: the whole "
+                    "process stood still (a long collection, a "
+                    "starved or frozen host)."),
     # --- gauges ---------------------------------------------------------
     "last_ttft_s": ("gauge", "TTFT of the most recent generation (s)."),
     "last_decode_tok_s": ("gauge",
@@ -382,9 +402,6 @@ METRIC_REGISTRY: dict[str, tuple[str, str]] = {
     "scheduler.replica.*.queue_depth": (
         "gauge", "Waiting requests attributed to one dp replica group "
                  "(balanced share of the shared admission queue)."),
-    "scheduler.batch_slots_active": ("gauge",
-                                     "Active slots in the last decode "
-                                     "dispatch (batch utilization)."),
     "pool.pages_total": ("gauge", "Allocatable KV pages (null page "
                                   "excluded)."),
     "pool.pages_free": ("gauge", "Free KV pages."),

@@ -30,6 +30,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qs, urlsplit
 
 from fei_tpu.obs.flight import FLIGHT
+from fei_tpu.obs.proc import WATCH
 from fei_tpu.obs.trace import TRACES
 from fei_tpu.utils.errors import (
     DeadlineExceededError,
@@ -1025,6 +1026,7 @@ class ServingServer:
         self._thread: threading.Thread | None = None
 
     def start(self) -> None:
+        WATCH.start()  # collector pauses and process stalls (obs/proc.py)
         self._thread = threading.Thread(
             target=self.httpd.serve_forever, daemon=True
         )
@@ -1036,6 +1038,8 @@ class ServingServer:
         self.httpd.server_close()
         if self._thread:
             self._thread.join(timeout=5)
+            self._thread = None
+            WATCH.stop()
 
 
 def main(argv: list[str] | None = None) -> int:

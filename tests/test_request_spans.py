@@ -295,9 +295,12 @@ class TestHostSpans:
         with r.span("loop.build", slots=3):
             time.sleep(0.01)
         (rec,) = r.records()
+        cpu_s = rec["tags"]["cpu_s"]  # the thread's CPU time: it slept
         assert rec == {"kind": "span", "name": "loop.build", "ts": rec["ts"],
-                       "dur_s": rec["dur_s"], "tags": {"slots": 3}}
+                       "dur_s": rec["dur_s"],
+                       "tags": {"slots": 3, "cpu_s": cpu_s}}
         assert rec["ts"] >= round(p0, 6) and 0.01 <= rec["dur_s"] < 1.0
+        assert 0.0 <= cpu_s < rec["dur_s"]
         assert r.counts()["loop.build"] == 1
 
     def test_span_is_recorded_when_the_body_raises(self):
@@ -325,7 +328,7 @@ class TestHostSpans:
             with r.span("loop.reap", i=i):
                 pass
         assert len(r) == 16
-        assert r.records()[-1]["tags"] == {"i": 99}
+        assert r.records()[-1]["tags"]["i"] == 99
 
     def test_loop_spans_and_dispatches_cover_a_busy_loop(self, engine):
         FLIGHT.reset()
