@@ -328,7 +328,7 @@ class AdmissionMixin:
             self._trace_finish(s, "deadline_exceeded")
             self._journal_end(s, "deadline_exceeded")
             METRICS.incr("scheduler.requests_shed")
-            s.out.put(DeadlineExceededError(
+            self._emit(s, end=DeadlineExceededError(
                 f"request {s.rid} spent its whole "
                 f"{s.deadline - s.t_queued:.1f}s deadline queued"
             ))
@@ -348,7 +348,7 @@ class AdmissionMixin:
         self._trace_finish(seq, "failed")
         self._journal_end(seq, "failed")
         METRICS.incr("scheduler.requests_failed_isolated")
-        seq.out.put(exc)
+        self._emit(seq, end=exc)
 
 
     def _admission_tokens(self, seq: _Seq) -> int:
@@ -384,6 +384,7 @@ class AdmissionMixin:
             dense = KVCache.create(cfg, 1, bucket, dtype=eng.dtype)
             last_logits, dense = eng.prefill([ids], dense)
             t_issue = time.perf_counter()
+            self._publish(behind_issue=True)
             last_logits.block_until_ready()
         FLIGHT.dispatch(
             "dispatch.prefill", t0, t_issue, time.perf_counter(),
@@ -524,6 +525,7 @@ class AdmissionMixin:
                         )
                     # no host sync: the replayed pool stays on device
                     t_issue = time.perf_counter()
+                    self._publish(behind_issue=True)
                     FLIGHT.dispatch(
                         "dispatch.prefill_chunk", t0, t_issue, t_issue,
                         rid=seq.rid, mesh=mesh_tag(eng.mesh),
@@ -595,6 +597,8 @@ class AdmissionMixin:
                 jnp.int32((hi if self._stateful else n) - 1 - lo), *extra,
             )
             t_issue = time.perf_counter()
+            # a chunk issued on its own hides the flush as a step does
+            self._publish(behind_issue=True)
             if self._stateful:
                 *out, snap = out
                 out = out if final else out[0]
@@ -852,6 +856,7 @@ class AdmissionMixin:
             # PRNGKey(seed) after its prefill split, same as the chain
             first_key = np.asarray(rng)
         self._deliver(seq, tok0, key=first_key)
+        self._charge(seq, 0)
 
 
     def _move_state(self, which: str, arg) -> None:
@@ -889,8 +894,7 @@ class AdmissionMixin:
             if recomputed is None else recomputed,
         )
         if seq.replay:
-            for t in seq.generated:
-                seq.out.put(t)
+            self._emit(seq, *seq.generated, at_once=True)
             seq.replay = False
         if len(seq.generated) >= seq.budget:
             self._finish(seq)
@@ -1248,6 +1252,7 @@ class AdmissionMixin:
             # PRNGKey(seed) after its prefill split, same as the chain
             first_key = np.asarray(rng)
         self._deliver(seq, tok0, key=first_key)
+        self._charge(seq, 0)
 
 
     def _admit_fn(self, bucket: int, n_pages: int):

@@ -223,7 +223,10 @@ class DecodeMixin:
         return True
 
     def _deliver_scan(self, active, toks: np.ndarray, n: int) -> None:
-        """Hand a scan's [B, n] tokens to their sequences, step by step."""
+        """SETTLE a scan's [B, n] tokens on their sequences, step by step
+        (``_deliver``), and charge each sequence's tenant once for its
+        run. Nothing here wakes a consumer: the tokens wait in
+        ``seq.pending`` for the ``_publish`` behind the next issue."""
         # stacked per-step PRNG states ([n, B, 2]): step_keys[i] is the
         # chain after i+1 splits — exactly the per-token reference state
         # after delivering i+1 tokens, which is what the journal records
@@ -232,6 +235,7 @@ class DecodeMixin:
         )
         rollback: dict[int, int] = {}
         for b, s in active:
+            had = len(s.generated)
             for i in range(n):
                 if self._slots[b] is not s:  # finished at an earlier step
                     break
@@ -251,6 +255,7 @@ class DecodeMixin:
                     # re-enter constrained decode from the exact token
                     rollback[b] = i
                     break
+            self._charge(s, had)
         if rollback:
             self._rollback_slots(rollback, n)
 
@@ -347,7 +352,13 @@ class DecodeMixin:
         key state.
 
         Host work before the dispatch is issued (growth, batch vectors,
-        uploads) is the ``loop.build`` span; the flight record carries
+        uploads) is the ``loop.build`` span. Between the issue and the
+        fetch that blocks, ``_publish`` hands the PREVIOUS dispatch's
+        settled tokens to their consumers (``loop.publish``): they wake
+        while the device runs and the loop waits with the interpreter
+        released. ``t_issue`` is taken before it, so the record's issue
+        stretch keeps its meaning and its sync stretch holds the flush.
+        The flight record carries
         what the dispatch ran: ``ctx``, each active slot's context length
         (prompt + generated) at the first step, in the order of ``rids``,
         and for a merged dispatch ``chunk_lo``, the tokens of the riding
@@ -380,24 +391,24 @@ class DecodeMixin:
                 off, pc["snap_pages"] = self._snap_offset(
                     pc["st"], pc["lo"], pc["toks"].shape[1])
                 kw["csnap"] = jnp.int32(off)
-            with METRICS.span("decode_step", jax_trace=True):
+        else:
+            step = self._multi_fn(n, grammared, masked=mask is not None)
+        with METRICS.span("decode_step", jax_trace=True):
+            if merged:
                 res = self._device_call("ragged merged dispatch", step,
                                         *rargs, **kw)
                 if self._stateful:
                     *res, pc["snap"] = res
                 if pc["final"]:
-                    (chunk_logits, nxt, self._step_keys, self._pool,
-                     self._keys) = res
-                else:
-                    nxt, self._step_keys, self._pool, self._keys = res
-                t_issue = time.perf_counter()
-                out = np.asarray(nxt)  # host sync inside the span
-        else:
-            step = self._multi_fn(n, grammared, masked=mask is not None)
-            with METRICS.span("decode_step", jax_trace=True):
+                    chunk_logits, *res = res
+                nxt, self._step_keys, self._pool, self._keys = res
+            else:
                 nxt, self._step_keys, self._pool, self._keys = step(*args, **kw)
-                t_issue = time.perf_counter()
-                out = np.asarray(nxt)  # host sync inside the span
+            t_issue = time.perf_counter()
+            # the device runs: the last dispatch's tokens go to their
+            # consumers, who wake while the loop waits in the fetch
+            self._publish(behind_issue=True)
+            out = np.asarray(nxt)  # host sync inside the span
         t1 = time.perf_counter()
         METRICS.timing("dispatch_issue", t_issue - t0)
         METRICS.timing("dispatch_sync", t1 - t_issue)

@@ -78,6 +78,11 @@ class _Seq:
     mask_fn: Callable[[list[int]], np.ndarray | None] | None
     stops: set[int]
     out: queue.Queue = field(default_factory=queue.Queue)
+    # settled and not yet published (``pending_tokens`` tokens, then at
+    # most an error and _DONE): ``_emit`` appends, ``_publish`` moves it
+    # into ``out``
+    pending: list = field(default_factory=list)
+    pending_tokens: int = 0
     generated: list[int] = field(default_factory=list)
     budget: int = 0
     slot: int = -1
@@ -289,6 +294,14 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         # number of the loop's working iteration: every loop.* span and
         # every dispatch issued from the loop carries it as ``it``
         self._it = 0
+        # settle / publish: live sequences whose ``pending`` holds tokens,
+        # by id(). The lock is for the publishers off the loop's thread (a
+        # submit that evicts a queued request, a drain with no loop).
+        self._unpublished: dict[int, _Seq] = {}
+        self._pub_lock = threading.Lock()
+        # whether the loop's current iteration has issued a device
+        # program to publish behind (the flush rule, ``_loop``)
+        self._issued = False
         self._prefix = None  # PrefixCache when engine.prefix_cache
         # active device grammar: ONE table pair serves every constrained
         # request (the agent memoizes one union grammar per tool set); a
@@ -712,7 +725,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
                 "queue_evict", rid=v.rid, priority=v.priority,
                 by_priority=priority,
             )
-            v.out.put(QueueFullError(
+            self._emit(v, end=QueueFullError(
                 f"request {v.rid} (priority {v.priority}) was evicted from "
                 f"the full queue by a priority-{priority} arrival",
                 retry_after_s=self.retry_after_s,
@@ -956,10 +969,23 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
     def _loop(self) -> None:
         # Every phase of a working iteration is a host span in the flight
         # recorder (loop.reap / loop.ctl / loop.admit here, loop.build /
-        # loop.deliver around the dispatch in sched_decode), tagged with
-        # the iteration's number; an unbroken
+        # loop.publish / loop.deliver around the dispatch in sched_decode),
+        # tagged with the iteration's number; an unbroken
         # stretch with nothing to do is ONE loop.idle span, closed when
         # work arrives or the thread parks — not one record per poll.
+        #
+        # Settle / publish: what a dispatch's tokens decide (stops, the
+        # budget, evictions, next_input, the journal) is settled in
+        # loop.deliver, before the next build; what a consumer sees of a
+        # stream that goes on is published behind the NEXT device issue
+        # (_publish in _dispatch_steps and after an admission's own
+        # issue), while the device runs. A stream's two ends are what its
+        # client waits on and are published at once: its first token, and
+        # what ends it (_DONE, an error) with every token before that.
+        # The flush rule, so that no live stream's tokens are stranded
+        # behind a dispatch that does not come: the loop never waits with
+        # tokens pending (_wait), and an iteration that issued nothing
+        # (_issued) publishes as the next one begins.
         idle = 0
         idle_t0 = None
 
@@ -972,6 +998,9 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
 
         while True:
             try:
+                if not self._issued:
+                    self._publish()
+                self._issued = False
                 if not self._has_work():
                     if idle_t0 is None:
                         idle_t0 = time.perf_counter()
@@ -986,8 +1015,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
                                 end_idle()
                                 self._thread = None
                                 return
-                    self._wake.wait(timeout=0.1)
-                    self._wake.clear()
+                    self._wait(0.1)
                     continue
                 end_idle()
                 if self._closed:
@@ -1024,8 +1052,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
                 if not any(self._slots):
                     # queued work that cannot be admitted yet (every
                     # waiting tenant over budget): poll, as before
-                    self._wake.wait(timeout=0.1)
-                    self._wake.clear()
+                    self._wait(0.1)
                     continue
                 self._step_active()
             except BaseException as exc:  # noqa: BLE001
@@ -1053,7 +1080,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
                 # slot freed through the healthy pool, typed error to the
                 # waiter, `deadline_exceeded` in the trace (which also
                 # increments scheduler.requests_deadline_exceeded)
-                s.out.put(DeadlineExceededError(
+                self._emit(s, end=DeadlineExceededError(
                     f"request {s.rid} exceeded its "
                     f"{s.deadline - s.t_queued:.1f}s deadline mid-decode"
                 ))
@@ -1069,9 +1096,17 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         return np.asarray(build_block_table([pages], width))[0]
 
     def _deliver(self, seq: _Seq, t: int, key=None) -> None:
-        """Handle one sampled token for an armed sequence — grammar walk,
-        stop handling, emission, completion. Shared by the admission first
-        token and every decode step. ``key`` is the slot's post-step PRNG
+        """SETTLE one sampled token for an armed sequence — grammar walk,
+        stop handling, the journal's and the export's record, completion
+        with its eviction, ``next_input``: everything the next dispatch
+        reads, in the order recovery rests on. What a consumer sees (the
+        token, ``_DONE``, an error) goes to ``seq.pending`` through
+        ``_emit`` and is PUBLISHED behind the next device issue
+        (``_publish``); a sequence's first token, and what ends it with
+        the tokens before, are published at once. Shared by the admission
+        first token and every decode step
+        (the callers charge the tenant once for the run: ``_charge``).
+        ``key`` is the slot's post-step PRNG
         state (host uint32[2]) when a consumer needs it (journal/export);
         None otherwise — the decode paths skip the device transfer
         entirely when nothing armed wants per-token keys.
@@ -1096,8 +1131,9 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
         """Fail ONE request: typed error to its waiter, `failed` trace,
         slot evicted through the same healthy-pool path as a normal
         completion — the pool, prefix cache, and every other stream
-        survive."""
-        seq.out.put(exc)
+        survive. The error is published after the tokens settled
+        before it, never ahead of them."""
+        self._emit(seq, end=exc)
         self._trace_finish(seq, "failed")
         self._journal_end(seq, "failed")
         METRICS.incr("scheduler.requests_failed_isolated")
@@ -1118,19 +1154,17 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
                     "ttft_seconds", time.perf_counter() - seq.t_queued
                 )
             seq.generated.append(t)
-            # journal + export BEFORE out.put publishes the token: the
+            # journal + export BEFORE the token can be published: the
             # consumer must never observe token n while its resume state
             # (keys[n-1] / the WAL tok record) is still missing — the
-            # commit point of the crash-consistency contract
+            # commit point of the crash-consistency contract. A token
+            # published later is still published after its record.
             if seq.export is not None:
                 seq.export["keys"].append(self._key_list(key))
             if seq.journaled:
                 self._journal.token(seq.rid, t, self._key_list(key))
-            seq.out.put(t)
-            # weighted-fair service accounting: admission picks the
-            # backlogged tenant with the least served-tokens/weight
-            self.tenants.charge(seq.tenant, 1)
-            METRICS.incr(f"tenant.{seq.tenant}.tokens_served")
+            # a first token is what a client feels as TTFT: at once
+            self._emit(seq, t, at_once=len(seq.generated) == 1)
         if not done and seq.gfallback_state is not None:
             # host-mask tool-call fallback: advance the masker NOW (it is
             # idempotent per prefix length) so acceptance ends the turn at
@@ -1149,6 +1183,74 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
             self._release_window_pages(seq)
         if len(seq.generated) >= seq.budget:
             self._finish(seq)
+
+    def _charge(self, seq: _Seq, had: int) -> None:
+        """Weighted-fair service accounting for the tokens ``seq`` gained
+        since it held ``had``, once a sequence and dispatch: admission
+        picks the backlogged tenant with the least served-tokens/weight."""
+        served = len(seq.generated) - had
+        if served > 0:
+            self.tenants.charge(seq.tenant, served)
+
+    # -- publish: what a consumer or a counter sees --------------------------
+
+    def _emit(self, seq: _Seq, *tokens: int, end=None,
+              at_once: bool = False) -> None:
+        """The ONE way into ``seq.out``, for a token, an error and
+        ``_DONE`` alike: append to the sequence's pending list, which is
+        handed over whole and in order, so an error or ``_DONE`` can
+        never overtake the tokens settled before it. Tokens wait for the
+        next ``_publish``. What a client waits on goes out here and now,
+        with all that is pending before it: a first token and a warm
+        restart's replay (``at_once``), and ``end``, what ends the stream
+        (an error, ``_DONE``: the end of a turn is what a closed loop's
+        next turn starts from, and costs one wake-up a request)."""
+        with self._pub_lock:
+            seq.pending.extend(tokens)
+            seq.pending_tokens += len(tokens)
+            if end is not None:
+                seq.pending.append(end)
+            if at_once or end is not None:
+                self._unpublished.pop(id(seq), None)
+                self._publish_seq(seq, "scheduler.tokens_published_at_once")
+            else:
+                self._unpublished[id(seq)] = seq
+
+    def _publish(self, behind_issue: bool = False) -> None:
+        """Hand every pending list to its consumer. ``behind_issue``: a
+        device program was just issued and the loop is about to wait for
+        it with the interpreter released, so the consumers wake while the
+        device runs; else nothing was issued to hide behind (the flush
+        rule, ``_loop``) and the tokens count as published at once."""
+        self._issued = self._issued or behind_issue
+        if not self._unpublished:
+            return
+        counter = ("scheduler.tokens_published_behind_issue" if behind_issue
+                   else "scheduler.tokens_published_at_once")
+        with self._phase("loop.publish", behind_issue=behind_issue), \
+                self._pub_lock:
+            for seq in self._unpublished.values():
+                self._publish_seq(seq, counter)
+            self._unpublished.clear()
+
+    def _publish_seq(self, seq: _Seq, counter: str) -> None:
+        """Under ``_pub_lock``: one sequence's pending list into its
+        queue, in order; the counters first, so that whoever reads
+        ``_DONE`` reads them whole."""
+        items, seq.pending = seq.pending, []
+        n, seq.pending_tokens = seq.pending_tokens, 0
+        if n:
+            METRICS.incr(f"tenant.{seq.tenant}.tokens_served", n)
+            METRICS.incr(counter, n)
+        for item in items:
+            seq.out.put(item)
+
+    def _wait(self, timeout: float) -> None:
+        """The loop's one way to wait for work: never with tokens
+        pending (the flush rule, ``_loop``)."""
+        self._publish()
+        self._wake.wait(timeout=timeout)
+        self._wake.clear()
 
     def _release_window_pages(self, seq: _Seq) -> None:
         """Rolling-buffer SWA: pages wholly below (pos - window - margin)
@@ -1187,7 +1289,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
             seq, "cancelled" if seq.cancelled else "completed"
         )
         self._update_sched_gauges()
-        seq.out.put(_DONE)
+        self._emit(seq, end=_DONE)
 
     def _evict_slot(self, slot: int) -> None:
         """Zero the slot's device block-table row + length (future KV
@@ -1370,11 +1472,11 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
             s.finished = True
             self._trace_finish(s, "failed")
             self._journal_end(s, "failed")
-            s.out.put(exc)
+            self._emit(s, end=exc)
         self._admitting = None
         for s in list(self._slots):
             if s is not None:
-                s.out.put(exc)
+                self._emit(s, end=exc)
                 self._trace_finish(s, "failed")
                 self._finish(s)
 
@@ -1424,7 +1526,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
             s.finished = True
             self._trace_finish(s, "failed")
             self._journal_end(s, "failed")
-            s.out.put(exc)
+            self._emit(s, end=exc)
 
     # -- memory pressure: preemption + pressure-aware allocation -------------
 
@@ -1692,8 +1794,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
             else:
                 # only a chunked admission is in flight; _admit_ready
                 # advances it one chunk per loop iteration
-                self._wake.wait(timeout=0.01)
-                self._wake.clear()
+                self._wait(0.01)
             return False
         self._finalize_drain()
         return True
@@ -1727,7 +1828,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
             snap = self._snapshot_seq(s)
             s.finished = True
             if snap is None:
-                s.out.put(EngineDrainingError(
+                self._emit(s, end=EngineDrainingError(
                     "engine drained; this request's constraint (grammar / "
                     "host mask closure) cannot be snapshotted across "
                     "processes — resubmit it after restart",
@@ -1740,7 +1841,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
                 FLIGHT.event(
                     "snapshot", rid=s.rid, generated=len(s.generated),
                 )
-                s.out.put(EngineDrainingError(
+                self._emit(s, end=EngineDrainingError(
                     "engine drained before this request completed; it was "
                     "snapshotted for warm restart",
                     retry_after_s=self.retry_after_s,
@@ -1750,7 +1851,7 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
                 # this session — without this, a warm restart would re-admit
                 # it twice (once from the snapshot file, once from the WAL)
                 self._journal_end(s, "snapshotted")
-            s.out.put(_DONE)
+            self._emit(s, end=_DONE)
         if snaps and self._drain_dir:
             from fei_tpu.engine import checkpoint
             from fei_tpu.parallel.mesh import mesh_geometry

@@ -70,6 +70,14 @@ METRIC_REGISTRY: dict[str, tuple[str, str]] = {
                                     "slots)."),
     "scheduler.host_mask_uploads": ("counter",
                                     "Host-side grammar mask uploads."),
+    "scheduler.tokens_published_behind_issue": (
+        "counter", "Tokens handed to their consumers right after the next "
+                   "device program's issue, while the device runs."),
+    "scheduler.tokens_published_at_once": (
+        "counter", "Tokens handed to their consumers with no issue to "
+                   "hide behind: a request's first token, a replay, the "
+                   "tokens a stream's end takes with it, and a dispatch's "
+                   "tokens when the next iteration issues nothing."),
     "scheduler.multi_steps": ("counter", "Multi-step decode dispatches."),
     "scheduler.multi_tokens": ("counter",
                                "Tokens produced by multi-step decode."),
