@@ -27,7 +27,10 @@ for the admission in flight, which becomes the slot's when the slot is
 armed. Linear attention keeps one array ``[Ll, B + 1, H, d, d]`` float32
 and no pages in those layers; a state-space mixer beside attention
 (models/falcon_h1.py) keeps a ``MixerState`` in every layer, which has
-pages too. A snapshot is the same block without the slot axis. Whatever
+pages too; mixer layers around attention layers
+(models/granite_hybrid.py) keep a ``MixerState`` of the mixer layers'
+rows and pages of the attention layers' alone, so a layer has one or the
+other. A snapshot is the same block without the slot axis. Whatever
 handles the block (``adopt_state``, ``load_state``, a replay, the prefix
 cache's snapshots, their bytes) maps over its arrays and asks no shape.
 Both are None for a model without such layers.
@@ -37,7 +40,9 @@ row a token and layer with no head axis and no K/V pair, ``latent`` [L, P,
 ps, W]: the compressed vector and the rotated key part, padded to whole
 lane tiles (``ModelConfig.latent_row``). ``k_pages`` and ``v_pages`` are
 then None. Tables, lengths, the allocator and the prefix cache deal in
-pages and are the same for all three.
+pages and are the same for all three. What the expert layers routed rides
+whichever of them the model keeps (``route_stats``,
+``cfg.counts_routing``).
 
 The allocator is deliberately host-side Python (free-list): allocation
 happens once per prefill and at page boundaries during decode, never inside
@@ -136,6 +141,8 @@ class PagedKVCache(NamedTuple):
     ) -> "PagedKVCache":
         if kv_quant not in (None, "int8"):
             raise EngineError(f"unsupported kv_quant mode: {kv_quant!r}")
+        route_stats = (jnp.zeros((4,), dtype=jnp.int32)
+                       if cfg.counts_routing else None)
         if cfg.is_latent:
             if kv_quant:
                 raise EngineError(f"{cfg.name}: latent rows stay unquantized")
@@ -146,7 +153,7 @@ class PagedKVCache(NamedTuple):
                 latent=jnp.zeros(
                     (cfg.num_layers, num_pages, page_size, cfg.latent_row),
                     dtype=dtype),
-                route_stats=jnp.zeros((4,), dtype=jnp.int32),
+                route_stats=route_stats,
             )
         L, K, D = cfg.kv_layers, cfg.num_kv_heads, cfg.head_dim_
         shape = (L, num_pages, K, page_size, D)
@@ -194,6 +201,7 @@ class PagedKVCache(NamedTuple):
             v_scales=scales(),
             kc_pages=kc,
             state=state,
+            route_stats=route_stats,
         )
 
 

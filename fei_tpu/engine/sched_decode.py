@@ -413,7 +413,7 @@ class DecodeMixin:
         METRICS.timing("dispatch_issue", t_issue - t0)
         METRICS.timing("dispatch_sync", t1 - t_issue)
         extra = {}
-        if self._latent:
+        if self._routed:
             # what the expert layers routed, over the dispatch's layers
             # and steps, idle slots and a chunk's padding left out: it
             # came out beside the tokens (``_route_ride``)

@@ -372,6 +372,9 @@ class PagedScheduler(AdmissionMixin, DecodeMixin, ConstraintMixin):
             self._refuse_what_moves_pages(
                 "a latent pool",
                 "spills and streams K and V pages a head (kv/pagesio.py)")
+        # expert layers that count what they route: the numbers ride the
+        # sampled tokens out of a step program, whatever cache they rode in
+        self._routed = engine.cfg.counts_routing
         self._state_jit: dict = {}  # adopt_state / load_state, jitted
         self._state_row = 0  # bytes of one slot's row of the state block
 

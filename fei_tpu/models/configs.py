@@ -27,7 +27,14 @@ tiny preset for hermetic CPU tests beside its published sizes:
   input and sums them, then a SwiGLU; so every layer keeps pages AND a
   recurrent state (the scan state and the convolution's last inputs), and
   nearly every product carries a muP multiplier: ``models/falcon_h1.py``,
-  one block scanned over one stacked tree.
+  one block scanned over one stacked tree;
+- granite-4.0-h-small (``granite-4.0-h-small``, ``tiny-granite-h``; the
+  ``granitemoehybrid`` block): ``mamba`` layers, whose mixer is that
+  Mamba-2 mixer alone (a state and no pages), around ``attention`` layers,
+  grouped-query attention with no positional encoding (pages and no
+  state); every layer's FFN many small experts, gated by a softmax over
+  the chosen logits, beside one shared MLP; four scalars and a tied head:
+  ``models/granite_hybrid.py``, a stack of weights a kind.
 
 Which caches a model keeps is read off two properties and nothing else:
 ``kv_layers`` (layers with pages) and ``state_layers`` (layers with a
@@ -38,6 +45,11 @@ gives the module whose step functions serve a configuration.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
+
+
+# what a layer of each kind (``ModelConfig.layer_kinds``) keeps a sequence
+PAGED_KINDS = frozenset({"minicpm4", "attention"})
+STATE_KINDS = frozenset({"lightning-attn", "mamba"})
 
 
 @dataclass(frozen=True)
@@ -135,7 +147,7 @@ class ModelConfig:
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
     expert_share: tuple = (0, 1)
-    # State-space mixer (Mamba-2, models/falcon_h1.py; mamba_d_ssm > 0):
+    # State-space mixer (Mamba-2, models/mamba2.py; mamba_d_ssm > 0):
     # ``mamba_n_heads`` heads of ``mamba_d_head`` channels (``mamba_d_ssm``
     # in all), each with a state of [mamba_d_head, mamba_d_state] float32 a
     # sequence; B and C of ``mamba_d_state`` are shared by the heads of one
@@ -143,8 +155,9 @@ class ModelConfig:
     # ``mamba_d_conv`` taps runs over x, B and C before the recurrence; an
     # admission chunk goes through the chunked form ``mamba_chunk_size``
     # positions at a time; ``mamba_rms_norm``: the gated output is RMS
-    # normed within each group's channels. Every layer of such a model has
-    # the mixer beside its attention.
+    # normed within each group's channels. With no ``layer_kinds`` every
+    # layer has the mixer beside its attention; with them, the layers of
+    # kind "mamba" have the mixer alone (below).
     mamba_d_ssm: int = 0
     mamba_n_heads: int = 0
     mamba_d_head: int = 0
@@ -166,6 +179,21 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
     mlp_multipliers: tuple = (1.0, 1.0)
+    # the ``granitemoehybrid`` block (models/granite_hybrid.py): layers of
+    # kind "mamba" (the mixer above alone: a state, no pages) and
+    # "attention" (pages, no state; ``attn_rope`` False: no positional
+    # encoding), each followed by ``num_experts`` experts of
+    # ``moe_intermediate_size`` (= ``intermediate_size``: the published
+    # config names one width) gated by a softmax over the chosen logits,
+    # beside one shared MLP of ``shared_intermediate_size``. Each residual
+    # branch times ``residual_multiplier``; attention scores times
+    # ``attention_multiplier`` (0: ``head_dim ** -0.5``); the logits
+    # divided by ``logits_scaling``; the embedding's rows times
+    # ``embedding_multiplier`` (above)
+    shared_intermediate_size: int = 0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
     # tokenizer/bos/eos defaults (overridden by a real tokenizer when loaded)
     bos_token_id: int = 1
     eos_token_id: int = 2
@@ -208,19 +236,27 @@ class ModelConfig:
         return i * held, held
 
     @property
+    def counts_routing(self) -> bool:
+        """The expert layers go through ``ops/moe.moe_held`` and count what
+        they route (``PagedKVCache.route_stats``)."""
+        return self.moe_intermediate_size > 0
+
+    @property
     def kv_layers(self) -> int:
-        """Layers that keep keys and values in pages."""
+        """Layers that keep keys and values in pages: all of a model of
+        one kind of layer, else those whose kind attends."""
         if not self.layer_kinds:
             return self.num_layers
-        return sum(k != "lightning-attn" for k in self.layer_kinds)
+        return sum(k in PAGED_KINDS for k in self.layer_kinds)
 
     @property
     def state_layers(self) -> int:
-        """Layers whose cache is a fixed-size recurrent state a sequence
-        (beside pages, where the layer also attends)."""
-        if self.mamba_d_ssm:
-            return self.num_layers
-        return sum(k == "lightning-attn" for k in self.layer_kinds)
+        """Layers whose cache is a fixed-size recurrent state a sequence:
+        those whose kind has a recurrence, or every layer of a model of
+        one kind of layer with a mixer beside its attention."""
+        if not self.layer_kinds:
+            return self.num_layers if self.mamba_d_ssm else 0
+        return sum(k in STATE_KINDS for k in self.layer_kinds)
 
     @property
     def has_state(self) -> bool:
@@ -242,9 +278,17 @@ class ModelConfig:
             attn += self.num_heads * d + 2 * self.num_kv_heads * d
         if self.o_bias:
             attn += h
+        mixer = h * (self.mamba_d_ssm + self.mamba_conv_dim
+                     + self.mamba_n_heads) + self.mamba_d_ssm * h
+        if self.mamba_d_ssm and self.layer_kinds:
+            # a mixer OR attention a layer, then experts and a shared MLP
+            ffn = (self.num_experts * 3 * h * self.moe_intermediate_size
+                   + h * self.num_experts
+                   + 3 * h * self.shared_intermediate_size)
+            return (self.state_layers * mixer + self.kv_layers * attn
+                    + L * (ffn + 2 * h) + v * h + h)
         if self.mamba_d_ssm:  # the mixer's two projections, beside attention
-            attn += h * (self.mamba_d_ssm + self.mamba_conv_dim
-                         + self.mamba_n_heads) + self.mamba_d_ssm * h
+            attn += mixer
         if self.is_latent:
             # what this program holds: its share of the routed experts
             r, dn, dr = self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim
@@ -503,6 +547,43 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         ssm_multipliers=(0.75, 1.3, 0.65, 1.2, 0.85),
         mlp_multipliers=(0.7, 0.5),
     ),
+    # granite-4.0-h-small (ibm-granite, 2025-10; config.json of
+    # ibm-granite/granite-4.0-h-small, model_type granitemoehybrid): 40
+    # layers in periods of ten, nine Mamba-2 mixers (128 heads x 64, state
+    # 128, one group) around one NoPE GQA layer; every layer's FFN 72
+    # experts of 768 (10 a token) beside a shared MLP of 1536
+    "granite-4.0-h-small": ModelConfig(
+        name="granite-4.0-h-small", vocab_size=100352, hidden_size=4096,
+        intermediate_size=768, num_layers=40, num_heads=32, num_kv_heads=8,
+        head_dim=128, rope_theta=10000.0, rms_norm_eps=1e-5,
+        max_seq_len=131072, tie_embeddings=True, attn_rope=False,
+        layer_kinds=tuple(
+            "attention" if i % 10 == 5 else "mamba" for i in range(40)),
+        num_experts=72, num_experts_per_tok=10, moe_intermediate_size=768,
+        shared_intermediate_size=1536,
+        mamba_d_ssm=8192, mamba_n_heads=128, mamba_d_head=64,
+        mamba_d_state=128, mamba_n_groups=1, mamba_d_conv=4,
+        mamba_chunk_size=256, mamba_rms_norm=True,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=0.0078125, logits_scaling=16.0,
+    ),
+    # the same family at test size: one attention layer inside two runs of
+    # mixers, a state twice the mixer's head, and each of the four scalars
+    # a value of its own that is not 1
+    "tiny-granite-h": ModelConfig(
+        name="tiny-granite-h", vocab_size=512, hidden_size=64,
+        intermediate_size=32, num_layers=5, num_heads=4, num_kv_heads=2,
+        head_dim=16, rope_theta=10000.0, rms_norm_eps=1e-5, max_seq_len=512,
+        tie_embeddings=True, attn_rope=False,
+        layer_kinds=("mamba", "mamba", "attention", "mamba", "mamba"),
+        num_experts=9, num_experts_per_tok=3, moe_intermediate_size=32,
+        shared_intermediate_size=48,
+        mamba_d_ssm=128, mamba_n_heads=8, mamba_d_head=16, mamba_d_state=32,
+        mamba_n_groups=1, mamba_d_conv=4, mamba_chunk_size=8,
+        mamba_rms_norm=True,
+        embedding_multiplier=3.0, residual_multiplier=0.45,
+        attention_multiplier=0.1, logits_scaling=2.5,
+    ),
     "qwen2-0.5b": ModelConfig(
         name="qwen2-0.5b", vocab_size=151936, hidden_size=896,
         intermediate_size=4864, num_layers=24, num_heads=14, num_kv_heads=2,
@@ -522,4 +603,7 @@ def get_model_config(name: str, **overrides) -> ModelConfig:
     if name not in MODEL_CONFIGS:
         raise KeyError(f"unknown model config {name!r}; known: {sorted(MODEL_CONFIGS)}")
     cfg = MODEL_CONFIGS[name]
+    # a configuration file's overrides are JSON: its lists are tuples here
+    overrides = {k: tuple(v) if isinstance(v, list) else v
+                 for k, v in overrides.items()}
     return replace(cfg, **overrides) if overrides else cfg

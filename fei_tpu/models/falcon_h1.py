@@ -8,16 +8,10 @@ every product carries a muP multiplier (``ModelConfig``'s fourteen).
 - Attention: keys and values in the paged pool, through the paged kernels
   every other family's pages go through (``ops/pallas``); rotation on the
   whole head; the keys times ``key_multiplier``.
-- Mixer: one projection to z, x, B, C and dt (each segment times its own
-  multiplier), a causal depthwise convolution over x, B and C, the
-  selective recurrence with a decay a token and head, a gate by silu(z),
-  an RMS norm within each group's channels, the output projection. Its
-  cache is ``PagedKVCache.state``, a ``MixerState``: the scan state
-  ``[L, B + 1, heads, d_head, d_state]`` float32 and the convolution's
-  last inputs ``[L, B + 1, taps - 1, channels]``, a row a slot and a last
-  row for the admission in flight. A decode step's recurrence is one
-  kernel over the scan state where it lies (``ops/pallas/ssd_step.py``):
-  the slots that decode advance, the others' rows are not touched.
+- Mixer: ``models/mamba2.py``, which ``models/granite_hybrid.py`` runs
+  too; here each segment of its projection, its input and its output
+  carry a multiplier. Its cache is ``PagedKVCache.state``, a
+  ``MixerState`` with a row of every layer.
 
 The layers run as ONE scan over the stacked weights (``llama._scan_pool``):
 the pool rides the carry viewed flat, the state block beside it, both
@@ -33,7 +27,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from fei_tpu.engine.paged_cache import MixerState, armed, empty_snapshot
+from fei_tpu.engine.paged_cache import armed, empty_snapshot
 from fei_tpu.models import llama
 from fei_tpu.models.configs import ModelConfig
 from fei_tpu.models.llama import (
@@ -47,8 +41,13 @@ from fei_tpu.models.llama import (
     model_dtype,
     qkv_proj,
 )
+from fei_tpu.models.mamba2 import (
+    _mixer_chunk,
+    _mixer_decode,
+    init_decay,
+    mixer_shapes,
+)
 from fei_tpu.models.sala import _chunk_points
-from fei_tpu.ops import ssd
 from fei_tpu.ops.pallas import ssd_step
 from fei_tpu.ops.quant import mm, quantize as _quantize
 from fei_tpu.ops.rope import compute_rope_freqs
@@ -61,13 +60,9 @@ _LINEARS = ("wq", "wk", "wv", "wo", "ssm_in", "ssm_out",
 def _layer_shapes(cfg: ModelConfig) -> dict:
     h, I = cfg.hidden_size, cfg.intermediate_size
     H, K, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
-    ds, nh, W = cfg.mamba_d_ssm, cfg.mamba_n_heads, cfg.mamba_conv_dim
     return {
         "attn_norm": (h,), "wq": (h, H * d), "wk": (h, K * d),
-        "wv": (h, K * d), "wo": (H * d, h),
-        "ssm_in": (h, ds + W + nh), "conv_w": (cfg.mamba_d_conv, W),
-        "conv_b": (W,), "dt_bias": (nh,), "A_log": (nh,), "ssm_D": (nh,),
-        "ssm_norm": (ds,), "ssm_out": (ds, h),
+        "wv": (h, K * d), "wo": (H * d, h), **mixer_shapes(cfg),
         "mlp_norm": (h,), "w_gate": (h, I), "w_up": (h, I), "w_down": (I, h),
     }
 
@@ -94,15 +89,9 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
         layers = {}
         for name, shape in _layer_shapes(cfg).items():
             key, sub = jax.random.split(key)
-            if name == "A_log":
-                layers[name] = jnp.log(jax.random.uniform(
-                    sub, (L, *shape), _F32, 1.0, 16.0)).astype(dtype)
-            elif name == "dt_bias":
-                dt = jnp.exp(jax.random.uniform(
-                    sub, (L, *shape), _F32, jnp.log(1e-3), jnp.log(1e-1)))
-                layers[name] = jnp.log(jnp.expm1(dt)).astype(dtype)
-            elif name == "conv_b":
-                layers[name] = jnp.zeros((L, *shape), dtype)
+            decay = init_decay(name, sub, (L, *shape), dtype)
+            if decay is not None:
+                layers[name] = decay
             elif len(shape) == 1:
                 layers[name] = jnp.ones((L, *shape), dtype)
             else:
@@ -148,117 +137,6 @@ def _attn_out(cfg, lp, attn):
     with jax.named_scope("attn_out"):
         o = mm(attn.reshape(n, T, -1), lp["wo"])
         return o.astype(_F32) * cfg.attention_out_multiplier
-
-
-# -- the mixer --------------------------------------------------------------
-
-
-@jax.named_scope("ssm_in")
-def _ssm_in(cfg, lp, y):
-    """y [n, T, h] -> z [n, T, d_ssm] float32, the convolution's input
-    [n, T, W] in y's dtype, dt [n, T, heads] float32: one projection, each
-    of its five segments (z, x, B, C, dt) times its own multiplier."""
-    ds, gn, nh = cfg.mamba_d_ssm, cfg.mamba_n_groups * cfg.mamba_d_state, \
-        cfg.mamba_n_heads
-    p = mm(y * jnp.asarray(cfg.ssm_in_multiplier, y.dtype), lp["ssm_in"])
-    m = jnp.concatenate([
-        jnp.full((width,), mult, _F32) for width, mult
-        in zip((ds, ds, gn, gn, nh), cfg.ssm_multipliers)])
-    p = p.astype(_F32) * m
-    return p[..., :ds], p[..., ds:-nh].astype(y.dtype), p[..., -nh:]
-
-
-def _split_conv(cfg, c):
-    """The convolution's output [..., W] -> x [..., heads, d_head], B and
-    C [..., groups, d_state]."""
-    ds, G, N = cfg.mamba_d_ssm, cfg.mamba_n_groups, cfg.mamba_d_state
-    lead = c.shape[:-1]
-    return (c[..., :ds].reshape(*lead, cfg.mamba_n_heads, cfg.mamba_d_head),
-            c[..., ds:ds + G * N].reshape(*lead, G, N),
-            c[..., ds + G * N:].reshape(*lead, G, N))
-
-
-def _decay(lp, dt):
-    """(dt after its bias and softplus, A = -exp(A_log), the skip D)."""
-    dt = jax.nn.softplus(dt + lp["dt_bias"].astype(_F32))
-    return dt, -jnp.exp(lp["A_log"].astype(_F32)), lp["ssm_D"].astype(_F32)
-
-
-def _ssm_tail(cfg, lp, y, z, dtype):
-    """Gate by silu(z), RMS norm within each group's channels, project
-    out. y: [n, T, heads, d_head] float32; z: [n, T, d_ssm] float32."""
-    n, T = y.shape[:2]
-    with jax.named_scope("ssm_gate"):
-        y = y.reshape(n, T, -1) * jax.nn.silu(z)
-        if cfg.mamba_rms_norm:
-            g = y.reshape(n, T, cfg.mamba_n_groups, -1)
-            g = g * jax.lax.rsqrt(
-                jnp.mean(g * g, axis=-1, keepdims=True) + cfg.rms_norm_eps)
-            y = g.reshape(n, T, -1) * lp["ssm_norm"].astype(_F32)
-    with jax.named_scope("ssm_out"):
-        o = mm(y.astype(dtype), lp["ssm_out"])
-        return o.astype(_F32) * cfg.ssm_out_multiplier
-
-
-def _row(a, l, row, n):
-    """Rows ``row`` .. ``row + n`` of layer ``l`` of one of the state's
-    arrays ``[L, B + 1, ...]``, read where they lie."""
-    at = (l, row) + (0,) * (a.ndim - 2)
-    return jax.lax.dynamic_slice(a, at, (1, n) + a.shape[2:])[0]
-
-
-def _put(a, rows, l, row):
-    """Write ``rows`` [n, ...] back as rows ``row`` on of layer ``l``."""
-    at = (l, row) + (0,) * (a.ndim - 2)
-    return jax.lax.dynamic_update_slice(a, rows[None].astype(a.dtype), at)
-
-
-def _mixer_decode(cfg, lp, y, l, st: MixerState, walk: ssd_step.Walk):
-    """One token a slot: y [B, 1, h] against the slots' rows of layer
-    ``l``'s state, the recurrence on the block where it lies: the rows of
-    ``walk`` advance, a slot that does not decode keeps its row untouched.
-    Returns (out [B, 1, h] float32, state)."""
-    B = y.shape[0]
-    z, u, dt = _ssm_in(cfg, lp, y)
-    with jax.named_scope("ssm_conv"):
-        c, last = ssd.conv_step(u[:, 0], _row(st.conv, l, 0, B),
-                                lp["conv_w"], lp["conv_b"])
-        conv = _put(st.conv, last, l, 0)
-    x, Bm, Cm = _split_conv(cfg, c)
-    with jax.named_scope("ssm_state"):
-        dt, A, D = _decay(lp, dt[:, 0])
-        o, ssm = ssd_step.step(x, dt, A, Bm, Cm, D, st.ssm, l, walk)
-    return _ssm_tail(cfg, lp, o[:, None], z, y.dtype), MixerState(ssm, conv)
-
-
-def _mixer_chunk(cfg, lp, y, l, st: MixerState, snap: MixerState, lo, points):
-    """``C`` positions of the admission in flight (the state's last row):
-    y [1, C, h] from position ``lo``. ``points``: int32 [2], (real tokens
-    of the chunk, where in it the snapshot is taken). Returns (out [1, C,
-    h] float32, state, snapshot with layer ``l``'s rows)."""
-    B = st.ssm.shape[1] - 1
-    z, u, dt = _ssm_in(cfg, lp, y)
-    fresh = lo == 0  # a sequence starts from nothing, whatever the row held
-    with jax.named_scope("ssm_conv"):
-        prev = _row(st.conv, l, B, 1)[0]
-        c, lasts = ssd.conv_chunk(
-            u[0], jnp.where(fresh, jnp.zeros_like(prev), prev),
-            lp["conv_w"], lp["conv_b"], points)
-    x, Bm, Cm = _split_conv(cfg, c)
-    with jax.named_scope("ssm_state"):
-        dt, A, D = _decay(lp, dt[0])
-        S0 = _row(st.ssm, l, B, 1)[0]
-        o, states = ssd.chunked(x, dt, A, Bm, Cm, D,
-                                jnp.where(fresh, 0.0, S0), points,
-                                cfg.mamba_chunk_size)
-    with jax.named_scope("state_carry"):
-        st = MixerState(_put(st.ssm, states[:1], l, B),
-                        _put(st.conv, lasts[:1], l, B))
-        snap = MixerState(
-            jax.lax.dynamic_update_slice(snap.ssm, states[1:], (l, 0, 0, 0)),
-            jax.lax.dynamic_update_slice(
-                snap.conv, lasts[1:].astype(snap.conv.dtype), (l, 0, 0)))
-    return _ssm_tail(cfg, lp, o[None], z, y.dtype), st, snap
 
 
 # -- the block and the three step functions ---------------------------------

@@ -107,6 +107,29 @@ def moe_mlp_routed(
     return out.reshape(B, T, H)
 
 
+def _largest(sel: jnp.ndarray, k: int) -> jnp.ndarray:
+    """The ``k`` largest of each row of ``sel`` [N, E], as indices [N, k],
+    by k rounds of arg-max, each taking its pick out (the first of equals,
+    as ``lax.top_k`` orders them): ``top_k`` of 64 is a whole sort a row on
+    the TPU, 0.1 ms a layer at 32 rows (PERF.md, PR 36)."""
+    lane = jnp.arange(sel.shape[-1], dtype=jnp.int32)[None, :]
+    picks = []
+    for _ in range(k):
+        pick = jnp.argmax(sel, axis=-1).astype(jnp.int32)
+        picks.append(pick)
+        sel = jnp.where(lane == pick[:, None], -jnp.inf, sel)
+    return jnp.stack(picks, axis=-1)
+
+
+def _router(x, router_w):
+    """``x W`` in float32, whatever the rows' dtype: a near-tie among the
+    scores must not be decided by a bfloat16 product."""
+    return jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+
 def sigmoid_gate(
     x: jnp.ndarray,  # [N, H]
     router_w: jnp.ndarray,  # [H, E]
@@ -120,25 +143,27 @@ def sigmoid_gate(
     ``s + bias`` chosen, their weights the scores alone (the bias selects
     and never weighs), normalised to sum 1 if ``norm``, times ``scale``.
     Returns (chosen [N, k] int32, weights [N, k] float32)."""
-    s = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST,
-    ))
-    # the k largest by k rounds of arg-max, each taking its pick out (the
-    # first of equals, as ``lax.top_k`` orders them): ``top_k`` of 64 is a
-    # whole sort a row on the TPU, 0.1 ms a layer at 32 rows (PERF.md, PR 36)
-    sel = s + bias.astype(jnp.float32)
-    lane = jnp.arange(sel.shape[-1], dtype=jnp.int32)[None, :]
-    picks = []
-    for _ in range(k):
-        pick = jnp.argmax(sel, axis=-1).astype(jnp.int32)
-        picks.append(pick)
-        sel = jnp.where(lane == pick[:, None], -jnp.inf, sel)
-    idx = jnp.stack(picks, axis=-1)
+    s = jax.nn.sigmoid(_router(x, router_w))
+    idx = _largest(s + bias.astype(jnp.float32), k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return idx, w * scale
+
+
+def softmax_gate(
+    x: jnp.ndarray,  # [N, H]
+    router_w: jnp.ndarray,  # [H, E]
+    k: int,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The gate of the ``granitemoehybrid`` expert layer: logits ``x W`` in
+    float32, the ``k`` largest chosen, their weights a softmax over those
+    ``k`` logits alone. Returns (chosen [N, k] int32, weights [N, k]
+    float32)."""
+    logits = _router(x, router_w)
+    idx = _largest(logits, k)
+    return idx, jax.nn.softmax(
+        jnp.take_along_axis(logits, idx, axis=-1), axis=-1)
 
 
 def _grouped(xs, w, sizes, expert_of, layer):

@@ -46,6 +46,14 @@ FALCON_SCOPES = LAYER_SCOPES | {
     "ssm_in", "ssm_conv", "ssm_state", "ssm_gate", "ssm_out"}
 
 
+# mixer layers around attention layers, each with an expert FFN
+# (models/granite_hybrid.py): the mixer's and the expert layer's scopes, and
+# the attention block's but for a rotation and a dense MLP
+GRANITE_SCOPES = (LAYER_SCOPES - {"rope"}) | {
+    "ssm_in", "ssm_conv", "ssm_state", "ssm_gate", "ssm_out", "state_carry",
+    "moe_route", "moe_experts", "moe_shared", "moe_combine"}
+
+
 def _programs(model: str, **kw):
     engine = InferenceEngine.from_config(
         model, paged=True, batch_size=2, max_seq_len=256, **kw
@@ -146,6 +154,48 @@ def test_recurrence_kernel_lies_under_its_scope(falcon_programs, program):
     assert all("ssm_state" in path.split("/") for path in found), found
     assert all("ssm_state_step" not in names
                for names in NAMES["kernels"].values())
+
+
+@pytest.fixture(scope="module")
+def granite_programs():
+    engine, fns = _programs("tiny-granite-h", page_size=8)
+    yield fns
+    engine.close()
+
+
+@pytest.mark.parametrize("program,expected", [
+    ("multi", GRANITE_SCOPES | {"lm_head", "sample", "grammar_mask"}),
+    ("ragged", GRANITE_SCOPES | {"lm_head", "sample", "grammar_mask"}),
+    ("chunk", GRANITE_SCOPES | {"lm_head"}),
+])
+def test_mixer_and_expert_step_programs_carry_every_scope(
+        granite_programs, program, expected):
+    fn, args, kw = granite_programs[program]
+    words = _scopes_in(fn, args, kw)
+    missing = expected - words
+    assert not missing, f"{program} lost scopes {sorted(missing)}"
+    assert "rope" not in words  # nothing rotates
+
+
+@pytest.mark.parametrize("program", ["multi", "ragged"])
+def test_recurrence_and_experts_kernels_lie_under_their_scopes(
+        granite_programs, program):
+    """``mamba_state_roofline`` reads the ``ssm_state`` scope and
+    ``moe_experts_roofline`` the ``moe_grouped_matmul`` calls: one body of
+    each kind of layer a layer loop, so the recurrence's kernel once and
+    the grouped product three times a kind (the merged program has two
+    layer loops: its first step's and the decode scan's)."""
+    fn, args, kw = granite_programs[program]
+    calls = _pallas_calls(fn, *args, **kw)
+    loops = {"multi": 1, "ragged": 2}[program]
+    state = [str(e.source_info.name_stack) for e in calls
+             if e.params["name"] == "ssm_state_step"]
+    assert len(state) == loops, state
+    assert all("ssm_state" in path.split("/") for path in state), state
+    # the grouped product is read by its kernel's name (names_moonlight.json)
+    experts = [e for e in calls if e.params["name"] == "moe_grouped_matmul"]
+    assert len(experts) == 2 * 3 * loops
+    assert "moe_grouped_matmul" in NAMES["kernels"]["moe_experts"]
 
 
 @pytest.mark.parametrize("program,expected", [

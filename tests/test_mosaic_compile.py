@@ -417,6 +417,144 @@ def test_mixer_step_programs_fit_the_chip_and_copy_no_state(
     assert kernel in text and "ssm_state_step." in text
 
 
+@pytest.mark.parametrize("rows,K,N", [
+    (320, 4096, 768), (320, 768, 4096), (2880, 4096, 768), (2880, 768, 4096)])
+def test_grouped_product_compiles_for_v5e_at_72_narrow_experts(
+        one_chip, rows, K, N):
+    """The experts' grouped product at granite-4.0-h-small's shapes: a
+    decode step's 32 x 10 assignments (5 tiles + 72 visits, 4.4 rows an
+    expert) and a merged dispatch's (32 + 256) x 10, gate/up and down, over
+    the int8 stack of the nine mamba layers' experts read in place."""
+    from fei_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    def S(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    args = [S((rows, K), jnp.bfloat16), S((9, 72, K, N), jnp.int8),
+            S((72,), jnp.int32), S((), jnp.int32)]
+
+    def call(xs, w, sizes, layer):
+        return grouped_matmul(xs, w, sizes, layer, interpret=False)
+
+    compiled = jax.jit(call).lower(*args).compile()
+    assert "moe_grouped_matmul." in compiled.as_text()
+    assert _pallas_grid(call, *args) == (rows // 64 + 72,)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+def test_recurrence_step_kernel_compiles_for_v5e_at_128_heads(
+        one_chip, monkeypatch):
+    """The recurrence's decode step at granite-4.0-h-small's shapes: nine
+    layers' rows, 128 heads of 64 x 128 float32 in one group: the same
+    4,194,304 bytes a row as Falcon-H1's 32 x 128 x 256, four times the
+    heads on the lanes of ``x`` and ``y``."""
+    from fei_tpu.ops.pallas import ssd_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    L, B, H, P, N, G = 9, 32, 128, 64, 128, 1
+
+    def S(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    args = [S((L, B + 1, H, P, N), jnp.float32), S((), jnp.int32),
+            S((B,), jnp.bool_), S((B, H, P), jnp.float32),
+            S((B, H), jnp.float32), S((H,), jnp.float32),
+            S((B, G, N), jnp.float32), S((B, G, N), jnp.float32),
+            S((H,), jnp.float32)]
+
+    def call(state, l, live, x, dt, A, Bm, Cm, D):
+        return ssd_step.step(x, dt, A, Bm, Cm, D, state, l,
+                             ssd_step.live_walk(live))
+
+    assert ssd_step._kernel_takes(args[0])
+    compiled = jax.jit(call, donate_argnums=(0,)).lower(*args).compile()
+    vmem = _scoped_vmem(compiled.as_text())
+    row = H * P * N * 4
+    assert row == 4194304 and list(vmem) == ["ssm_state_step"]
+    assert 2 * row <= vmem["ssm_state_step"] <= 2 * row + (8 << 20)
+    assert _pallas_grid(call, *args) == (B,)
+    assert compiled.memory_analysis().temp_size_in_bytes < row
+
+
+@pytest.mark.parametrize("which", ["multi", "ragged", "chunk"])
+def test_hybrid_expert_step_programs_fit_the_chip_and_copy_no_state(
+        one_chip, monkeypatch, which):
+    """``multi(8)``, ``ragged(8, 256, final)`` and ``chunk(256, final)`` of
+    granite-4.0-h-small (int8, one period of ten layers with all 72
+    experts a layer: one stage of four) at the cell's shapes, 32 slots of
+    4096 positions: the program fits a 16 GB chip beside its arguments (8.8
+    GB of weights, 0.54 GB of pages for the one layer that attends, 1.26
+    GB of state for the nine that do not), the state block rides the
+    layer loops and is written in place (nothing copies one layer's rows,
+    140 MB), and no copy of a layer's experts is made, in any type."""
+    from fei_tpu.engine.paged_cache import PagedKVCache, state_row_bytes
+    from fei_tpu.engine.sched_admission import AdmissionMixin
+    from fei_tpu.engine.sched_decode import DecodeMixin
+    from fei_tpu.models.configs import get_model_config
+    from fei_tpu.models.granite_hybrid import init_params
+
+    full = get_model_config("granite-4.0-h-small")
+    cfg = get_model_config("granite-4.0-h-small", num_layers=10,
+                           layer_kinds=full.layer_kinds[:10])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, width = 32, 4096 // 64
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree)
+
+    def S(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: init_params(cfg, k, quantize="int8"), jax.random.PRNGKey(0)))
+    pool = on_chip(jax.eval_shape(lambda: PagedKVCache.create(
+        cfg, slots * width + 1, slots, width, page_size=64)))
+    assert pool.k_pages.shape == (1, slots * width + 1, 8, 64, 128)
+    layer_rows = state_row_bytes(pool.state) * (slots + 1) // 9
+    assert layer_rows == 33 * (4194304 + 50688)
+    sched = types.SimpleNamespace(
+        engine=types.SimpleNamespace(
+            cfg=cfg, mesh=None,
+            _compiles=types.SimpleNamespace(wrap=lambda fam, key, fn: fn)),
+        _step_jit={}, _pchunk_jit={}, _stateful=True, _latent=False)
+    sampling = [
+        S((slots, 1), jnp.int32), S((slots, 2), jnp.uint32),
+        S((slots,), jnp.float32), S((slots,), jnp.int32),
+        S((slots,), jnp.float32), S((slots,), jnp.float32)]
+    chunk = [S((1, 256), jnp.int32), S((1, width), jnp.int32),
+             S((1,), jnp.int32), S((), jnp.int32)]
+    if which == "multi":
+        fn = DecodeMixin._multi_fn(sched, 8, False)
+        args, kw, kernel = sampling, {}, "paged_attention."
+    elif which == "ragged":
+        fn = DecodeMixin._ragged_fn(sched, 8, 256, True, False)
+        args, kw = chunk + sampling, {"csnap": S((), jnp.int32)}
+        kernel = "ragged_paged_attention."
+    else:
+        fn = AdmissionMixin._paged_chunk_fn(sched, 256, True)
+        args, kw, kernel = chunk + [S((), jnp.int32)], {}, "paged_attention_block."
+    compiled = fn.lower(params, pool, *args, **kw).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
+    # read: 27 MB (multi), 176 MB (ragged), 214 MB (chunk: 2,880 expert
+    # rows of 4096 there and back, the scan's [128, 256, 256] decays)
+    assert mem.temp_size_in_bytes < 2 * layer_rows
+    text = compiled.as_text()
+    assert kernel in text and "moe_grouped_matmul." in text
+    if which != "chunk":
+        assert "ssm_state_step." in text
+    # nothing of the state's size, of one layer's rows, or of a layer's
+    # experts (in any type) is copied, sliced out or converted: the rows
+    # are written where they lie, the grouped product reads the stack
+    moved = [m.group(0)[:160] for m in re.finditer(
+        r"= (\S+?)\{[^}]*\} (copy|copy-start|dynamic-slice|convert)\(.*", text)
+        if re.fullmatch(r"\w+\[((9,33|1,33|33),128,64,128"
+                        r"|(1,|9,)?72,(4096,768|768,4096))\]", m.group(1))]
+    assert not moved, "\n".join(moved)
+
+
 # -- the step programs' pool traffic ------------------------------------------
 #
 # The Llama family's layer scan carries the page pool flat and writes a

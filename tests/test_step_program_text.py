@@ -7,7 +7,10 @@ the chip runs, and every number read off it, is the parent's (PERF.md
 section 6, "PR 24-31": compare step programs' lowered text at ``tiny``
 with the parent's). The digests below were taken from an unpacked ``git
 archive`` of the parent of PR 42 (75c4638) with this file's own
-``digests()``; PR 42's tree gives the same twelve.
+``digests()``; PR 42's tree gives the same twelve, and PR 43's (which
+moved the mixer both hybrid families run into ``models/mamba2.py`` and
+gave ``ops/moe.py`` a second gate) the same twelve again beside the three
+of the family it added.
 
 A PR that changes a device program on purpose re-pins the programs it
 meant to change (``python tests/test_step_program_text.py`` prints the
@@ -29,6 +32,7 @@ FAMILIES = {
     "tiny-sala": {"page_size": 8},
     "tiny-moonlight": {"page_size": 8},
     "tiny-falcon-h1": {"page_size": 8},
+    "tiny-granite-h": {"page_size": 8},
 }
 
 PINNED = {
@@ -56,6 +60,13 @@ PINNED = {
         "5f20b1abb129fb0ac44483d2659b3a75d2f030c2810956f416dab611b16ec9b9",
     ("tiny-falcon-h1", "chunk"):
         "851381effc8f02a1b81ed1643c9ac1872df62c20c997a4e95c787a10f9f7f8a8",
+    # PR 43: a fifth family; its own tree's digests (the parent has none)
+    ("tiny-granite-h", "multi"):
+        "426b9aef7f98ecdd1fa325740603361436ed806c493eb87c92657d3ed5f5f5e7",
+    ("tiny-granite-h", "ragged"):
+        "e0b20f47eaf9187898ab2cbd67bc849cdc97eab5d9a580463fd7a23e46cc4d31",
+    ("tiny-granite-h", "chunk"):
+        "317b9fc481451e302bd6f39b859948dcb843f7c1dff14bd53ec96b687a1ade52",
 }
 
 
