@@ -10,9 +10,9 @@ decode (``live_walk``) are scalar-prefetched. Grid = (B,): position ``i``
 of the walk takes row ``rows[i]`` of layer ``l``:
 
 - the program copies the row into one half of a ``[2, H, P, N]`` buffer,
-  works it there head by head, elementwise in float32 (``a S + (dt x)
-  (outer) B``, then ``sum_n S' C``: nothing of the state goes through the
-  matrix unit), and copies it back over itself;
+  works it there a block of heads a loop turn, elementwise in float32 (``a
+  S + (dt x) (outer) B``, then ``sum_n S' C``: nothing of the state goes
+  through the matrix unit), and copies it back over itself;
 - row ``i + 1`` is read while row ``i`` is worked, and has arrived before
   row ``i`` is written: **a read never shares the HBM with a write**. Read
   and written at once the block streams at 650 GB/s, in turns at 690 (703
@@ -25,11 +25,31 @@ of the walk takes row ``rows[i]`` of layer ``l``:
 
 ``x`` comes in as the column a head's ``[P, N]`` tile wants (``dt x``
 transposed to ``[B, P, H]`` beside the call, a head a lane, the head's lane
-picked by a mask so that the heads are a loop) and ``y`` leaves the same
-way: the read-out is a sum over lanes, 16 lane reductions a head, under
-the copy's time like the rest (the kernel read the same with the state
-kept ``[H, N, P]``, where the read-out is a sum over sublanes: PERF.md, PR
-40, so the layout stayed).
+picked by a mask) and ``y`` leaves the same way: the read-out is a sum over
+lanes, under the copy's time like the rest (the kernel read the same with
+the state kept ``[H, N, P]``, where the read-out is a sum over sublanes:
+PERF.md, PR 40, so the layout stayed).
+
+**A turn of the loop** (PR 44). A head's work is one dependent chain: pick
+its column (a select and ``P / 8`` lane reductions), decay and add, store,
+the product with ``C``, ``P / 8`` lane reductions, a select into ``y``. A
+lane reduction's result comes 0.07 us after it is asked for (a lane
+broadcast's too: with either in the chain's place a turn took as long),
+and a loop turn that works one head waits for two of them with nothing
+else to do: 0.13 us a head whatever the tile's size, which 32 heads of 128
+x 256 hide under the next row's read and 128 heads of 64 x 128 do not (30
+us a row against the copy's 13; TPU v5e, PR 44). So a turn works a block
+of ``heads_a_turn`` heads whose places in the block are static: that many
+independent chains of straight-line code, which the compiler's scheduler
+interleaves; and it picks the NEXT block's columns, carried into the next
+turn, so that the pick's reductions run under this block's read-out and a
+turn waits once, not twice. The block is chosen from the head's tile
+alone, about 32 of the 64 vector registers of state a turn (4 heads of 64
+x 128, 1 of 128 x 256), and never straddles a group of ``B`` and ``C``:
+larger blocks spill and read worse (8 and 16 heads without the pick ahead:
+16-18 us a row against 14.6 for 4), and the loop is not unrolled further
+because a step program holds the kernel once or twice and there are a
+dozen of them to lower.
 
 The kernel takes widths Mosaic's tiles take, ``d_state`` a multiple of 128
 lanes and ``d_head`` of 8 sublanes; on a TPU any other width goes through
@@ -51,6 +71,7 @@ from fei_tpu.ops import ssd
 _F32 = jnp.float32
 _VMEM_ROOM = 8 << 20  # beside the two row buffers: operands, Mosaic's own
 _VMEM_MOST = 96 << 20  # of a v5e core's 128 MiB
+_TURN_REGS = 32  # of the 64 vector registers: the state a loop turn holds
 
 
 class Walk(NamedTuple):
@@ -94,7 +115,7 @@ def _ssm_state_step(
 ):
     i = pl.program_id(0)
     n, l = n_ref[0], l_ref[0]
-    (H, P), G = buf.shape[1:3], b_ref.shape[1]
+    (H, P, N), G = buf.shape[1:], b_ref.shape[1]
 
     def read(j):
         return pltpu.make_async_copy(
@@ -116,21 +137,35 @@ def _ssm_state_step(
             read(i + 1).start()
 
         row, half = rows_ref[i], i % 2
+        k = heads_a_turn(H, P, N, G)
         lane = jax.lax.broadcasted_iota(jnp.int32, (P, H), 1)
 
-        def head(h, y):
-            # a loop, not 32 copies of the body: a step program holds the
-            # kernel once or twice and there are a dozen of them to lower
-            g = h // (H // G)
-            mine = lane == h
-            col = jnp.sum(jnp.where(mine, x_ref[0], 0.0), axis=-1,
-                          keepdims=True)  # [P, 1]: head h's dt x
-            new = buf[half, h] * a_ref[row, h] + col * b_ref[0, g]
-            buf[half, h] = new
-            out = jnp.sum(new * c_ref[0, g], axis=-1, keepdims=True)
-            return jnp.where(mine, out, y)
+        def pick(t):
+            """Block t's columns of ``dt x``, each [P, 1]."""
+            return tuple(
+                jnp.sum(jnp.where(lane == t * k + j, x_ref[0], 0.0), axis=-1,
+                        keepdims=True) for j in range(k))
 
-        y_ref[0] = jax.lax.fori_loop(0, H, head, jnp.zeros((P, H), _F32))
+        def turn(t, carry):
+            # a block of k heads whose places in it are static: k chains
+            # of straight-line code for the scheduler to interleave, and
+            # the next block's columns picked under this block's read-out
+            # (a loop over the blocks, not H copies of the body: a step
+            # program holds the kernel once or twice and there are a
+            # dozen of them to lower)
+            y, cols = carry
+            ahead = pick(jnp.minimum(t + 1, H // k - 1))
+            g = t * k // (H // G)
+            for j, col in enumerate(cols):
+                h = t * k + j
+                new = buf[half, h] * a_ref[row, h] + col * b_ref[0, g]
+                buf[half, h] = new
+                out = jnp.sum(new * c_ref[0, g], axis=-1, keepdims=True)
+                y = jnp.where(lane == h, out, y)
+            return y, ahead
+
+        y_ref[0], _ = jax.lax.fori_loop(
+            0, H // k, turn, (jnp.zeros((P, H), _F32), pick(0)))
 
         @pl.when(i + 1 < n)
         def _arrived():
@@ -138,6 +173,17 @@ def _ssm_state_step(
 
         write(i).start()
         write(i).wait()
+
+
+def heads_a_turn(H: int, P: int, N: int, G: int) -> int:
+    """How many heads a turn of the kernel's loop works, from the head's
+    tile alone: about ``_TURN_REGS`` vector registers of state a turn, and
+    no block of heads straddles a group."""
+    tile = -(-P // 8) * -(-N // 128)  # a head's [P, N] in registers
+    k = max(1, _TURN_REGS // tile)
+    while (H // G) % k:
+        k -= 1
+    return k
 
 
 def _kernel_takes(S) -> bool:

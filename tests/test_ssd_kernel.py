@@ -28,13 +28,16 @@ from fei_tpu.ops import ssd
 from fei_tpu.ops.pallas import ssd_step
 from fei_tpu.utils.metrics import METRICS
 
-# L, B, H, P, N, G: the tiny preset's mixer; the cell's head tile (P 128,
-# N 256) at 16 heads in 2 groups, few rows; one group, a state no wider
-# than a lane
+# L, B, H, P, N, G: the tiny preset's mixer; the Falcon cell's head tile (P
+# 128, N 256) at 16 heads in 2 groups, few rows; one group, a state no
+# wider than a lane; the granite cell's tile (P 64, N 128, one group) at
+# three blocks of four heads; that tile with a group that is one block
 SHAPES = {
     "tiny": (3, 5, 4, 16, 32, 2),
     "cell_tile": (2, 5, 16, 128, 256, 2),
     "one_group": (2, 5, 8, 8, 128, 1),
+    "granite_tile": (2, 5, 12, 64, 128, 1),
+    "group_is_a_block": (2, 5, 8, 64, 128, 2),
 }
 # dead rows at the start, in the middle, at the end; nobody; everybody
 MASKS = {
@@ -86,6 +89,25 @@ def test_kernel_advances_live_rows_as_the_plain_step_and_no_other(shape, mask):
     assert np.array_equal(out[l, :B][~live], S[l, :B][~live])
     assert np.array_equal(out[l, B], S[l, B])  # the admission's row
     assert np.array_equal(out[:l], S[:l])  # every other layer
+
+
+# the two cells' mixers, then the shapes above: two of them run every mask
+# case at a block of four heads, one with the group a single block
+@pytest.mark.parametrize("shape, k", [
+    ((9, 32, 128, 64, 128, 1), 4),  # granite-4.0-h-small: a tile of 8 registers
+    ((12, 32, 32, 128, 256, 2), 1),  # falcon-h1-34b: of 32, a head a turn
+    (SHAPES["granite_tile"], 4),
+    (SHAPES["group_is_a_block"], 4),
+    (SHAPES["cell_tile"], 1),
+    (SHAPES["one_group"], 8),  # 32 by the tile: the group's eight heads
+    (SHAPES["tiny"], 2),
+])
+def test_block_of_heads_comes_from_the_tile_and_straddles_no_group(shape, k):
+    _, _, H, P, N, G = shape
+    assert ssd_step.heads_a_turn(H, P, N, G) == k
+    assert (H // G) % k == 0  # so H % k == 0 too: whole turns
+    tile = -(-P // 8) * -(-N // 128)
+    assert k * tile <= 32 or k == 1  # a turn's state fits half the registers
 
 
 @pytest.mark.parametrize("live", [[1, 0, 1], [0, 0, 0], [1, 1, 1], [0, 0, 1]])

@@ -10,7 +10,11 @@ archive`` of the parent of PR 42 (75c4638) with this file's own
 ``digests()``; PR 42's tree gives the same twelve, and PR 43's (which
 moved the mixer both hybrid families run into ``models/mamba2.py`` and
 gave ``ops/moe.py`` a second gate) the same twelve again beside the three
-of the family it added.
+of the family it added. PR 44 changed the recurrence's decode kernel
+(``ops/pallas/ssd_step.py``: a block of heads a loop turn, the next
+block's columns picked a turn ahead) and re-pinned the four programs that
+hold it, ``multi`` and ``ragged`` of ``tiny-falcon-h1`` and
+``tiny-granite-h``; the other eleven are the parent's.
 
 A PR that changes a device program on purpose re-pins the programs it
 meant to change (``python tests/test_step_program_text.py`` prints the
@@ -54,17 +58,18 @@ PINNED = {
         "bfa5b6fd242705b78f295b375c344dd9626eeb6aff45fe6bc09eb6271204b319",
     ("tiny-moonlight", "chunk"):
         "1397d8933acb4df8b2ab9fe045bb7ffc9e76ee29de18440bbe7e02d723e75179",
+    # PR 44: the two mixer families' decode programs hold the new kernel
     ("tiny-falcon-h1", "multi"):
-        "2ce1a60210cab02f0d2fa26f91a09c615f7a29109de800e2e2ba723253a04473",
+        "febc92afc20b31a8f893578e1cfabcc3a252df56434b6a438d3507dc41d2accc",
     ("tiny-falcon-h1", "ragged"):
-        "5f20b1abb129fb0ac44483d2659b3a75d2f030c2810956f416dab611b16ec9b9",
+        "b3f703788c8795f1b1d1d29d0d503b612f15c54f2e478dfd4f585cf713e95f4f",
     ("tiny-falcon-h1", "chunk"):
         "851381effc8f02a1b81ed1643c9ac1872df62c20c997a4e95c787a10f9f7f8a8",
     # PR 43: a fifth family; its own tree's digests (the parent has none)
     ("tiny-granite-h", "multi"):
-        "426b9aef7f98ecdd1fa325740603361436ed806c493eb87c92657d3ed5f5f5e7",
+        "985f910e95510b35b0f996c3de480e3e46d531da5d27f890552f2fbbf1a7c19b",
     ("tiny-granite-h", "ragged"):
-        "e0b20f47eaf9187898ab2cbd67bc849cdc97eab5d9a580463fd7a23e46cc4d31",
+        "3241a0ae50c767d91fc259dbabc033d451effc9d387c0183da3cb1a73890bd53",
     ("tiny-granite-h", "chunk"):
         "317b9fc481451e302bd6f39b859948dcb843f7c1dff14bd53ec96b687a1ade52",
 }
